@@ -5,8 +5,7 @@ get the same driver via the `bigdl-tpu-bench` console script; see that
 module's docstring for metric definitions.
 """
 
-from bigdl_tpu.tools.bench_cli import (bench_lenet, bench_resnet50,  # noqa: F401
-                                       main)
+from bigdl_tpu.tools.bench_cli import bench_resnet50, main  # noqa: F401
 
 if __name__ == "__main__":
     main()

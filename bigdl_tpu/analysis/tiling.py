@@ -7,10 +7,14 @@ array extent (the grid is `n // tile`) and be a MULTIPLE OF 8 (the f32
 sublane quantum), under a VMEM budget. This checker keeps new kernel
 code on that discipline:
 
-- `block-literal` — an integer literal > 1 used as the leading (row)
+- `block-literal` — an integer literal used as the leading (row)
   dimension of a `pl.BlockSpec((r, ...))` that is not a multiple of 8.
-  (1 is allowed: single-row partial-reduction outputs are a legal and
-  used layout — bn_relu's dscale/dshift tiles.)
+  In a 2-D block that includes 1: the row dimension is then the block's
+  second-to-last, and a `(1, C)` block over `[n_tiles, C]` is what
+  Pallas's TPU lowering refused in bn_relu's partial sums (legal only
+  where the array's own dimension is 1: say so with the escape hatch).
+  Per-row outputs ride as `[n, 1, C]` with `(1, 1, C)` blocks, where the
+  leading 1 is a plain grid dimension.
 - `unvalidated-tile` — a `pallas_call(grid=(n // t, ...))` whose tile
   `t` was NOT produced by a `_pick_tile_*` helper in the same function
   and has no `n % t` divisibility guard: when `t` does not divide `n`
@@ -101,13 +105,14 @@ class TilingChecker(Checker):
                 isinstance(lead.value, int) and \
                 not isinstance(lead.value, bool):
             r = lead.value
-            if r > 1 and r % 8 != 0:
+            if r % 8 != 0 and (r > 1 or len(shape.elts) == 2):
                 raw.append((
                     "block-literal", lead.lineno,
                     f"BlockSpec row dimension {r} is not a multiple of 8 "
                     f"(the f32 sublane quantum Mosaic tiles by)",
-                    "use a multiple of 8 (or 1 for partial-reduction "
-                    "rows), or size it with _pick_tile_n"))
+                    "use a multiple of 8, size it with _pick_tile_n, or "
+                    "carry per-row outputs as [n, 1, C] with (1, 1, C) "
+                    "blocks"))
 
     @staticmethod
     def _check_grid(node: ast.Call, picked: Set[str], guarded: Set[str],

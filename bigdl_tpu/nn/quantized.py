@@ -167,9 +167,9 @@ class WeightOnlyQuantizedLinear(QuantizedLinear):
     """Weight-only int8 Linear: int8 weights dequantized at the matmul,
     activations and compute stay bf16/f32.
 
-    Why (beyond the reference's full-int8 scheme): the honest TPU
-    evaluation (docs/bench_records/r03_int8_inference_*.txt) showed full
-    int8 LOSES to bf16 on conv models — the MXU is already saturated in
+    Why (beyond the reference's full-int8 scheme): the v5e evaluation
+    (docs/PERF.md; captured 2026-07-31 on pre-PR-2 code, not
+    re-measured) showed full int8 LOSES to bf16 on conv models — the MXU is already saturated in
     bf16 and the activation quantize/dequant costs real time. The 4x
     weight size win is still free: weights stream from HBM as int8 (4x
     less bandwidth and memory -> bigger serving batches) and XLA fuses

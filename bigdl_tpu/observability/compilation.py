@@ -26,10 +26,11 @@ event:
 Durations use `time.monotonic()` — an NTP step cannot produce a negative
 `compile_s`.
 
-Robustness: if any stage of the AOT path fails (older jax without
-`jit.trace`, a backend that rejects AOT dispatch), the wrapper falls back
-to the plain jitted call permanently for that instance — instrumentation
-must never take down the loop it observes.
+A lowering or compile error (a Mosaic refusal, an out-of-memory plan)
+propagates to the caller once, as itself: compiling the same program a
+second time through plain `jit` would only fail again, later and under
+another name. Only a failed AOT *dispatch* (aval drift in a non-signature
+argument) flips the instance onto the plain jitted call.
 """
 
 from __future__ import annotations
@@ -154,6 +155,14 @@ class CompiledFunction:
                 pass
         return n
 
+    def executables(self) -> list:
+        """The compiled executables built so far (`jax.stages.Compiled`),
+        one per signature — chip_smoke.py reads their HLO to check that
+        the Mosaic kernels and the collectives it expects are in the step
+        that actually ran."""
+        with self._lock:
+            return [compiled for compiled, _ in self._cache.values()]
+
     def _emit(self, record: Dict):
         if self.telemetry is None:
             return
@@ -164,33 +173,20 @@ class CompiledFunction:
 
     def _compile(self, sig: Tuple, args):
         """Stage lower+compile for one signature, emit its compile record,
-        cache the executable. Returns (compiled, info) or None when the
-        AOT path is unavailable (caller falls back to plain jit)."""
-        eqns = None
+        cache the executable. Returns (compiled, info); lowering and
+        compile errors propagate."""
         t0 = time.monotonic()
-        try:
-            try:
-                traced = self._jit.trace(*args)
-                eqns = costs.jaxpr_eqn_count(traced.jaxpr)
-                lowered = traced.lower()
-            except AttributeError:  # older jax: no .trace on jit
-                traced = None
-                lowered = self._jit.lower(*args)
-            lower_s = time.monotonic() - t0
-            t1 = time.monotonic()
-            compiled = lowered.compile()
-            compile_s = time.monotonic() - t1
-        except Exception as e:
-            logger.warning(
-                "AOT compile path unavailable for %s (%r); falling back "
-                "to plain jit dispatch", self.label, e)
-            return None
+        traced = self._jit.trace(*args)
+        eqns = costs.jaxpr_eqn_count(traced.jaxpr)
+        lowered = traced.lower()
+        lower_s = time.monotonic() - t0
+        t1 = time.monotonic()
+        compiled = lowered.compile()
+        compile_s = time.monotonic() - t1
         cost = costs.executable_costs(compiled)
-        if cost["flops"] is None and traced is not None:
-            try:  # backend reported nothing: jaxpr-walk floor estimate
-                cost["flops"] = costs.jaxpr_flops(traced.jaxpr) or None
-            except Exception:
-                pass
+        if cost["flops"] is None:
+            # the backend's count was zero or non-finite: jaxpr-walk floor
+            cost["flops"] = costs.jaxpr_flops(traced.jaxpr) or None
         key = (self.label, sig, eqns)
         with _COMPILED_BEFORE_LOCK:
             cache_hit = key in _COMPILED_BEFORE
@@ -221,9 +217,6 @@ class CompiledFunction:
             entry = self._cache.get(sig)
         if entry is None:
             entry = self._compile(sig, args)
-            if entry is None:
-                self._aot_ok = False
-                return self._fallback(args)
             with self._lock:
                 self._cache.setdefault(sig, entry)
         compiled, info = entry

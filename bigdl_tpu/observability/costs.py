@@ -6,16 +6,16 @@ had no notion of FLOPs, so nobody could read model efficiency off a run's
 records. This module makes cost a first-class telemetry input:
 
 - `executable_costs(compiled)` reads XLA's own cost model off a
-  `jax.stages.Compiled` (`flops`, `bytes accessed`) — authoritative where
-  the backend reports it (CPU and TPU both do today).
-- `jaxpr_flops(jaxpr)` is the fallback estimator for backends whose PJRT
-  plugin reports nothing: a jaxpr walk counting matmul/conv FLOPs exactly
-  and elementwise ops as one FLOP per output element, recursing through
-  pjit/scan/while sub-jaxprs (scan bodies scale by trip count).
+  `jax.stages.Compiled` (`flops`, `bytes accessed`).
+- `jaxpr_flops(jaxpr)` is the fallback estimator for an executable whose
+  analysis carries no usable count: a jaxpr walk counting matmul/conv
+  FLOPs exactly and elementwise ops as one FLOP per output element,
+  recursing through pjit/scan/while sub-jaxprs (scan bodies scale by
+  trip count).
 - `PEAK_BF16_FLOPS` / `peak_flops(device_kind)` is the small peak-FLOPs
-  chip registry (dense bf16 per chip). Unknown kinds — CPU included —
-  return None, and every derived MFU is then None (null in JSONL), never
-  a made-up number.
+  chip registry (dense bf16 per chip), matched on the exact
+  `device_kind`. Unknown kinds — CPU included — return None, and every
+  derived MFU is then None (null in JSONL), never a made-up number.
 - `mfu(flops, step_time_s, ...)` folds the three together:
   achieved FLOP/s over the mesh peak.
 
@@ -34,28 +34,26 @@ from __future__ import annotations
 import math
 from typing import Dict, Optional
 
-#: Dense bf16 peak FLOP/s per chip, matched by case-insensitive substring
-#: of the jax `device_kind` (first match wins; ordered most-specific
-#: first). The registry is deliberately small and explicit — an unknown
-#: chip yields None, which downstream reports as a null MFU rather than
-#: a wrong one.
-PEAK_BF16_FLOPS = (
-    ("v6", 918e12), ("trillium", 918e12),
-    ("v5p", 459e12), ("v5 lite", 197e12), ("v5e", 197e12), ("v5", 459e12),
-    ("v4", 275e12), ("v3", 123e12), ("v2", 45e12),
-)
+#: Dense bf16 peak FLOP/s per chip, keyed by the lower-cased
+#: `device_kind` string JAX reports (both spellings where JAX has two).
+#: Exact match only: a kind that is not here — a new chip, the CPU —
+#: yields None, which the telemetry stream reports as a null MFU and the
+#: chip entry points (chip_smoke.py, bench.py) refuse to run on.
+PEAK_BF16_FLOPS = {
+    "tpu v2": 45e12, "tpu v3": 123e12, "tpu v4": 275e12,
+    "tpu v5 lite": 197e12, "tpu v5e": 197e12,
+    "tpu v5": 459e12, "tpu v5p": 459e12,
+    "tpu v6 lite": 918e12, "tpu v6e": 918e12,
+}
 
 
 def peak_flops(device_kind) -> Optional[float]:
     """Peak dense bf16 FLOP/s for a chip, from the registry; None for
     unknown kinds (CPU, new chips not yet registered). Accepts a kind
     string or a jax device object."""
-    kind = (device_kind if isinstance(device_kind, str)
-            else getattr(device_kind, "device_kind", "")).lower()
-    for key, peak in PEAK_BF16_FLOPS:
-        if key in kind:
-            return peak
-    return None
+    kind = device_kind if isinstance(device_kind, str) \
+        else getattr(device_kind, "device_kind", "")
+    return PEAK_BF16_FLOPS.get(kind.lower())
 
 
 def default_device_kind() -> str:
@@ -76,26 +74,17 @@ _DEVICE_KIND: Optional[str] = None
 
 def executable_costs(compiled) -> Dict[str, Optional[float]]:
     """`{"flops": ..., "bytes_accessed": ...}` from a
-    `jax.stages.Compiled`'s `cost_analysis()` (list- and dict-shaped
-    returns both handled). Missing/empty analysis — some PJRT plugins
-    return None — yields None values; callers fall back to
-    `jaxpr_flops`."""
-    out: Dict[str, Optional[float]] = {"flops": None, "bytes_accessed": None}
-    try:
-        cost = compiled.cost_analysis()
-    except Exception:
-        return out
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    if not isinstance(cost, dict):
-        return out
-    flops = cost.get("flops")
-    if flops is not None and math.isfinite(flops) and flops > 0:
-        out["flops"] = float(flops)
-    nbytes = cost.get("bytes accessed")
-    if nbytes is not None and math.isfinite(nbytes) and nbytes > 0:
-        out["bytes_accessed"] = float(nbytes)
-    return out
+    `jax.stages.Compiled`'s `cost_analysis()` dict. A count the backend
+    left out, or reported as zero or non-finite, is None; callers fall
+    back to `jaxpr_flops`."""
+    cost = compiled.cost_analysis()
+
+    def positive(key):
+        v = cost.get(key)
+        return float(v) if v is not None and math.isfinite(v) and v > 0 \
+            else None
+    return {"flops": positive("flops"),
+            "bytes_accessed": positive("bytes accessed")}
 
 
 def _prod(xs) -> float:

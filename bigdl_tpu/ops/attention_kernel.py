@@ -25,6 +25,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from bigdl_tpu.ops.partitioning import (DATA_AXIS, MODEL_AXIS, per_shard,
+                                        split_axis)
 
 NEG_INF = -1e30
 
@@ -179,8 +183,7 @@ def _kernel_block_update(q, k_blk, v_blk, acc, m, l, sm_scale, causal,
     acc_new = acc * scale_old[:, None] + jax.lax.dot_general(
         p, v_blk, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    return (_match_vma(acc_new, acc), _match_vma(m_new, m),
-            _match_vma(l_new, l))
+    return acc_new, m_new, l_new
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
@@ -312,39 +315,12 @@ def _flash_carry_kernel(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
     ol_ref[0, 0] = l
 
 
-def _match_vma(val, like):
-    """pcast `val` to carry `like`'s varying-manual-axes type (interpret
-    mode inside shard_map can drop vma through reductions); no-op
-    elsewhere."""
-    try:
-        want = jax.typeof(like).vma
-        have = jax.typeof(val).vma
-        missing = tuple(set(want) - set(have))
-        if missing:
-            return lax.pcast(val, missing, to="varying")
-    except (AttributeError, TypeError):
-        pass
-    return val
-
-
 def _offs_spec(interpret):
     from jax.experimental import pallas as pl
     if interpret:
         return pl.BlockSpec((2,), lambda i, j: (0,))
     from jax.experimental.pallas import tpu as pltpu
     return pl.BlockSpec(memory_space=pltpu.SMEM)
-
-
-def _struct_like(shape, dtype, like):
-    """ShapeDtypeStruct carrying `like`'s varying-manual-axes type, so the
-    kernel works both at top level and inside shard_map (check_vma)."""
-    try:
-        vma = jax.typeof(like).vma
-        if vma:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except (AttributeError, TypeError):
-        pass
-    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def flash_attention_carry(q, k, v, carry, causal: bool = False,
@@ -355,8 +331,8 @@ def flash_attention_carry(q, k, v, carry, causal: bool = False,
     """One ring-attention hop through the Pallas kernel: continue the
     online softmax carried in `carry` (= attention_state_init shapes)
     with this KV shard. Returns the updated (acc, m, l) — call
-    `attention_state_finish` after the last hop. Falls back to the XLA
-    blockwise step when shapes don't tile the kernel blocks."""
+    `attention_state_finish` after the last hop. Shapes that do not tile
+    the kernel blocks take the XLA blockwise step (same math)."""
     if interpret is None:
         interpret = INTERPRET
     from jax.experimental import pallas as pl
@@ -377,43 +353,35 @@ def flash_attention_carry(q, k, v, carry, causal: bool = False,
                       jnp.asarray(k_offset, jnp.int32)])
     kernel = functools.partial(_flash_carry_kernel, block_k=block_k,
                                sm_scale=sm_scale, causal=causal, seq_k=tk)
-    try:
-        oacc, om, ol = pl.pallas_call(
-            kernel,
-            grid=(bh, tq // block_q),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
-                pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
-                pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
-                pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
-                # offsets feed control flow (the causal loop bound):
-                # Mosaic requires such scalars in SMEM; interpret mode
-                # ignores the memory space
-                _offs_spec(interpret),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
-                pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
-            ],
-            out_shape=[
-                _struct_like((bh, tq, d), jnp.float32, q),
-                _struct_like((bh, 1, tq), jnp.float32, q),
-                _struct_like((bh, 1, tq), jnp.float32, q),
-            ],
-            interpret=interpret,
-        )(q.reshape(bh, tq, d), k.reshape(bh, tk, d), v.reshape(bh, tk, d),
-          acc.reshape(bh, tq, d), m.reshape(bh, 1, tq), l.reshape(bh, 1, tq),
-          offs)
-    except TypeError:
-        # varying-axes typing rejected the kernel on this backend/version:
-        # the XLA blockwise step is the same math
-        return blockwise_attention(q, k, v, causal=causal,
-                                   sm_scale=sm_scale, block_k=block_k,
-                                   q_offset=q_offset, k_offset=k_offset,
-                                   carry=carry, finish=False)
+    oacc, om, ol = pl.pallas_call(
+        kernel,
+        grid=(bh, tq // block_q),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
+            # offsets feed control flow (the causal loop bound):
+            # Mosaic requires such scalars in SMEM; interpret mode
+            # ignores the memory space
+            _offs_spec(interpret),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, tq, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
+        ],
+        interpret=interpret,
+    )(q.reshape(bh, tq, d), k.reshape(bh, tk, d), v.reshape(bh, tk, d),
+      acc.reshape(bh, tq, d), m.reshape(bh, 1, tq), l.reshape(bh, 1, tq),
+      offs)
     return (oacc.reshape(b, h, tq, d), om.reshape(b, h, tq),
             ol.reshape(b, h, tq))
 
@@ -590,6 +558,22 @@ def flash_attention_backward(q, k, v, out, lse, g, causal: bool = False,
             dv.reshape(b, h, tk, d))
 
 
+# --------------------------------------------------------------------------- #
+# partitioning: how the dense kernels split over a device mesh
+# --------------------------------------------------------------------------- #
+
+def _per_shard(kernel, n_in, n_out, q):
+    """`kernel` over [B, H, ...] operands, run per device under the mesh
+    the training loop traces in (ops/partitioning.py; a Mosaic call
+    cannot be partitioned automatically). A (batch, head) program never
+    reads another's rows, so batch splits over 'data' and heads over
+    'model' (where column-parallel q/k/v projections leave them) with no
+    collective; sequence and head width stay whole on every device."""
+    spec = P(split_axis(DATA_AXIS, q.shape[0]),
+             split_axis(MODEL_AXIS, q.shape[1]))
+    return per_shard(kernel, (spec,) * n_in, (spec,) * n_out)
+
+
 def _flash_plan(q_shape, k_shape, causal, use_pallas):
     """Static routing shared by forward and backward: (pallas?, bq, bk,
     pad_q, pad_k). Deterministic in shapes + static args, so the vjp
@@ -600,15 +584,16 @@ def _flash_plan(q_shape, k_shape, causal, use_pallas):
     t, tk = q_shape[2], k_shape[2]
     if not use_pallas:
         return False, 0, 0, 0, 0
-    # block_k 1024: +7% at 16k tokens vs 512 on v5e (neutral at 8k),
-    # measured 2026-07-31 block sweep (docs/bench_records). Prefer it only
-    # when it divides tk — padding would push non-causal odd-multiple-of-512
-    # key lengths (1536, 2560, ...) off the Pallas path entirely.
-    # block_q 512 when it divides t: +6-8% on the fwd+bwd training path
-    # vs 256 (22.0/40.1 TF/s at 8k/16k, v5e live sweep 2026-08-01,
-    # docs/bench_records/r05_flash_sweep.txt); otherwise keep 256, whose
-    # padding behavior for ragged t is long-tested
-    bq = 512 if t % 512 == 0 else min(256, _ceil_to(t, 8))
+    # block_k 1024: +7% at 16k tokens vs 512 on v5e (neutral at 8k).
+    # Prefer it only when it divides tk — padding would push non-causal
+    # odd-multiple-of-512 key lengths (1536, 2560, ...) off the Pallas
+    # path entirely. block_q 512 when it divides t: +6-8% on the fwd+bwd
+    # training path vs 256 (22.0/40.1 TF/s at 8k/16k). Both captured
+    # 2026-07-31..08-01 on pre-PR-2 code, not re-measured. Otherwise 256,
+    # or t rounded up to 128 lanes when shorter: the backward slices lse
+    # along lanes at `ib * block_q`, which Mosaic must prove 128-aligned
+    # (a 104-row block for t=100 fails to compile).
+    bq = 512 if t % 512 == 0 else min(256, _ceil_to(t, 128))
     for bk in (1024, 512):
         if tk % bk == 0:
             break
@@ -633,19 +618,7 @@ def flash_attention(q, k, v, causal: bool = False,
                     use_pallas: Optional[bool] = None):
     """Flash attention: Pallas forward AND backward on TPU (blockwise-XLA
     path elsewhere). `use_pallas=None` auto-detects the backend."""
-    return _flash_impl(q, k, v, causal, sm_scale, use_pallas)
-
-
-def _flash_impl(q, k, v, causal, sm_scale, use_pallas):
-    pallas, bq, bk, pq, pk = _flash_plan(q.shape, k.shape, causal,
-                                         use_pallas)
-    if not pallas:
-        return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale)
-    t = q.shape[2]
-    out = flash_attention_forward(_pad_t(q, pq), _pad_t(k, pk),
-                                  _pad_t(v, pk), causal=causal,
-                                  sm_scale=sm_scale, block_q=bq, block_k=bk)
-    return out[:, :, :t]
+    return _flash_fwd_rule(q, k, v, causal, sm_scale, use_pallas)[0]
 
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, use_pallas):
@@ -655,9 +628,11 @@ def _flash_fwd_rule(q, k, v, causal, sm_scale, use_pallas):
         out = blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale)
         return out, (q, k, v, None, None)
     t = q.shape[2]
-    out_p, lse = flash_attention_forward(
-        _pad_t(q, pq), _pad_t(k, pk), _pad_t(v, pk), causal=causal,
-        sm_scale=sm_scale, block_q=bq, block_k=bk, return_lse=True)
+    fwd = functools.partial(flash_attention_forward, causal=causal,
+                            sm_scale=sm_scale, block_q=bq, block_k=bk,
+                            return_lse=True)
+    out_p, lse = _per_shard(fwd, 3, 2, q)(
+        _pad_t(q, pq), _pad_t(k, pk), _pad_t(v, pk))
     return out_p[:, :, :t], (q, k, v, out_p, lse)
 
 
@@ -672,10 +647,11 @@ def _flash_bwd_rule(causal, sm_scale, use_pallas, res, g):
             q, k, v)
         return vjp(g)
     t, tk = q.shape[2], k.shape[2]
-    dq, dk, dv = flash_attention_backward(
+    bwd = functools.partial(flash_attention_backward, causal=causal,
+                            sm_scale=sm_scale, block_q=bq, block_k=bk)
+    dq, dk, dv = _per_shard(bwd, 6, 3, q)(
         _pad_t(q, pq), _pad_t(k, pk), _pad_t(v, pk), out_p, lse,
-        _pad_t(g, pq), causal=causal, sm_scale=sm_scale,
-        block_q=bq, block_k=bk)
+        _pad_t(g, pq))
     return dq[:, :, :t], dk[:, :, :tk], dv[:, :, :tk]
 
 

@@ -1,12 +1,8 @@
 """Pallas kernel for the fused BatchNorm/bias + activation tail.
 
-The round-5 perf record (docs/PERF.md) puts the residual gap to peak in
-ResNet-50's memory-bound stages: after every conv, the BatchNorm
-normalize-affine and the ReLU each cost a full HBM read-modify-write of
-the [B, H, W, C] activation. XLA fuses SOME of these into the adjacent
-conv, but the BN tail's scale/shift (computed from batch statistics) plus
-the separate ReLU module boundary leave up to three elementwise HBM
-round trips per block on the profile. This kernel collapses the tail to
+After every conv in ResNet-50, the BatchNorm normalize-affine and the
+ReLU each cost an HBM read-modify-write of the [B, H, W, C] activation
+unless XLA fuses them into a neighbour. This kernel states the tail as
 ONE VMEM-resident pass:
 
     y = max(x * scale + shift, 0)        (relu=True)
@@ -16,21 +12,24 @@ with `scale`/`shift` the per-channel folded BN coefficients the module
 already computes (nn/normalization.py folds weight/rsqrt(var) into one
 multiply-add). The backward fuses the same way (`custom_vjp`): one kernel
 produces dx and per-tile partial reductions for dscale/dshift, so training
-never materializes the mask or the pre-activation in HBM.
+never materializes the mask or the pre-activation in HBM. Whether this
+beats what XLA does with the unfused graph on the chip is ROADMAP S1's
+pair-run; the kernels first compiled under Mosaic and ran on a v5e in
+PR 22 (PERF.md).
 
-Routing follows the stem-kernel convention (ops/stem_kernel.py): on TPU
-`bn_relu` dispatches the Pallas custom_vjp pair (`bn_relu_pallas`);
-off-TPU it INLINES the exact unfused op sequence with no
-custom-derivative boundary, so the CPU fused graph is structurally the
-unfused graph minus the module dispatch — autodiff and trajectories stay
-bit-identical (the CI parity gate pins this; a custom_vjp boundary on
-CPU measurably perturbs XLA's fusion/FMA grouping at the ~1e-7 level).
-The raw kernels remain reachable in interpreter mode for parity tests
-(`bn_relu_forward` / `bn_relu_backward`, the `_pick_tile_n` boundary
-suite), and `FORCE_PALLAS=True` routes the public op through the
-interpreter-mode custom_vjp off-TPU for end-to-end kernel drills —
-forward bit-identical, backward within 1e-6 of the unfused autodiff
-(the tiled partial reductions regroup sums).
+Routing: on TPU `bn_relu` dispatches the Pallas custom_vjp pair
+(`bn_relu_pallas`), compiled by Mosaic; off-TPU it INLINES the exact
+unfused op sequence with no custom-derivative boundary, so the CPU fused
+graph is structurally the unfused graph minus the module dispatch —
+autodiff and trajectories stay bit-identical (the CI parity gate pins
+this; a custom_vjp boundary on CPU measurably perturbs XLA's fusion/FMA
+grouping at the ~1e-7 level). Interpreter mode exists only behind the
+test hooks: `INTERPRET`, an explicit `interpret=True` (the raw-kernel
+parity tests and the `_pick_tile_n` boundary suite), and `FORCE_PALLAS`,
+which routes the public op through the interpreter-mode custom_vjp
+off-TPU for end-to-end kernel drills — forward bit-identical, backward
+within 1e-6 of the unfused autodiff (the tiled partial reductions regroup
+sums).
 
 No reference counterpart: the reference's CPU BN calls MKL's fused
 batchnorm primitive; this exists because on TPU the fusion has to be
@@ -44,6 +43,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from bigdl_tpu.ops.partitioning import (DATA_AXIS, MODEL_AXIS, per_shard,
+                                        split_axis)
 
 # test hook, same convention as ops/attention_kernel.py: run the Pallas
 # kernels in interpreter mode (CPU) when True
@@ -53,35 +56,59 @@ INTERPRET = False
 # even off-TPU (interpreter mode) — the end-to-end kernel path on CPU
 FORCE_PALLAS = False
 
-#: VMEM budget the row-tile picker sizes against: ~6 live f32 copies of a
-#: [tile_n, C] block (x, the product, the cast, g/dx on the backward).
+#: VMEM the row-tile picker sizes one grid step against: every [tile_n, C]
+#: in/out block twice (Pallas double-buffers each block against the next
+#: step's DMA) plus `_LIVE_F32_TEMPS` f32 intermediates the kernel body
+#: keeps live. Half of the v5e's 16 MiB default scoped-VMEM limit, so the
+#: estimate may be off by 2x before Mosaic refuses the kernel.
 _VMEM_BUDGET_BYTES = 8 * 2 ** 20
+_LIVE_F32_TEMPS = 4
+_LANES = 128
 
 
-def _pick_tile_n(n: int, c: int, tile_n: Optional[int] = None) -> int:
-    """Largest row tile that (a) divides n, (b) is a multiple of 8 (the
-    f32 sublane quantum — same Mosaic rule as stem `_pick_tile_w`), and
-    (c) keeps ~6 live f32 copies of the [tile, c] block under the VMEM
-    budget. Falls back to the full n when no candidate exists (tiny or
-    odd row counts: interpret mode and Mosaic both accept a full-array
-    block)."""
+def _sublanes(itemsize: int) -> int:
+    """Rows of one native Mosaic tile: 8 for 4-byte, 16 for 2-byte
+    (bf16), 32 for 1-byte dtypes — narrower dtypes pack along sublanes."""
+    return 8 * max(1, 4 // itemsize)
+
+
+def _pick_tile_n(n: int, c: int, tile_n: Optional[int] = None,
+                 itemsizes: Tuple[int, ...] = (4, 4)) -> int:
+    """Largest row tile that (a) divides n, (b) is a multiple of the
+    sublane quantum of the NARROWEST [tile, c] block (`itemsizes`: bytes
+    per element of every row-tiled in/out block; 8 rows for f32, 16 for a
+    bf16 output or cotangent), and (c) keeps the double-buffered blocks
+    plus the live f32 temporaries under the VMEM budget, counting c
+    padded to the 128 lanes a VMEM row occupies. Falls back to the full
+    n when no candidate exists (tiny or odd row counts: a block equal to
+    the array is legal at any size)."""
+    q = _sublanes(min(itemsizes))
     if tile_n is None:
-        tile_n = max(8, _VMEM_BUDGET_BYTES // (6 * 4 * max(c, 1)))
-    cands = [d for d in range(min(tile_n, n), 0, -1)
-             if n % d == 0 and d % 8 == 0]
+        c_pad = -(-max(c, 1) // _LANES) * _LANES
+        row_bytes = c_pad * (2 * sum(itemsizes) + 4 * _LIVE_F32_TEMPS)
+        tile_n = max(q, _VMEM_BUDGET_BYTES // row_bytes)
+    cands = [d for d in range(min(tile_n, n) // q * q, 0, -q) if n % d == 0]
     return cands[0] if cands else n
 
 
 def _fwd_kernel(x_ref, s_ref, b_ref, o_ref, *, relu: bool):
     """One program = one row tile: fused normalize-affine (+ ReLU).
 
-    The multiply-add runs in f32 registers; the cast to the output dtype
-    happens BEFORE the max, mirroring the unfused graph's op order
-    (BN casts to out_dtype, then the ReLU module runs) so the fused
-    forward is bit-identical to the unfused one."""
+    Everything runs in f32 registers (the v5e VPU has no bf16 ALU) and
+    the one cast to the output dtype comes last. Rounding is monotonic
+    and 0 is exact, so max-then-cast equals the unfused graph's
+    cast-then-max bit for bit."""
     v = x_ref[...] * s_ref[...] + b_ref[...]
-    v = v.astype(o_ref.dtype)
-    o_ref[...] = jnp.maximum(v, 0) if relu else v
+    if relu:
+        v = jnp.maximum(v, 0.0)
+    o_ref[...] = v.astype(o_ref.dtype)
+
+
+def _row(v):
+    """[C] coefficients as the [1, C] operand the kernels broadcast over
+    rows: Mosaic wants >= 2-D operands, and a (1, C) block of a [1, C]
+    array is legal because it equals the array's own dims."""
+    return v.reshape(1, -1)
 
 
 def bn_relu_forward(x2, scale, shift, relu: bool = True,
@@ -96,23 +123,21 @@ def bn_relu_forward(x2, scale, shift, relu: bool = True,
     from jax.experimental import pallas as pl
 
     if interpret is None:
-        interpret = INTERPRET
+        interpret = INTERPRET or FORCE_PALLAS
     n, c = x2.shape
-    out_dtype = out_dtype or x2.dtype
-    tn = _pick_tile_n(n, c, tile_n)
+    out_dtype = jnp.dtype(out_dtype or x2.dtype)
+    tn = _pick_tile_n(n, c, tile_n,
+                      (x2.dtype.itemsize, out_dtype.itemsize))
     kernel = functools.partial(_fwd_kernel, relu=relu)
+    coef = pl.BlockSpec((1, c), lambda i: (0, 0))  # lint: tiling-ok(equals the [1, C] array)
     return pl.pallas_call(
         kernel,
         grid=(n // tn,),
-        in_specs=[
-            pl.BlockSpec((tn, c), lambda i: (i, 0)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-        ],
+        in_specs=[pl.BlockSpec((tn, c), lambda i: (i, 0)), coef, coef],
         out_specs=pl.BlockSpec((tn, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, c), out_dtype),
         interpret=interpret,
-    )(x2, scale, shift)
+    )(x2, _row(scale), _row(shift))
 
 
 def _bwd_kernel(x_ref, s_ref, b_ref, g_ref, dx_ref, ds_ref, db_ref, *,
@@ -120,17 +145,18 @@ def _bwd_kernel(x_ref, s_ref, b_ref, g_ref, dx_ref, ds_ref, db_ref, *,
     """One program = one row tile of the fused backward: recompute the
     pre-activation in VMEM (nothing was saved to HBM), apply the ReLU
     mask to the cotangent, and emit dx plus this tile's PARTIAL
-    dscale/dshift row sums (the caller reduces over tiles)."""
+    dscale/dshift row sums (the caller reduces over tiles). All in f32:
+    the cotangent widens on load, and the mask compares the f32
+    pre-activation (its sign survives the forward's cast, bar f32
+    subnormals the TPU flushes anyway)."""
     x = x_ref[...]
     s = s_ref[...]
-    g = g_ref[...]
+    g = g_ref[...].astype(jnp.float32)
     if relu:
-        pre = (x * s + b_ref[...]).astype(g.dtype)
-        g = jnp.where(pre > 0, g, 0)
-    g32 = g.astype(jnp.float32)
-    dx_ref[...] = g32 * s
-    ds_ref[...] = jnp.sum(g32 * x, axis=0, keepdims=True)
-    db_ref[...] = jnp.sum(g32, axis=0, keepdims=True)
+        g = jnp.where(x * s + b_ref[...] > 0, g, 0.0)
+    dx_ref[...] = g * s
+    ds_ref[0] = jnp.sum(g * x, axis=0, keepdims=True)
+    db_ref[0] = jnp.sum(g, axis=0, keepdims=True)
 
 
 def bn_relu_backward(x2, scale, shift, g2, relu: bool = True,
@@ -142,33 +168,32 @@ def bn_relu_backward(x2, scale, shift, g2, relu: bool = True,
     from jax.experimental import pallas as pl
 
     if interpret is None:
-        interpret = INTERPRET
+        interpret = INTERPRET or FORCE_PALLAS
     n, c = x2.shape
-    tn = _pick_tile_n(n, c, tile_n)
+    tn = _pick_tile_n(n, c, tile_n,
+                      (x2.dtype.itemsize, g2.dtype.itemsize, 4))
     n_tiles = n // tn
     kernel = functools.partial(_bwd_kernel, relu=relu)
+    rows = pl.BlockSpec((tn, c), lambda i: (i, 0))
+    coef = pl.BlockSpec((1, c), lambda i: (0, 0))  # lint: tiling-ok(equals the [1, C] array)
+    # per-tile partial sums ride as [n_tiles, 1, C] with block (1, 1, C):
+    # a (1, C) block over [n_tiles, C] breaks Mosaic's rule that the last
+    # two block dims divide (8, 128) or equal the array's (same layout as
+    # the flash kernel's lse, ops/attention_kernel.py)
+    part = pl.BlockSpec((1, 1, c), lambda i: (i, 0, 0))
     dx, ds_part, db_part = pl.pallas_call(
         kernel,
         grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((tn, c), lambda i: (i, 0)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((tn, c), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tn, c), lambda i: (i, 0)),
-            pl.BlockSpec((1, c), lambda i: (i, 0)),
-            pl.BlockSpec((1, c), lambda i: (i, 0)),
-        ],
+        in_specs=[rows, coef, coef, rows],
+        out_specs=[rows, part, part],
         out_shape=[
             jax.ShapeDtypeStruct((n, c), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, c), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, c), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles, 1, c), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles, 1, c), jnp.float32),
         ],
         interpret=interpret,
-    )(x2, scale, shift, g2)
-    return dx, jnp.sum(ds_part, axis=0), jnp.sum(db_part, axis=0)
+    )(x2, _row(scale), _row(shift), g2)
+    return dx, jnp.sum(ds_part, axis=(0, 1)), jnp.sum(db_part, axis=(0, 1))
 
 
 # ---------------------------------------------------------------------- #
@@ -197,23 +222,62 @@ def _reference_backward(x, scale, shift, g, relu: bool, out_dtype):
 
 
 # ---------------------------------------------------------------------- #
-# public op: backend-routed dispatcher over the custom_vjp kernel pair
+# partitioning: how the kernels split over a device mesh
 # ---------------------------------------------------------------------- #
+# A Mosaic custom call carries no partitioning rule: under a multi-device
+# `jit` its lowering refuses outright ("Mosaic kernels cannot be
+# automatically partitioned"), and libtpu has no `custom_partitioning`.
+# The tail is elementwise over rows and channels, so under the mesh the
+# training loop traces in (ops/partitioning.py) each device runs the
+# kernel on its own shard: batch rows over 'data', channels over 'model'
+# while every shard keeps whole 128-lane rows (where a column-parallel
+# conv leaves them). No activation moves; the one collective is the
+# backward's psum of the [C] partial sums over the row axis.
 
 def _flat(x):
     return x.reshape(-1, x.shape[-1])
 
 
+def _specs(x):
+    """(mesh axis splitting the rows, [..., C] spec, [C] spec) for x
+    under the context mesh; all None where there is nothing to split."""
+    rows = split_axis(DATA_AXIS, x.shape[0])
+    chans = split_axis(MODEL_AXIS, x.shape[-1], _LANES)
+    return rows, P(rows, *(None,) * (x.ndim - 2), chans), P(chans)
+
+
+def _forward_nd(x, scale, shift, relu, out_dtype):
+    def local(x, scale, shift):
+        return bn_relu_forward(_flat(x), scale, shift, relu,
+                               out_dtype).reshape(x.shape)
+    _, xs, cs = _specs(x)
+    return per_shard(local, (xs, cs, cs), xs)(x, scale, shift)
+
+
+def _backward_nd(x, scale, shift, g, relu):
+    rows, xs, cs = _specs(x)
+
+    def local(x, scale, shift, g):
+        dx, ds, db = bn_relu_backward(_flat(x), scale, shift, _flat(g), relu)
+        if rows:
+            ds, db = jax.lax.psum((ds, db), rows)
+        return dx.reshape(x.shape), ds, db
+    return per_shard(local, (xs, cs, cs, xs), (xs, cs, cs))(
+        x, scale, shift, g)
+
+
+# ---------------------------------------------------------------------- #
+# public op: backend-routed dispatcher over the custom_vjp kernel pair
+# ---------------------------------------------------------------------- #
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def bn_relu_pallas(x, scale, shift, relu: bool = True, out_dtype=None):
     """The fused tail as a custom_vjp over the Pallas kernels (forward
-    AND backward fuse; interpreter mode off-TPU). `relu`/`out_dtype` are
+    AND backward fuse), compiled by Mosaic; interpreter mode only under
+    the `INTERPRET`/`FORCE_PALLAS` test hooks. `relu`/`out_dtype` are
     static. Use `bn_relu` for backend-routed production dispatch."""
-    out_dtype = jnp.dtype(out_dtype or x.dtype)
-    y2 = bn_relu_forward(_flat(x), scale, shift, relu=relu,
-                         out_dtype=out_dtype,
-                         interpret=jax.default_backend() != "tpu")
-    return y2.reshape(x.shape)
+    return _forward_nd(x, scale, shift, relu,
+                       jnp.dtype(out_dtype or x.dtype))
 
 
 def _bn_relu_fwd_rule(x, scale, shift, relu, out_dtype):
@@ -223,10 +287,7 @@ def _bn_relu_fwd_rule(x, scale, shift, relu, out_dtype):
 
 def _bn_relu_bwd_rule(relu, out_dtype, res, g):
     x, scale, shift = res
-    dx2, ds, db = bn_relu_backward(
-        _flat(x), scale, shift, _flat(g), relu=relu,
-        interpret=jax.default_backend() != "tpu")
-    return dx2.reshape(x.shape), ds, db
+    return _backward_nd(x, scale, shift, g, relu)
 
 
 bn_relu_pallas.defvjp(_bn_relu_fwd_rule, _bn_relu_bwd_rule)
@@ -254,24 +315,18 @@ def bn_relu(x, scale, shift, relu: bool = True, out_dtype=None):
 
 
 def count_fused_calls(jaxpr) -> int:
-    """Number of `bn_relu` custom_vjp call sites in a (closed) jaxpr,
-    recursing through sub-jaxprs — the jaxpr-level fusion assertion the
-    suite pins (a fused graph must carry one per matched BN+ReLU pair
-    and NO standalone relu custom_jvp eqns)."""
+    """Number of `bn_relu_pallas` custom_vjp call sites in a (closed)
+    jaxpr, recursing through sub-jaxprs — the jaxpr-level fusion
+    assertion the suite and chip_smoke.py pin (a fused graph on the
+    kernel route must carry one per matched BN+ReLU pair)."""
     inner = getattr(jaxpr, "jaxpr", jaxpr)
     total = 0
     for eqn in inner.eqns:
-        if eqn.primitive.name.startswith("custom_vjp_call"):
-            sub = eqn.params.get("fun_jaxpr") or eqn.params.get("call_jaxpr")
-            names = {e.primitive.name
-                     for e in getattr(sub, "jaxpr", sub).eqns} if sub else set()
-            # the bn_relu forward body: a mul+add+(max) chain or a single
-            # pallas_call — either way it touches no other custom calls
-            if names and names <= {"mul", "add", "max",
-                                   "convert_element_type", "broadcast_in_dim",
-                                   "pallas_call", "reshape"}:
-                total += 1
-                continue
+        if eqn.primitive.name == "custom_vjp_call" and \
+                eqn.params["call_jaxpr"].jaxpr.debug_info.func_name == \
+                bn_relu_pallas.__name__:
+            total += 1
+            continue
         for key in ("jaxpr", "call_jaxpr", "fun_jaxpr", "body_jaxpr"):
             if key in eqn.params:
                 total += count_fused_calls(eqn.params[key])

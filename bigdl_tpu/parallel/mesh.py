@@ -76,12 +76,3 @@ def shard_batch(mesh: Mesh, batch):
         return jax.device_put(jnp.asarray(x), sh)
 
     return jax.tree_util.tree_map(put, batch)
-
-
-def get_shard_map():
-    """jax.shard_map, with the pre-0.10 experimental fallback."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    return shard_map

@@ -268,14 +268,12 @@ class MoE(Module):
             return (jnp.sum(y_unit.reshape(K, t_local, moe.d), axis=0),
                     keep.reshape(K, t_local))
 
-        from bigdl_tpu.parallel.mesh import get_shard_map
-        shard_map = get_shard_map()
         param_specs = {
             "router": P(),
             "w1": P("expert"), "b1": P("expert"),
             "w2": P("expert"), "b2": P("expert"),
         }
-        mapped_fn = shard_map(
+        mapped_fn = jax.shard_map(
             mapped, mesh=mesh,
             in_specs=(param_specs, P("expert")),  # tokens split over axis
             out_specs=(P("expert"), P(None, "expert")))

@@ -46,12 +46,10 @@ from bigdl_tpu.nn.module import ApplyContext, Module
 
 
 def _varying(a):
-    """Mark an array device-varying over 'pipe' (newer shard_map type
-    system); idempotent, and a no-op on JAX versions without lax.pcast."""
+    """Mark an array device-varying over 'pipe' (shard_map's
+    varying-axes type system); idempotent."""
     try:
         return lax.pcast(a, ("pipe",), to="varying")
-    except AttributeError:
-        return a
     except ValueError:
         return a  # already varying
 
@@ -144,12 +142,9 @@ class GPipe(Module):
             params_local = jax.tree_util.tree_map(
                 lambda l: l[0], params_stage)
             idx = lax.axis_index("pipe")
-            zeros = jnp.zeros_like(micro_all[0])
-            try:
-                # scan carry must be device-varying like the loop outputs
-                zeros = lax.pcast(zeros, ("pipe",), to="varying")
-            except AttributeError:
-                pass
+            # scan carry must be device-varying like the loop outputs
+            zeros = lax.pcast(jnp.zeros_like(micro_all[0]), ("pipe",),
+                              to="varying")
             T = n_micro + S - 1
             perm = [(i, (i + 1) % S) for i in range(S)]
 
@@ -169,10 +164,8 @@ class GPipe(Module):
                                   jnp.zeros_like(out_local))
             return lax.psum(out_local, "pipe")
 
-        from bigdl_tpu.parallel.mesh import get_shard_map
-        shard_map = get_shard_map()
         stage_spec = jax.tree_util.tree_map(lambda _: P("pipe"), params)
-        mapped = shard_map(
+        mapped = jax.shard_map(
             staged, mesh=mesh,
             in_specs=(stage_spec, P()),   # params by stage, batch replicated
             out_specs=P())
@@ -422,10 +415,8 @@ class PipelineStages:
             res = lax.dynamic_slice_in_dim(res, S - 1, M, axis=0)
             return lax.psum(res, "pipe")
 
-        from bigdl_tpu.parallel.mesh import get_shard_map
-        shard_map = get_shard_map()
-        mapped = shard_map(staged, mesh=mesh,
-                           in_specs=(P("pipe"), P()), out_specs=P())
+        mapped = jax.shard_map(staged, mesh=mesh,
+                               in_specs=(P("pipe"), P()), out_specs=P())
         out_pad = mapped(stacked, micro)             # [M, act_pad]
         out_sd = self.boundary_shapes[-1]
         n = int(np.prod(out_sd.shape))
@@ -629,11 +620,9 @@ class PipelineStages:
                 jnp.arange(T))
             return gacc[None, :], lax.psum(loss_acc, "pipe")
 
-        from bigdl_tpu.parallel.mesh import get_shard_map
-        shard_map = get_shard_map()
-        mapped = jax.jit(shard_map(staged, mesh=mesh,
-                                   in_specs=(P("pipe"), P(), P()),
-                                   out_specs=(P("pipe"), P())))
+        mapped = jax.jit(jax.shard_map(staged, mesh=mesh,
+                                       in_specs=(P("pipe"), P(), P()),
+                                       out_specs=(P("pipe"), P())))
         self._1f1b_fn_cache = (fn_key, mapped, mesh, loss_fn)
         gpad, loss_sum = mapped(stacked, micro_x, micro_y)
         grads = [unravels[s](gpad[s, :sizes[s]])

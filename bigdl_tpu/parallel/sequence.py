@@ -302,10 +302,6 @@ def make_sequence_parallel_attention(mesh: Mesh, scheme: str = "ring",
         ...                   atol=1e-5))
         True
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
     if scheme not in ("ring", "ulysses", "zigzag"):
         raise ValueError(f"scheme must be ring|ulysses|zigzag, got {scheme}")
     n = int(mesh.shape[axis_name])
@@ -320,17 +316,15 @@ def make_sequence_parallel_attention(mesh: Mesh, scheme: str = "ring",
                                causal=causal)
     spec = P(None, None, axis_name, None)
 
-    kw = {}
     from bigdl_tpu.ops import attention_kernel as ak
-    if scheme in ("ring", "zigzag") and ak.INTERPRET:
-        # interpret-mode Pallas drops varying-axes types inside the carry
-        # kernel's loop (CPU test hook only; the real-TPU path keeps full
-        # vma checking). Older shard_map predates the kwarg.
-        import inspect as _inspect
-        if "check_vma" in _inspect.signature(shard_map).parameters:
-            kw["check_vma"] = False
-    mapped = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, **kw)
+    # the Pallas hop kernel is traced with varying-axes typing off: ops
+    # inside a kernel body drop the type while loads from its refs keep
+    # it, so the hop's fori_loop carry would mismatch. The XLA blockwise
+    # ring keeps the check.
+    pallas = scheme != "ulysses" and (jax.default_backend() == "tpu"
+                                      or ak.INTERPRET)
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec, check_vma=not pallas)
     if scheme == "zigzag":
         # callers keep natural order: reorder in, inverse-reorder out.
         # (Feed zigzag-ordered data directly and call the shard_mapped fn

@@ -84,7 +84,7 @@ class InjectedFault(Exception):
 
 class TransientInjectedFault(InjectedFault):
     """An injected fault classified TRANSIENT by `RetryPolicy` defaults —
-    models a flaky network read, a preempted worker, a tunnel blip."""
+    models a flaky network read, a preempted worker, a link blip."""
 
 
 class PermanentInjectedFault(InjectedFault):

@@ -509,13 +509,10 @@ class InferenceEngine:
 
     def compile_count(self) -> int:
         """Number of distinct XLA compilations of the serving forward, from
-        the jit cache (one entry per traced input signature — i.e. per
-        bucket per feature signature). 0 before any forward."""
-        try:
-            return int(self._pred._jitted._cache_size())
-        except AttributeError:  # private jax API moved: fall back to the
-            with self._slock:   # engine's own (signature, bucket) ledger
-                return len(self._compiled)
+        the predictor's compile-telemetry wrapper (one entry per input
+        signature — i.e. per bucket per feature signature). 0 before any
+        forward."""
+        return self._pred._jitted._cache_size()
 
     # ------------------------------------------------------------ dispatcher
     def _run(self):

@@ -109,17 +109,13 @@ def main(argv=None):
     if args.distributed:
         from bigdl_tpu.parallel.mesh import build_mesh, shard_batch
         from jax.sharding import NamedSharding, PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax
-            from jax.experimental.shard_map import shard_map
         mesh = build_mesh(model=1)
         n_dev = mesh.devices.size
         x = jnp.asarray(np.tile(x_np, (n_dev,) + (1,) * (x_np.ndim - 1)))
         y = jnp.asarray(np.tile(y_np, (n_dev,) + (1,) * (y_np.ndim - 1)))
         records = args.batch_size * n_dev
 
-        run = jax.jit(shard_map(
+        run = jax.jit(jax.shard_map(
             step, mesh=mesh,
             in_specs=(P(), P(), P(), P("data"), P("data")),
             out_specs=(P(), P(), P(), P())))

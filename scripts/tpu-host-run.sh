@@ -47,9 +47,10 @@ if [ -n "${BIGDL_TPU_COORDINATOR:-}" ]; then
     export JAX_PROCESS_ID="${BIGDL_TPU_HOST_INDEX}"
 fi
 
-# persistent XLA compile cache: recompiles cost 20-40s on TPU; keep them
-# across restarts (orbax-style checkpoint resume makes restarts routine)
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-${HOME}/.cache/bigdl_tpu_xla}"
-mkdir -p "${JAX_COMPILATION_CACHE_DIR}"
+# persistent XLA compile cache: a ResNet-50 step costs most of a minute
+# to compile; keep it across restarts (checkpoint resume makes restarts
+# routine). Where the variable is set it is left alone; where it is not,
+# the one fixed default of bigdl_tpu/utils/compile_cache.py is used.
+export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$(python -m bigdl_tpu.utils.compile_cache)}"
 
 exec python "$@"

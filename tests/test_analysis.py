@@ -384,8 +384,27 @@ class TestTiling:
                 pl.pallas_call(body, grid=(n // tn,),
                     in_specs=[pl.BlockSpec((tn, c), lambda i: (i, 0))])(x)
                 pl.pallas_call(body, grid=(n // t2,),
-                    out_specs=pl.BlockSpec((1, c), lambda i: (0, 0)))(x)
+                    out_specs=pl.BlockSpec((1, 1, c), lambda i: (i, 0, 0)))(x)
         """, name="bigdl_tpu/ops/fixture.py")
+        assert f == []
+
+    def test_single_row_2d_block_is_a_block_literal(self):
+        # (1, c) over [n_tiles, c] is what Pallas's TPU lowering refused
+        # in bn_relu's partial sums; legal only where the array's own
+        # row count is 1, which the escape hatch states
+        src = """
+            import jax.experimental.pallas as pl
+            def k(x, n, c):
+                tn = _pick_tile_n(n, c)
+                part = pl.BlockSpec((1, c), lambda i: (i, 0)){hatch}
+                pl.pallas_call(body, grid=(n // tn,), out_specs=part)(x)
+        """
+        f = run_on(TilingChecker(), src.format(hatch=""),
+                   name="bigdl_tpu/ops/fixture.py")
+        assert rules(f) == ["block-literal"]
+        f = run_on(TilingChecker(), src.format(
+            hatch="  # lint: tiling-ok(the array is [1, c])"),
+            name="bigdl_tpu/ops/fixture.py")
         assert f == []
 
     def test_deep_check_real_pickers_hold(self):

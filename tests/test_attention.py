@@ -91,7 +91,7 @@ class TestPallasFlash:
 
 
 class TestPallasFlashBackward:
-    """The Pallas backward kernels (VERDICT r3 #2): dq/dk/dv from the
+    """The Pallas backward kernels (round-3 review #2): dq/dk/dv from the
     saved forward logsumexp must match autodiff of the naive reference —
     the training path no longer leaves Pallas."""
 

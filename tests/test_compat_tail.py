@@ -1,4 +1,4 @@
-"""The `bigdl.*` compat-namespace tail (VERDICT r4 missing #3 / weak #4).
+"""The `bigdl.*` compat-namespace tail (round-4 review missing #3 / weak #4).
 
 Covers: the previously-stubbed Layer methods (update_parameters, freeze,
 stop_gradient, save_graph_topology), the `bigdl.keras` converter

@@ -89,7 +89,7 @@ class TestPredictorConversion:
 
 
 class TestS2DStemRestatement:
-    """The s2d-stem rewrite is an IR pass (VERDICT r4 weak #6), not a
+    """The s2d-stem rewrite is an IR pass (round-4 review weak #6), not a
     model-code hand-edit: eligible stems restate with bit-identical math
     and param tree; non-stems are untouched."""
 

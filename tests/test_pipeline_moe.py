@@ -98,7 +98,7 @@ class TestGPipe:
 
 
 class TestHeteroPipeline:
-    """PipelineStages: heterogeneous stages + 1F1B (VERDICT r3 #5).
+    """PipelineStages: heterogeneous stages + 1F1B (round-3 review #5).
 
     Reference ambition bar: DL/optim/ParallelOptimizer.scala is the
     reference's second parallelism engine; this pipelines models whose

