@@ -61,6 +61,16 @@ def _new_id(nbytes: int) -> str:
     return os.urandom(nbytes).hex()
 
 
+def _trace_annotation():
+    """`jax.profiler.TraceAnnotation`, or False where JAX has none: a
+    span looks it up once a tracer, not once a span."""
+    try:
+        import jax
+        return jax.profiler.TraceAnnotation
+    except Exception:
+        return False
+
+
 class TraceContext:
     """Distributed span identity: (trace_id, span_id, parent_id).
 
@@ -124,6 +134,9 @@ class SpanTracer:
         self._tls = threading.local()  # per-thread TraceContext stack
         self._lanes: Dict[int, str] = {}  # tid -> display name
         self._next_lane_tid = 1_000_000_000  # synthetic-lane tid range
+        # jax.profiler.TraceAnnotation, resolved on the first span (not
+        # here: building a tracer must not import jax); False = unusable
+        self._annotation = None
         # monotonic offsets supply the durations (an NTP step mid-run can
         # never produce a negative span); the wall base, sampled once,
         # anchors them to absolute epoch time for cross-trace alignment
@@ -209,12 +222,15 @@ class SpanTracer:
         compatibility for plain phase timing)."""
         ann = None
         if self.annotate:
-            try:
-                import jax
-                ann = jax.profiler.TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:
-                ann = None
+            cls = self._annotation
+            if cls is None:
+                cls = self._annotation = _trace_annotation()
+            if cls:
+                try:
+                    ann = cls(name)
+                    ann.__enter__()
+                except Exception:
+                    ann = None
         stack = self._ctx_stack()
         if ctx is None and stack:
             ctx = stack[-1].child()
@@ -237,9 +253,9 @@ class SpanTracer:
                   "ts": t0, "dur": dur, "pid": self.pid, "tid": tid}
             if args:
                 ev["args"] = args
-            tname = threading.current_thread().name
             with self._lock:
-                self._lanes.setdefault(tid, tname)
+                if tid not in self._lanes:
+                    self._lanes[tid] = threading.current_thread().name
                 self._append(ev)
 
     def _append(self, ev):  # under self._lock

@@ -231,6 +231,11 @@ RECORD_SCHEMAS: Dict[str, Dict] = {
                      "batch_form_ms": _NUM, "dispatch_ms": _NUM,
                      "forward_ms": _NUM, "fetch_ms": _NUM,
                      "prefill_ms": _NUM, "decode_ms": _NUM, "tokens": int,
+                     # kind="generate": the engine's own token clock
+                     # (TokenStream.token_times): first token after
+                     # submit, median and widest gap between tokens
+                     "ttft_ms": _NUM, "itl_p50_ms": _NUM,
+                     "itl_max_ms": _NUM,
                      "batch": int, "bucket": int,
                      "critical_path": list, "error": str,
                      "sample_weight": int, "replica_id": str,
@@ -256,7 +261,19 @@ RECORD_SCHEMAS: Dict[str, Dict] = {
                      "decode_occupancy": _OPT_NUM},
         "optional": {"queue_depth": int, "max_len": int,
                      "prefill_batches": int, "prefill_s_total": _NUM,
-                     "decode_s_total": _NUM},
+                     "decode_s_total": _NUM,
+                     # the host's parts of the decode steps (dispatch +
+                     # fetch lie inside decode_s_total, deliver after it)
+                     "decode_dispatch_s_total": _NUM,
+                     "decode_fetch_s_total": _NUM,
+                     "decode_deliver_s_total": _NUM,
+                     # engine-side token clock over the recent window
+                     # (WindowedHistogram.snapshot: quantiles absent
+                     # before the first observation)
+                     "ttft_ms_p50": _NUM, "ttft_ms_p95": _NUM,
+                     "ttft_ms_p99": _NUM, "ttft_ms_count": int,
+                     "itl_ms_p50": _NUM, "itl_ms_p95": _NUM,
+                     "itl_ms_p99": _NUM, "itl_ms_count": int},
     },
     # fleet-level counters/gauges (serving/fleet.py), one per
     # membership change or maintain() tick; PrometheusTextSink renders

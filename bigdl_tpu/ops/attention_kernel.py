@@ -270,6 +270,7 @@ def flash_attention_forward(q, k, v, causal: bool = False,
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qr, kr, vr)
     out = out.reshape(b, h, tq, d)
     if return_lse:
@@ -379,6 +380,7 @@ def flash_attention_carry(q, k, v, carry, causal: bool = False,
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_carry",
     )(q.reshape(bh, tq, d), k.reshape(bh, tk, d), v.reshape(bh, tk, d),
       acc.reshape(bh, tq, d), m.reshape(bh, 1, tq), l.reshape(bh, 1, tq),
       offs)
@@ -528,6 +530,7 @@ def flash_attention_backward(q, k, v, out, lse, g, causal: bool = False,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qr, kr, vr, dor, lser, delta)
 
     dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
@@ -553,6 +556,7 @@ def flash_attention_backward(q, k, v, out, lse, g, causal: bool = False,
             jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qr, kr, vr, dor, lser, delta)
     return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
             dv.reshape(b, h, tk, d))

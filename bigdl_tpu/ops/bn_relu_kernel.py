@@ -137,6 +137,7 @@ def bn_relu_forward(x2, scale, shift, relu: bool = True,
         out_specs=pl.BlockSpec((tn, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, c), out_dtype),
         interpret=interpret,
+        name="bn_relu_fwd",
     )(x2, _row(scale), _row(shift))
 
 
@@ -192,6 +193,7 @@ def bn_relu_backward(x2, scale, shift, g2, relu: bool = True,
             jax.ShapeDtypeStruct((n_tiles, 1, c), jnp.float32),
         ],
         interpret=interpret,
+        name="bn_relu_bwd",
     )(x2, _row(scale), _row(shift), g2)
     return dx, jnp.sum(ds_part, axis=(0, 1)), jnp.sum(db_part, axis=(0, 1))
 

@@ -148,6 +148,7 @@ def stem_conv_forward(x2, wk, bias, pad_front: int, pad_rear: int,
                                lambda i, j, kw: (i, j, kw, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, w, n_out), x2.dtype),
         interpret=interpret,
+        name="stem_conv",
     )(xs, w2, bvec)
     return out
 

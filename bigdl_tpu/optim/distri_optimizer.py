@@ -429,11 +429,12 @@ class DistriOptimizer(BaseOptimizer):
         pending = fetch_and_place()
         while pending is not None and not self.end_trigger(driver_state):
             batch, x, y = pending
-            # chaos hook: a no-op unless a FaultInjector is installed —
-            # lets tests crash the loop at an exact iteration and drive
-            # the retry/reload machinery deterministically
-            faults.fire("train.step", step=driver_state["neval"] + 1)
-            lr = self.optim_method.current_lr()
+            with self._span("step prepare"):
+                # chaos hook: a no-op unless a FaultInjector is installed
+                # — lets tests crash the loop at an exact iteration and
+                # drive the retry/reload machinery deterministically
+                faults.fire("train.step", step=driver_state["neval"] + 1)
+                lr = self.optim_method.current_lr()
             with self._span("step dispatch", step=driver_state["neval"] + 1):
                 params, opt_state, new_ms, loss, rng_dev, aux = step(
                     params, opt_state, model_state, x, y, lr, rng_dev)
@@ -450,74 +451,81 @@ class DistriOptimizer(BaseOptimizer):
                 # every dispatched step up to here has completed
                 with self._span("loss sync"):
                     loss_val = float(loss)
-            model_state = new_ms  # step returns the FULL merged state
+            # the host's tail of the step, one span whether it synced or
+            # not: counters, the sync's records and log line, summaries,
+            # epoch roll-over, validation, checkpoint, hook
+            with self._span("step bookkeeping"):
+                model_state = new_ms  # step returns the FULL merged state
 
-            n = batch.size() * num_hosts  # global records this step
-            driver_state["neval"] += 1
-            driver_state["recordsProcessedThisEpoch"] += n
-            driver_state["loss"] = loss_val
-            win.add(n)
-            if do_sync:
-                # throughput + per-iteration compute time over the sync
-                # window: exact wall time between device-drained points,
-                # valid for any sync_interval (per iteration when 1,
-                # reference semantics). The window counts ONLY
-                # dispatch+device time — it restarts after the
-                # validation/checkpoint/hook tail at the iteration end —
-                # and recording the metric only at sync keeps "computing
-                # time average" a true per-step figure (per-dispatch
-                # timing is meaningless under async).
-                throughput = win.throughput(self.metrics)
-                self._observe_sync(driver_state, loss_val, lr, throughput,
-                                   win.step_time_s, n, aux_pending)
-                logger.info(
-                    f"[Epoch {driver_state['epoch'] + 1} "
-                    f"{driver_state['recordsProcessedThisEpoch']}/"
-                    f"{epoch_size}]"
-                    f"[Iteration {driver_state['neval']}] Training cost "
-                    f"{loss_val}. Throughput is {throughput} "
-                    f"records/second. ({n_dev} devices)")
-            if do_sync and self.train_summary is not None:
-                it = driver_state["neval"]
-                self.train_summary.add_scalar("Loss", loss_val, it)
-                self.train_summary.add_scalar("LearningRate",
-                                              self._lr_scalar(lr), it)
-                self.train_summary.add_scalar("Throughput", throughput, it)
-                # Parameters histograms only behind an explicit trigger —
-                # they pull every sharded weight to host
-                # (AbstractOptimizer.scala:47-92)
-                trig = getattr(self.train_summary, "get_summary_trigger",
-                               lambda _n: None)("Parameters")
-                if trig is not None and trig(driver_state):
-                    host = jax.device_get(params)
-                    flat = jax.tree_util.tree_flatten_with_path(host)[0]
-                    for path, leaf in flat:
-                        tag = "/".join(
-                            str(getattr(p, "key", getattr(p, "idx", p)))
-                            for p in path)
-                        self.train_summary.add_histogram(tag, leaf, it)
+                n = batch.size() * num_hosts  # global records this step
+                driver_state["neval"] += 1
+                driver_state["recordsProcessedThisEpoch"] += n
+                driver_state["loss"] = loss_val
+                win.add(n)
+                if do_sync:
+                    # throughput + per-iteration compute time over the sync
+                    # window: exact wall time between device-drained points,
+                    # valid for any sync_interval (per iteration when 1,
+                    # reference semantics). The window counts ONLY
+                    # dispatch+device time — it restarts after the
+                    # validation/checkpoint/hook tail at the iteration end —
+                    # and recording the metric only at sync keeps "computing
+                    # time average" a true per-step figure (per-dispatch
+                    # timing is meaningless under async).
+                    throughput = win.throughput(self.metrics)
+                    self._observe_sync(driver_state, loss_val, lr, throughput,
+                                       win.step_time_s, n, aux_pending)
+                    logger.info(
+                        f"[Epoch {driver_state['epoch'] + 1} "
+                        f"{driver_state['recordsProcessedThisEpoch']}/"
+                        f"{epoch_size}]"
+                        f"[Iteration {driver_state['neval']}] Training cost "
+                        f"{loss_val}. Throughput is {throughput} "
+                        f"records/second. ({n_dev} devices)")
+                if do_sync and self.train_summary is not None:
+                    it = driver_state["neval"]
+                    self.train_summary.add_scalar("Loss", loss_val, it)
+                    self.train_summary.add_scalar("LearningRate",
+                                                  self._lr_scalar(lr), it)
+                    self.train_summary.add_scalar("Throughput",
+                                                  throughput, it)
+                    # Parameters histograms only behind an explicit trigger —
+                    # they pull every sharded weight to host
+                    # (AbstractOptimizer.scala:47-92)
+                    trig = getattr(self.train_summary, "get_summary_trigger",
+                                   lambda _n: None)("Parameters")
+                    if trig is not None and trig(driver_state):
+                        host = jax.device_get(params)
+                        flat = jax.tree_util.tree_flatten_with_path(host)[0]
+                        for path, leaf in flat:
+                            tag = "/".join(
+                                str(getattr(p, "key", getattr(p, "idx", p)))
+                                for p in path)
+                            self.train_summary.add_histogram(tag, leaf, it)
 
-            if driver_state["recordsProcessedThisEpoch"] >= epoch_size:
-                driver_state["epoch"] += 1
-                driver_state["recordsProcessedThisEpoch"] = 0
-                self._shuffle_dataset()
+                if driver_state["recordsProcessedThisEpoch"] >= epoch_size:
+                    driver_state["epoch"] += 1
+                    driver_state["recordsProcessedThisEpoch"] = 0
+                    self._shuffle_dataset()
 
-            with self._span("validation"):
-                self._validate(params, model_state, driver_state)
-            if self.checkpoint_trigger and self.checkpoint_trigger(driver_state):
-                with Timer(self.metrics, "checkpoint time"), \
-                        self._span("checkpoint"):
-                    self._save_checkpoint(params, model_state,
-                                          tag=f"iter{driver_state['neval']}",
-                                          opt_slots=opt_state)
-            if self.iteration_hook is not None:
-                self.iteration_hook(driver_state)
-            if self._check_preemption(params, model_state, opt_state,
-                                      driver_state, loss):
-                preempted = True
-                break
-            if do_sync:
-                win.restart()  # exclude the tail work from the next window
+                with self._span("validation"):
+                    self._validate(params, model_state, driver_state)
+                if self.checkpoint_trigger \
+                        and self.checkpoint_trigger(driver_state):
+                    with Timer(self.metrics, "checkpoint time"), \
+                            self._span("checkpoint"):
+                        self._save_checkpoint(
+                            params, model_state,
+                            tag=f"iter{driver_state['neval']}",
+                            opt_slots=opt_state)
+                if self.iteration_hook is not None:
+                    self.iteration_hook(driver_state)
+                if self._check_preemption(params, model_state, opt_state,
+                                          driver_state, loss):
+                    preempted = True
+                    break
+                if do_sync:
+                    win.restart()  # exclude the tail work from the next window
 
         if sync_every > 1 and loss is not None and \
                 driver_state["neval"] % sync_every != 0:
@@ -532,10 +540,11 @@ class DistriOptimizer(BaseOptimizer):
         # persist the advanced rng chain so a subsequent optimize() call
         # (resume / train-more) continues the dropout/noise stream instead
         # of replaying it (LocalOptimizer advances self.rng the same way)
-        self.rng = jax.device_get(rng_dev)
-        # gather back to host (reference getModel:646 pulls partitions)
-        self.model.set_params(jax.device_get(params))
-        self.model._state = jax.device_get(model_state)
+        with self._span("gather params"):
+            self.rng = jax.device_get(rng_dev)
+            # gather back to host (reference getModel:646 pulls partitions)
+            self.model.set_params(jax.device_get(params))
+            self.model._state = jax.device_get(model_state)
         return self.model
 
 
@@ -898,16 +907,18 @@ class DistriOptimizer(BaseOptimizer):
                 break
             step_no = driver_state["neval"] + 1
             try:
-                faults.fire("train.step", step=step_no)
-                faults.fire("mesh.device_loss", step=step_no,
-                            n_active=plan.n_active)
-                lr = self.optim_method.current_lr()
-                rng, step_rng = jax.random.split(rng)
-                # shard rng streams key off the LOGICAL index — a shard's
-                # dropout/noise draw survives remapping to another device
-                shard_rngs = jax.random.split(step_rng, R0)
-                xs = controller.split_batch(batch.get_input())
-                ys = controller.split_batch(batch.get_target())
+                with self._span("step prepare"):
+                    faults.fire("train.step", step=step_no)
+                    faults.fire("mesh.device_loss", step=step_no,
+                                n_active=plan.n_active)
+                    lr = self.optim_method.current_lr()
+                    rng, step_rng = jax.random.split(rng)
+                    # shard rng streams key off the LOGICAL index — a
+                    # shard's dropout/noise draw survives remapping to
+                    # another device
+                    shard_rngs = jax.random.split(step_rng, R0)
+                    xs = controller.split_batch(batch.get_input())
+                    ys = controller.split_batch(batch.get_target())
                 with self._span("step dispatch", step=step_no):
                     per_dev = {}
                     for d in plan.devices:
@@ -1037,101 +1048,105 @@ class DistriOptimizer(BaseOptimizer):
                 win.restart()
                 continue
 
-            model_state = merge_state(model_state, new_ms)
-            n = batch.size() * num_hosts
-            driver_state["neval"] += 1
-            driver_state["recordsProcessedThisEpoch"] += n
-            driver_state["loss"] = loss_val
-            win.add(n)
-            if do_sync:
-                throughput = win.throughput(self.metrics)
-                self._observe_sync(driver_state, loss_val, lr, throughput,
-                                   win.step_time_s, n, [])
-                logger.info(
-                    f"[Epoch {driver_state['epoch'] + 1} "
-                    f"{driver_state['recordsProcessedThisEpoch']}/"
-                    f"{epoch_size}]"
-                    f"[Iteration {driver_state['neval']}] Training cost "
-                    f"{loss_val}. Throughput is {throughput} "
-                    f"records/second. ({plan.n_active} devices, elastic)")
-                if self.train_summary is not None:
-                    it = driver_state["neval"]
-                    self.train_summary.add_scalar("Loss", loss_val, it)
-                    self.train_summary.add_scalar(
-                        "LearningRate", self._lr_scalar(lr), it)
-                    self.train_summary.add_scalar("Throughput",
-                                                  throughput, it)
-
-            boundary = driver_state["recordsProcessedThisEpoch"] >= \
-                epoch_size
-            if boundary:
-                driver_state["epoch"] += 1
-                driver_state["recordsProcessedThisEpoch"] = 0
-                self._shuffle_dataset()
-
-            with self._span("validation"):
-                self._validate(params, model_state, driver_state)
-            if self.checkpoint_trigger and \
-                    self.checkpoint_trigger(driver_state):
-                with Timer(self.metrics, "checkpoint time"), \
-                        self._span("checkpoint"):
-                    self._save_checkpoint(
-                        params, model_state,
-                        tag=f"iter{driver_state['neval']}",
-                        opt_slots=opt_state)
-            if self.iteration_hook is not None:
-                self.iteration_hook(driver_state)
-            if self._check_preemption(params, model_state, opt_state,
-                                      driver_state, loss):
-                preempted = True
-                break
-
-            if do_sync or boundary:
-                # commit: this state is now the rollback target. Epoch
-                # boundaries ALWAYS commit so a rollback never replays a
-                # dataset reshuffle (the shuffle above already consumed
-                # the dataset rng).
-                committed = commit()
-                window_batches.clear()
-                recoveries = 0  # committed progress past the failures
-                # boundary replan: lease expiries shrink proactively,
-                # revived workers grow the fleet back — both at a
-                # committed point, so no rollback is needed
-                registry.sweep()
-                new_plan = controller.plan(registry.alive_devices(),
-                                           total_dev)
-                if new_plan.devices != plan.devices:
-                    grow = new_plan.n_active > plan.n_active
-                    if self.telemetry is not None:
-                        self.telemetry.event(
-                            "elastic_grow" if grow else "elastic_shrink",
-                            step=driver_state["neval"],
-                            n_active_before=plan.n_active,
-                            n_active=new_plan.n_active,
-                            alive_workers=len(registry.alive()),
-                            degraded_capacity=new_plan.degraded_capacity)
+            # the host's tail of the step (see the SPMD loop), with the
+            # elastic commit and boundary replan inside it
+            with self._span("step bookkeeping"):
+                model_state = merge_state(model_state, new_ms)
+                n = batch.size() * num_hosts
+                driver_state["neval"] += 1
+                driver_state["recordsProcessedThisEpoch"] += n
+                driver_state["loss"] = loss_val
+                win.add(n)
+                if do_sync:
+                    throughput = win.throughput(self.metrics)
+                    self._observe_sync(driver_state, loss_val, lr, throughput,
+                                       win.step_time_s, n, [])
                     logger.info(
-                        "elastic %s at step %d: %d -> %d active devices",
-                        "grow" if grow else "shrink",
-                        driver_state["neval"], plan.n_active,
-                        new_plan.n_active)
-                    plan = new_plan
-                    if plan.lead is not lead:
-                        params = place(params, plan.lead)
-                        opt_state = place(opt_state, plan.lead)
-                        model_state = place(model_state, plan.lead)
-                        lead = plan.lead
-            if do_sync:
-                win.restart()
+                        f"[Epoch {driver_state['epoch'] + 1} "
+                        f"{driver_state['recordsProcessedThisEpoch']}/"
+                        f"{epoch_size}]"
+                        f"[Iteration {driver_state['neval']}] Training cost "
+                        f"{loss_val}. Throughput is {throughput} "
+                        f"records/second. ({plan.n_active} devices, elastic)")
+                    if self.train_summary is not None:
+                        it = driver_state["neval"]
+                        self.train_summary.add_scalar("Loss", loss_val, it)
+                        self.train_summary.add_scalar(
+                            "LearningRate", self._lr_scalar(lr), it)
+                        self.train_summary.add_scalar("Throughput",
+                                                      throughput, it)
+
+                boundary = driver_state["recordsProcessedThisEpoch"] >= \
+                    epoch_size
+                if boundary:
+                    driver_state["epoch"] += 1
+                    driver_state["recordsProcessedThisEpoch"] = 0
+                    self._shuffle_dataset()
+
+                with self._span("validation"):
+                    self._validate(params, model_state, driver_state)
+                if self.checkpoint_trigger and \
+                        self.checkpoint_trigger(driver_state):
+                    with Timer(self.metrics, "checkpoint time"), \
+                            self._span("checkpoint"):
+                        self._save_checkpoint(
+                            params, model_state,
+                            tag=f"iter{driver_state['neval']}",
+                            opt_slots=opt_state)
+                if self.iteration_hook is not None:
+                    self.iteration_hook(driver_state)
+                if self._check_preemption(params, model_state, opt_state,
+                                          driver_state, loss):
+                    preempted = True
+                    break
+
+                if do_sync or boundary:
+                    # commit: this state is now the rollback target. Epoch
+                    # boundaries ALWAYS commit so a rollback never replays a
+                    # dataset reshuffle (the shuffle above already consumed
+                    # the dataset rng).
+                    committed = commit()
+                    window_batches.clear()
+                    recoveries = 0  # committed progress past the failures
+                    # boundary replan: lease expiries shrink proactively,
+                    # revived workers grow the fleet back — both at a
+                    # committed point, so no rollback is needed
+                    registry.sweep()
+                    new_plan = controller.plan(registry.alive_devices(),
+                                               total_dev)
+                    if new_plan.devices != plan.devices:
+                        grow = new_plan.n_active > plan.n_active
+                        if self.telemetry is not None:
+                            self.telemetry.event(
+                                "elastic_grow" if grow else "elastic_shrink",
+                                step=driver_state["neval"],
+                                n_active_before=plan.n_active,
+                                n_active=new_plan.n_active,
+                                alive_workers=len(registry.alive()),
+                                degraded_capacity=new_plan.degraded_capacity)
+                        logger.info(
+                            "elastic %s at step %d: %d -> %d active devices",
+                            "grow" if grow else "shrink",
+                            driver_state["neval"], plan.n_active,
+                            new_plan.n_active)
+                        plan = new_plan
+                        if plan.lead is not lead:
+                            params = place(params, plan.lead)
+                            opt_state = place(opt_state, plan.lead)
+                            model_state = place(model_state, plan.lead)
+                            lead = plan.lead
+                if do_sync:
+                    win.restart()
 
         if sync_every > 1 and loss is not None and \
                 driver_state["neval"] % sync_every != 0:
             driver_state["loss"] = loss_val = float(loss)
         if not preempted:
             self._telemetry_run_end(driver_state)
-        self.rng = jax.device_get(rng)
-        self.model.set_params(jax.device_get(params))
-        self.model._state = jax.device_get(model_state)
+        with self._span("gather params"):
+            self.rng = jax.device_get(rng)
+            self.model.set_params(jax.device_get(params))
+            self.model._state = jax.device_get(model_state)
         return self.model
 
 

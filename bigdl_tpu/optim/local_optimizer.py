@@ -1091,11 +1091,12 @@ class LocalOptimizer(BaseOptimizer):
         pending = fetch_and_place()
         while pending is not None and not self.end_trigger(driver_state):
             batch, x, y = pending
-            # chaos hook (resilience/faults.py): no-op unless a
-            # FaultInjector is installed
-            faults.fire("train.step", step=driver_state["neval"] + 1)
-            lr = self.optim_method.current_lr()
-            self.rng, step_rng = jax.random.split(self.rng)
+            with self._span("step prepare"):
+                # chaos hook (resilience/faults.py): no-op unless a
+                # FaultInjector is installed
+                faults.fire("train.step", step=driver_state["neval"] + 1)
+                lr = self.optim_method.current_lr()
+                self.rng, step_rng = jax.random.split(self.rng)
             with self._span("step dispatch", step=driver_state["neval"] + 1):
                 params, opt_state, new_ms, loss, aux = step(
                     params, opt_state, model_state, x, y, lr, step_rng)
@@ -1106,65 +1107,73 @@ class LocalOptimizer(BaseOptimizer):
             if do_sync:
                 with self._span("loss sync"):
                     loss_val = float(loss)  # waits for the step to finish
-            model_state = new_ms  # step returns the FULL merged state
+            # the host's tail of the step, one span whether it synced or
+            # not: counters, the sync's records and log line, summaries,
+            # epoch roll-over, validation, checkpoint, hook
+            with self._span("step bookkeeping"):
+                model_state = new_ms  # step returns the FULL merged state
 
-            n = batch.size()
-            driver_state["neval"] += 1
-            driver_state["recordsProcessedThisEpoch"] += n
-            driver_state["loss"] = loss_val
-            win.add(n)
-            if do_sync:
-                # per-window figures: dispatch+device only (the window
-                # restarts AFTER the validation/checkpoint/hook tail)
-                throughput = win.throughput(self.metrics)
-                self._observe_sync(driver_state, loss_val, lr, throughput,
-                                   win.step_time_s, n, aux_pending)
-                logger.info(
-                    f"[Epoch {driver_state['epoch'] + 1} "
-                    f"{driver_state['recordsProcessedThisEpoch']}/"
-                    f"{epoch_size}]"
-                    f"[Iteration {driver_state['neval']}] Training cost "
-                    f"{loss_val}. Throughput is {throughput} "
-                    f"records/second. ")
-            if do_sync and self.train_summary is not None:
-                it = driver_state["neval"]
-                self.train_summary.add_scalar("Loss", loss_val, it)
-                self.train_summary.add_scalar("LearningRate",
-                                              self._lr_scalar(lr), it)
-                self.train_summary.add_scalar("Throughput", throughput, it)
-                # Parameters histograms only behind an explicit trigger —
-                # they pull every weight to host (AbstractOptimizer.scala:47-92)
-                trig = getattr(self.train_summary, "get_summary_trigger",
-                               lambda _n: None)("Parameters")
-                if trig is not None and trig(driver_state):
-                    import jax as _jax
-                    flat = _jax.tree_util.tree_flatten_with_path(params)[0]
-                    for path, leaf in flat:
-                        tag = "/".join(
-                            str(getattr(p, "key", getattr(p, "idx", p)))
-                            for p in path)
-                        self.train_summary.add_histogram(tag, leaf, it)
+                n = batch.size()
+                driver_state["neval"] += 1
+                driver_state["recordsProcessedThisEpoch"] += n
+                driver_state["loss"] = loss_val
+                win.add(n)
+                if do_sync:
+                    # per-window figures: dispatch+device only (the window
+                    # restarts AFTER the validation/checkpoint/hook tail)
+                    throughput = win.throughput(self.metrics)
+                    self._observe_sync(driver_state, loss_val, lr, throughput,
+                                       win.step_time_s, n, aux_pending)
+                    logger.info(
+                        f"[Epoch {driver_state['epoch'] + 1} "
+                        f"{driver_state['recordsProcessedThisEpoch']}/"
+                        f"{epoch_size}]"
+                        f"[Iteration {driver_state['neval']}] Training cost "
+                        f"{loss_val}. Throughput is {throughput} "
+                        f"records/second. ")
+                if do_sync and self.train_summary is not None:
+                    it = driver_state["neval"]
+                    self.train_summary.add_scalar("Loss", loss_val, it)
+                    self.train_summary.add_scalar("LearningRate",
+                                                  self._lr_scalar(lr), it)
+                    self.train_summary.add_scalar("Throughput",
+                                                  throughput, it)
+                    # Parameters histograms only behind an explicit
+                    # trigger — they pull every weight to host
+                    # (AbstractOptimizer.scala:47-92)
+                    trig = getattr(self.train_summary, "get_summary_trigger",
+                                   lambda _n: None)("Parameters")
+                    if trig is not None and trig(driver_state):
+                        import jax as _jax
+                        flat = _jax.tree_util.tree_flatten_with_path(params)[0]
+                        for path, leaf in flat:
+                            tag = "/".join(
+                                str(getattr(p, "key", getattr(p, "idx", p)))
+                                for p in path)
+                            self.train_summary.add_histogram(tag, leaf, it)
 
-            if driver_state["recordsProcessedThisEpoch"] >= epoch_size:
-                driver_state["epoch"] += 1
-                driver_state["recordsProcessedThisEpoch"] = 0
-                self._shuffle_dataset()
+                if driver_state["recordsProcessedThisEpoch"] >= epoch_size:
+                    driver_state["epoch"] += 1
+                    driver_state["recordsProcessedThisEpoch"] = 0
+                    self._shuffle_dataset()
 
-            with self._span("validation"):
-                self._validate(params, model_state, driver_state)
-            if self.checkpoint_trigger and self.checkpoint_trigger(driver_state):
-                with self._span("checkpoint"):
-                    self._save_checkpoint(params, model_state,
-                                          tag=f"iter{driver_state['neval']}",
-                                          opt_slots=opt_state)
-            if self.iteration_hook is not None:
-                self.iteration_hook(driver_state)
-            if self._check_preemption(params, model_state, opt_state,
-                                      driver_state, loss):
-                preempted = True
-                break
-            if do_sync:
-                win.restart()  # exclude the tail work from the next window
+                with self._span("validation"):
+                    self._validate(params, model_state, driver_state)
+                if self.checkpoint_trigger \
+                        and self.checkpoint_trigger(driver_state):
+                    with self._span("checkpoint"):
+                        self._save_checkpoint(
+                            params, model_state,
+                            tag=f"iter{driver_state['neval']}",
+                            opt_slots=opt_state)
+                if self.iteration_hook is not None:
+                    self.iteration_hook(driver_state)
+                if self._check_preemption(params, model_state, opt_state,
+                                          driver_state, loss):
+                    preempted = True
+                    break
+                if do_sync:
+                    win.restart()  # exclude the tail work from the next window
 
         if sync_every > 1 and loss is not None and \
                 driver_state["neval"] % sync_every != 0:

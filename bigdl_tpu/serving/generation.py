@@ -76,6 +76,7 @@ from bigdl_tpu.resilience.breaker import HALF_OPEN
 from bigdl_tpu.serving.engine import (EngineClosedError, InferenceEngine,
                                       ServingError, ServingTimeoutError,
                                       ServingUnavailableError)
+from bigdl_tpu.serving.stats import WindowedHistogram
 
 logger = logging.getLogger("bigdl_tpu.serving")
 
@@ -112,7 +113,13 @@ class TokenStream:
       finished OK with fewer tokens — the index-based surface the
       fleet's exactly-once re-route wrapper builds on;
     - `cancel()` — stop generation at the next step boundary (the slot
-      frees; tokens already emitted stay readable).
+      frees; tokens already emitted stay readable);
+    - `token_times()` — when the ENGINE delivered each token
+      (`time.perf_counter()` seconds, one stamp a token): the
+      dispatcher reads the clock once a decode step (or prefill group)
+      and every token of that step carries the reading, so first-token
+      time and inter-token gaps can be had without a reader thread
+      waking once a token to stamp them.
 
     Thread-safe. `status` is None while streaming, then one of
     "ok"/"timeout"/"error"/"cancelled"/"shed". Token ids are 1-based
@@ -123,6 +130,11 @@ class TokenStream:
     def __init__(self):
         self._cond = threading.Condition()
         self._tokens: List[int] = []
+        # engine-side stamp of each token: the dispatcher sets `_t` to its
+        # one clock reading for the step before it puts the step's token
+        # (`_put` keeps its one argument); None: `_put` reads the clock
+        self._t: Optional[float] = None
+        self._times: List[float] = []
         self._status: Optional[str] = None
         self._exc: Optional[BaseException] = None
         self._cancelled = False
@@ -131,6 +143,8 @@ class TokenStream:
     def _put(self, tok: int):
         with self._cond:
             self._tokens.append(int(tok))
+            self._times.append(time.perf_counter() if self._t is None
+                               else self._t)
             self._cond.notify_all()
 
     def _finish(self, status: str = "ok",
@@ -172,6 +186,15 @@ class TokenStream:
     def token_count(self) -> int:
         with self._cond:
             return len(self._tokens)
+
+    def token_times(self) -> List[float]:
+        """The engine's delivery stamp of every token so far
+        (`time.perf_counter()` seconds; `token_times()[i]` belongs to
+        `get(i)`; non-decreasing). First-token time is
+        `token_times()[0]` minus the submit time, inter-token gaps are
+        `numpy.diff(token_times())`."""
+        with self._cond:
+            return list(self._times)
 
     def get(self, i: int, timeout: Optional[float] = None) -> Optional[int]:
         """Token `i` (blocking up to `timeout` seconds), or None when the
@@ -346,7 +369,18 @@ class GenerationEngine(InferenceEngine):
         self._g = {"tokens": 0, "decode_steps": 0, "decode_slot_steps": 0,
                    "prefill_requests": 0, "prefill_batches": 0,
                    "slot_joins": 0, "slot_leaves": 0,
-                   "prefill_s": 0.0, "decode_s": 0.0}
+                   "prefill_s": 0.0, "decode_s": 0.0,
+                   # the host's parts of a decode step: the jit call,
+                   # the blocking fetch (both inside decode_s) and the
+                   # delivery of the step's tokens after it
+                   "decode_dispatch_s": 0.0, "decode_fetch_s": 0.0,
+                   "decode_deliver_s": 0.0}
+        # engine-side token clock (seconds): first token after submit,
+        # one a request; gap between two decode steps' deliveries, ONE a
+        # step (every slot that decoded in both saw the same gap)
+        self.ttft = WindowedHistogram(hist_window)
+        self.token_gap = WindowedHistogram(hist_window)
+        self._t_decoded: Optional[float] = None  # last decode delivery
         mname = type(self.model).__name__
         model_ref = self.model
 
@@ -475,17 +509,20 @@ class GenerationEngine(InferenceEngine):
         try:
             while True:
                 with self._lock:
-                    while not self._q and self._active == 0 \
+                    if not self._q and self._active == 0 \
                             and not self._closing:
-                        self._not_empty.wait()
+                        with self._span("await request"):
+                            while not self._q and self._active == 0 \
+                                    and not self._closing:
+                                self._not_empty.wait()
                     if self._closing:
                         if not self._drain:
                             break
                         if not self._q and self._active == 0:
                             break
-                self._admit_into_slots()
                 # lint: unguarded-ok(the dispatcher thread is the only _active writer; _slock exists for cross-thread stats readers, not this owner-thread read)
-                if self._active:
+                with self._span("generate step", n_active=self._active):
+                    self._admit_into_slots()
                     self._decode_once()
         finally:
             self._abort_slots(EngineClosedError("engine closed"))
@@ -518,19 +555,21 @@ class GenerationEngine(InferenceEngine):
                 else:
                     take.append(r)
             self._not_full.notify_all()
-        for r, status, exc in dropped:
-            r.stream._finish(status, exc)
-            self._gen_trace(r, status)
-        if not take:
+        if not take and not dropped:
             return
-        groups: Dict[int, List[_GenRequest]] = {}
-        for r in take:
-            groups.setdefault(self._seq_bucket(r.prompt.size),
-                              []).append(r)
-        for t_pad, rs in groups.items():
-            for i in range(0, len(rs), self.max_batch_size):
-                self._prefill_group(rs[i:i + self.max_batch_size],
-                                    t_pad, free)
+        with self._span("admit requests", taken=len(take),
+                        dropped=len(dropped)):
+            for r, status, exc in dropped:
+                r.stream._finish(status, exc)
+                self._gen_trace(r, status)
+            groups: Dict[int, List[_GenRequest]] = {}
+            for r in take:
+                groups.setdefault(self._seq_bucket(r.prompt.size),
+                                  []).append(r)
+            for t_pad, rs in groups.items():
+                for i in range(0, len(rs), self.max_batch_size):
+                    self._prefill_group(rs[i:i + self.max_batch_size],
+                                        t_pad, free)
 
     def _prefill_group(self, rs: List[_GenRequest], t_pad: int,
                        free: List[int]):
@@ -607,7 +646,9 @@ class GenerationEngine(InferenceEngine):
             self._slot_req[r.slot] = r
             tok = int(first[j])
             r.tokens_out.append(tok)
+            r.stream._t = t1  # the group's one reading stamps the token
             r.stream._put(tok)
+            self.ttft.record(t1 - r.t_submit)
             if r.stream.cancelled:
                 self._retire(r, "cancelled")
             elif tok == r.eos_id or r.max_new_tokens == 1:
@@ -638,49 +679,70 @@ class GenerationEngine(InferenceEngine):
     def _decode_once(self):
         """ONE fixed-shape decode step over all slots; active slots
         advance a token, inactive slots ride along (fixed shape = zero
-        recompiles, whatever the churn)."""
+        recompiles, whatever the churn). No step while no slot is
+        active."""
         active = [r for r in self._slot_req if r is not None]
-        tokens = np.ones((self.slots,), np.int32)
-        positions = np.zeros((self.slots,), np.int32)
-        for r in active:
-            tokens[r.slot] = r.tokens_out[-1]
-            positions[r.slot] = r.pos
+        if not active:
+            return
+        with self._span("decode build"):
+            tokens = np.ones((self.slots,), np.int32)
+            positions = np.zeros((self.slots,), np.int32)
+            for r in active:
+                tokens[r.slot] = r.tokens_out[-1]
+                positions[r.slot] = r.pos
         t0 = time.perf_counter()
         try:
             with self._span("generate decode", n=len(active)):
                 faults.fire(SITE_DECODE, n=len(active))
-                nxt, self._cache = self._decode(self._params, self._cache,
-                                                tokens, positions)
-                nxt = np.asarray(nxt)
+                t_call = time.perf_counter()
+                with self._span("decode dispatch"):
+                    nxt, self._cache = self._decode(
+                        self._params, self._cache, tokens, positions)
+                t_fetch = time.perf_counter()
+                with self._span("decode fetch"):
+                    nxt = np.asarray(nxt)  # waits for the device
+                t_got = time.perf_counter()
         except Exception as e:
             # each active stream is counted "failed" ONCE, by _retire
             self._reset_cache(ServingError(f"decode step failed: {e!r}"))
             return
-        dt = time.perf_counter() - t0
-        self.batch_sizes.record(len(active))
-        info = self._decode.last_info
-        with self._slock:
-            self._g["decode_steps"] += 1
-            self._g["decode_slot_steps"] += len(active)
-            self._g["decode_s"] += dt
-            self._g["tokens"] += len(active)
-            if info is not None:
-                self._flops_total += info.get("flops") or 0.0
-                self._bytes_total += info.get("bytes_accessed") or 0.0
-            steps = self._g["decode_steps"]
-        for r in active:
-            tok = int(nxt[r.slot])
-            r.tokens_out.append(tok)
-            r.pos += 1
-            r.stream._put(tok)
-            if r.stream.cancelled:
-                self._retire(r, "cancelled")
-            elif tok == r.eos_id \
-                    or len(r.tokens_out) >= r.max_new_tokens:
-                self._retire(r, "ok")
-        if steps % self.emit_every == 0:
-            self._emit_safe({"type": "generation",
-                             **self.generation_stats()})
+        # the step's ONE delivery reading: the end of decode_s, the stamp
+        # of every token this step emits, the start of `decode deliver`
+        now = time.perf_counter()
+        with self._span("decode deliver", n=len(active)):
+            self.batch_sizes.record(len(active))
+            if self._t_decoded is not None:
+                self.token_gap.record(now - self._t_decoded)
+            self._t_decoded = now
+            info = self._decode.last_info
+            with self._slock:
+                self._g["decode_steps"] += 1
+                self._g["decode_slot_steps"] += len(active)
+                self._g["decode_s"] += now - t0
+                self._g["decode_dispatch_s"] += t_fetch - t_call
+                self._g["decode_fetch_s"] += t_got - t_fetch
+                self._g["tokens"] += len(active)
+                if info is not None:
+                    self._flops_total += info.get("flops") or 0.0
+                    self._bytes_total += info.get("bytes_accessed") or 0.0
+                steps = self._g["decode_steps"]
+            for r in active:
+                tok = int(nxt[r.slot])
+                r.tokens_out.append(tok)
+                r.pos += 1
+                r.stream._t = now  # the step's one reading
+                r.stream._put(tok)
+                if r.stream.cancelled:
+                    self._retire(r, "cancelled")
+                elif tok == r.eos_id \
+                        or len(r.tokens_out) >= r.max_new_tokens:
+                    self._retire(r, "ok")
+            if steps % self.emit_every == 0 and self.telemetry is not None:
+                self._emit_safe({"type": "generation",
+                                 **self.generation_stats()})
+            delivered = time.perf_counter() - now
+            with self._slock:
+                self._g["decode_deliver_s"] += delivered
 
     def _retire(self, r: _GenRequest, status: str,
                 exc: Optional[BaseException] = None):
@@ -690,10 +752,14 @@ class GenerationEngine(InferenceEngine):
         self._slot_req[r.slot] = None
         with self._slock:
             self._active -= 1
+            idle = self._active == 0
             self._g["slot_leaves"] += 1
             key = {"ok": "completed", "error": "failed",
                    "cancelled": "cancelled", "timeout": "timed_out"}
             self._n[key.get(status, "failed")] += 1
+        if idle:
+            # no slot is left to see the gap to the next decode step
+            self._t_decoded = None
         if status == "ok":
             self.latency.record(time.perf_counter() - r.t_submit)
         r.stream._finish(status, exc)
@@ -750,8 +816,13 @@ class GenerationEngine(InferenceEngine):
             "prefill_batches": g["prefill_batches"],
             "prefill_s_total": round(g["prefill_s"], 4),
             "decode_s_total": round(g["decode_s"], 4),
+            "decode_dispatch_s_total": round(g["decode_dispatch_s"], 4),
+            "decode_fetch_s_total": round(g["decode_fetch_s"], 4),
+            "decode_deliver_s_total": round(g["decode_deliver_s"], 4),
             "slot_joins": g["slot_joins"],
             "slot_leaves": g["slot_leaves"],
+            **self.ttft.snapshot("ttft_ms", scale=1e3),
+            **self.token_gap.snapshot("itl_ms", scale=1e3),
         }
 
     def _gen_trace(self, r: _GenRequest, status: str,
@@ -799,6 +870,14 @@ class GenerationEngine(InferenceEngine):
                "prompt_tokens": int(r.prompt.size),
                "arrival_offset_ms":
                    round((r.t_submit - self._t0_perf) * 1e3, 3)}
+        stamps = r.stream.token_times()
+        if stamps:
+            # the engine's own token clock (TokenStream.token_times)
+            rec["ttft_ms"] = round((stamps[0] - r.t_submit) * 1e3, 3)
+        if len(stamps) > 1:
+            gaps = np.diff(stamps) * 1e3
+            rec["itl_p50_ms"] = round(float(np.median(gaps)), 3)
+            rec["itl_max_ms"] = round(float(gaps.max()), 3)
         if r.session is not None:
             rec["session_id"] = str(r.session)
         if r.deadline_budget_ms is not None:

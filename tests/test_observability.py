@@ -424,3 +424,133 @@ class TestOptimizerIntegration:
         out = np.asarray(trained.forward(
             jnp.asarray(np.zeros((2, 6), np.float32)), training=False))
         assert np.isfinite(out).all()
+
+
+# ------------------------------------------------------------------ #
+# the train loops' spans: every phase of an iteration has one
+# ------------------------------------------------------------------ #
+_LOOPS = {"local": (LocalOptimizer, False), "distri": (DistriOptimizer, False),
+          "distri_elastic": (DistriOptimizer, True)}
+_ITERS = 6
+
+
+def _traced_loop(loop, sync):
+    opt_cls, elastic = _LOOPS[loop]
+    opt = opt_cls(_toy_model(), _OrderedDataSet(_toy_batches()),
+                  nn.ClassNLLCriterion())
+    opt.set_optim_method(optim.SGD(learning_rate=0.05))
+    opt.set_end_when(optim.max_iteration(_ITERS))
+    opt.set_sync_interval(sync)
+    if elastic:
+        opt.set_elastic()
+    tracer = SpanTracer()
+    opt.set_tracer(tracer)
+    opt.optimize()
+    spans = {}
+    for e in tracer.events:
+        if e["ph"] == "X":
+            spans.setdefault(e["name"], []).append(
+                (e["ts"], e["ts"] + e["dur"]))
+    return {n: sorted(v) for n, v in spans.items()}
+
+
+@pytest.fixture(scope="module", params=sorted(_LOOPS))
+def loop_spans(request):
+    return {"loop": request.param,
+            **{sync: _traced_loop(request.param, sync) for sync in (1, 3)}}
+
+
+def _inside(span, holders, eps=1.0):
+    return [h for h in holders
+            if h[0] - eps <= span[0] and span[1] <= h[1] + eps]
+
+
+class TestTrainLoopSpans:
+    @pytest.mark.parametrize("sync", [1, 3])
+    def test_loss_sync_once_a_synced_step(self, loop_spans, sync):
+        assert len(loop_spans[sync]["loss sync"]) == _ITERS // sync
+
+    @pytest.mark.parametrize("name", ["step prepare", "step dispatch",
+                                      "step bookkeeping", "validation"])
+    @pytest.mark.parametrize("sync", [1, 3])
+    def test_step_spans_once_a_step_synced_or_not(self, loop_spans, sync,
+                                                  name):
+        assert len(loop_spans[sync][name]) == _ITERS
+
+    @pytest.mark.parametrize("sync", [1, 3])
+    def test_loss_sync_is_one_call_with_no_span_inside(self, loop_spans,
+                                                       sync):
+        """`loss sync` is one span around the one `float(loss)`, after
+        its step's dispatch: the loop opens nothing inside it."""
+        s = loop_spans[sync]
+        syncs = s["loss sync"]
+        for one, dispatch in zip(syncs, s["step dispatch"][sync - 1::sync]):
+            assert dispatch[1] <= one[0] + 1.0
+        for name, spans in s.items():
+            if name != "loss sync":
+                assert not [x for x in spans if _inside(x, syncs)], name
+
+    @pytest.mark.parametrize("sync", [1, 3])
+    def test_bookkeeping_is_the_tail_of_the_step(self, loop_spans, sync):
+        """From the end of `loss sync` (or of the data fetch, on a step
+        that does not sync) to the end of the iteration: the next thing
+        the loop's lane shows is the next step's `step prepare`."""
+        s = loop_spans[sync]
+        books = s["step bookkeeping"]
+        for b, sync_span in zip(books[sync - 1::sync], s["loss sync"]):
+            assert sync_span[1] <= b[0] + 1.0
+        for b, dispatch in zip(books, s["step dispatch"]):
+            assert dispatch[1] <= b[0] + 1.0
+        for b, nxt in zip(books, s["step prepare"][1:]):
+            assert b[1] <= nxt[0] + 1.0
+        for v in s["validation"]:
+            assert len(_inside(v, books)) == 1
+        # nothing else of the loop's spans begins inside a tail
+        for name in ("step prepare", "step dispatch", "loss sync",
+                     "data fetch"):
+            assert not [x for x in s[name] if _inside(x, books)], name
+
+    @pytest.mark.parametrize("sync", [1, 3])
+    def test_the_final_gather_has_a_span(self, loop_spans, sync):
+        """The distributed loops end by pulling parameters and state to
+        the host, device idle: after the last tail, under a span. The
+        local loop leaves them on the device and has none."""
+        s = loop_spans[sync]
+        gathers = s.get("gather params", [])
+        assert len(gathers) == (0 if loop_spans["loop"] == "local" else 1)
+        for g in gathers:
+            assert s["step bookkeeping"][-1][1] <= g[0] + 1.0
+
+    @pytest.mark.parametrize("loop", sorted(_LOOPS))
+    def test_a_tracer_changes_no_call_to_the_device(self, loop, monkeypatch):
+        """Traced and untraced, the loop syncs the same way: a tracer
+        adds no `block_until_ready` (the measured path is the path a
+        user runs)."""
+        calls = []
+        wait = jax.block_until_ready
+
+        def counted(x):
+            calls.append(1)
+            return wait(x)
+        monkeypatch.setattr(jax, "block_until_ready", counted)
+        opt_cls, elastic = _LOOPS[loop]
+        counts = []
+        for tracer in (None, SpanTracer()):
+            opt = TestOptimizerIntegration()._run(opt_cls, iters=3)
+            if elastic:
+                opt.set_elastic()
+            if tracer is not None:
+                opt.set_tracer(tracer)
+            del calls[:]
+            opt.optimize()
+            counts.append(len(calls))
+        assert counts[0] == counts[1]
+
+    def test_no_tracer_builds_no_span(self, monkeypatch):
+        def boom(self, *a, **k):
+            raise AssertionError("a span was built with no tracer attached")
+        monkeypatch.setattr(SpanTracer, "span", boom)
+        for opt_cls in (LocalOptimizer, DistriOptimizer):
+            opt = TestOptimizerIntegration()._run(opt_cls, iters=3)
+            opt.optimize()
+            assert opt.optim_method.state["neval"] == 3
