@@ -267,6 +267,11 @@ RECORD_SCHEMAS: Dict[str, Dict] = {
                      "decode_dispatch_s_total": _NUM,
                      "decode_fetch_s_total": _NUM,
                      "decode_deliver_s_total": _NUM,
+                     # the one-step decode pipeline: steps dispatched
+                     # before the step before was fetched; slot-steps
+                     # computed for a request that had already ended
+                     "decode_overlapped_steps": int,
+                     "decode_discarded_slot_steps": int,
                      # engine-side token clock over the recent window
                      # (WindowedHistogram.snapshot: quantiles absent
                      # before the first observation)

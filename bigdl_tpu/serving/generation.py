@@ -22,7 +22,11 @@ is the autoregressive tier on two compiled paths:
   written in place, and the next greedy token comes out. O(1) memory and
   step cost per token — never a per-token concat, never a retrace.
   Steady-state decode emits ZERO new `compile` records regardless of
-  join/leave churn or token position (suite-asserted).
+  join/leave churn or token position (suite-asserted). The loop is a
+  pipeline ONE step deep: a step's tokens stay on the device as the
+  next step's input, so step n+1 is dispatched BEFORE the host fetches
+  and delivers step n — the jit call, the fetch's wake-up and the
+  delivery run while the chip decodes.
 
 **Continuous batching**: requests join a free slot as soon as their
 prefill lands and leave at EOS / max-tokens *between* decode steps — no
@@ -65,7 +69,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -268,9 +272,15 @@ class _GenRequest:
         self.t_prefill1: Optional[float] = None  # prefill landed
         self.tokens_out: List[int] = []
         self.slot: Optional[int] = None
-        self.pos = 0  # next decode position (= prompt length after prefill)
+        self.pos = 0  # next decode position (= prompt length after prefill;
+        # advances when a step is dispatched, not when it is delivered)
         self.session = session    # echoed into the trace record
         self.deadline_budget_ms = deadline_budget_ms  # as GIVEN, not spent
+
+    def asked(self) -> int:
+        """Tokens the device has been asked for: the prefill's and one a
+        dispatched decode step, delivered or still in flight."""
+        return self.pos - self.prompt.size + 1
 
 
 class GenerationEngine(InferenceEngine):
@@ -366,7 +376,16 @@ class GenerationEngine(InferenceEngine):
         # _slock for stats()/generation_stats() readers
         self._slot_req: List[Optional[_GenRequest]] = [None] * self.slots
         self._active = 0
+        # the decode step dispatched and not yet fetched: its [slots]
+        # tokens on the device, and the requests it was dispatched for
+        # (the slot table may have moved on by delivery)
+        self._flying: Optional[Tuple] = None
         self._g = {"tokens": 0, "decode_steps": 0, "decode_slot_steps": 0,
+                   # steps dispatched while the one before was not yet
+                   # fetched; slot-steps computed for a request that had
+                   # ended (EOS, cancel) by the time they were delivered
+                   "decode_overlapped_steps": 0,
+                   "decode_discarded_slot_steps": 0,
                    "prefill_requests": 0, "prefill_batches": 0,
                    "slot_joins": 0, "slot_leaves": 0,
                    "prefill_s": 0.0, "decode_s": 0.0,
@@ -383,15 +402,20 @@ class GenerationEngine(InferenceEngine):
         self._t_decoded: Optional[float] = None  # last decode delivery
         mname = type(self.model).__name__
         model_ref = self.model
+        import jax.numpy as jnp
+        # `prev` of a step with none before it (every slot is `fresh`
+        # then): a device array, so it shares the one decode signature
+        self._no_prev = jnp.ones((self.slots,), jnp.int32)
 
-        def _decode_fn(params, cache, tokens, positions):
-            import jax.numpy as jnp
+        def _decode_fn(params, cache, prev, tokens, fresh, positions):
+            # a slot's input is the step before's output, still on the
+            # device, unless the host holds its last token (`fresh`)
+            tokens = jnp.where(fresh, tokens, prev)
             logp, cache = model_ref.apply_step(params, tokens, cache,
                                                positions)
             return jnp.argmax(logp, axis=-1).astype(jnp.int32) + 1, cache
 
         def _prefill_fn(params, cache, tokens, slot_ids, lengths):
-            import jax.numpy as jnp
             logp, cache = model_ref.apply_prefill(params, tokens, cache,
                                                   slot_ids, lengths)
             return jnp.argmax(logp, axis=-1).astype(jnp.int32) + 1, cache
@@ -401,7 +425,8 @@ class GenerationEngine(InferenceEngine):
         # the token arrays alone (params/cache avals are fixed for life)
         self._decode = CompiledFunction(
             _decode_fn, label=f"serving.decode/{mname}",
-            telemetry=telemetry, sig_argnums=(2, 3), donate_argnums=(1,))
+            telemetry=telemetry, sig_argnums=(2, 3, 4, 5),
+            donate_argnums=(1,))
         self._prefill = CompiledFunction(
             _prefill_fn, label=f"serving.prefill/{mname}",
             telemetry=telemetry, sig_argnums=(2,), donate_argnums=(1,))
@@ -480,7 +505,8 @@ class GenerationEngine(InferenceEngine):
                 with self._slock:
                     self._compiled.add((self._gen_sig(t_pad), b))
         tok, scratch = self._decode(
-            self._params, scratch, np.ones((self.slots,), np.int32),
+            self._params, scratch, self._no_prev,
+            np.ones((self.slots,), np.int32), np.ones((self.slots,), bool),
             np.zeros((self.slots,), np.int32))
         np.asarray(tok)
         return self.compile_count()
@@ -677,59 +703,98 @@ class GenerationEngine(InferenceEngine):
             self._reset_cache(exc)
 
     def _decode_once(self):
-        """ONE fixed-shape decode step over all slots; active slots
-        advance a token, inactive slots ride along (fixed shape = zero
-        recompiles, whatever the churn). No step while no slot is
-        active."""
-        active = [r for r in self._slot_req if r is not None]
-        if not active:
+        """One turn of the decode pipeline: dispatch step n+1, THEN fetch
+        and deliver step n, so the host's share of a step (the jit call,
+        the fetch's wake-up, delivery) runs while the device decodes.
+
+        Step n+1 takes its tokens from step n's result on the device, so
+        its roster is settled before step n is seen: every active request
+        but those that step n completes by count. One that step n ends by
+        EOS, or that was cancelled, rides along once more and is dropped
+        at delivery; its stray K/V write lies beyond what a later
+        occupant of the slot attends to before overwriting it. Each step
+        is ONE fixed-shape program over all slots, inactive slots riding
+        along (zero recompiles, whatever the churn)."""
+        flying = self._flying
+        riders = [r for r in self._slot_req
+                  if r is not None and r.asked() < r.max_new_tokens]
+        if not riders and flying is None:
             return
-        with self._span("decode build"):
-            tokens = np.ones((self.slots,), np.int32)
-            positions = np.zeros((self.slots,), np.int32)
-            for r in active:
-                tokens[r.slot] = r.tokens_out[-1]
-                positions[r.slot] = r.pos
+        if riders:
+            with self._span("decode build"):
+                tokens = np.ones((self.slots,), np.int32)
+                fresh = np.ones((self.slots,), bool)
+                positions = np.zeros((self.slots,), np.int32)
+                for r in riders:
+                    if r.asked() == len(r.tokens_out):  # all delivered
+                        tokens[r.slot] = r.tokens_out[-1]
+                    else:  # its last token is step n's, on the device
+                        fresh[r.slot] = False
+                    positions[r.slot] = r.pos
+        self._flying = None  # step n is this turn's to fetch
         t0 = time.perf_counter()
+        dispatch_s = fetch_s = 0.0
         try:
-            with self._span("generate decode", n=len(active)):
-                faults.fire(SITE_DECODE, n=len(active))
-                t_call = time.perf_counter()
-                with self._span("decode dispatch"):
-                    nxt, self._cache = self._decode(
-                        self._params, self._cache, tokens, positions)
-                t_fetch = time.perf_counter()
-                with self._span("decode fetch"):
-                    nxt = np.asarray(nxt)  # waits for the device
-                t_got = time.perf_counter()
+            with self._span("generate decode", n=len(riders)):
+                if riders:
+                    faults.fire(SITE_DECODE, n=len(riders))
+                    t = time.perf_counter()
+                    with self._span("decode dispatch"):
+                        nxt, self._cache = self._decode(
+                            self._params, self._cache,
+                            self._no_prev if flying is None else flying[0],
+                            tokens, fresh, positions)
+                    dispatch_s = time.perf_counter() - t
+                    self._flying = (nxt, riders)
+                    for r in riders:
+                        r.pos += 1
+                if flying is not None:
+                    t = time.perf_counter()
+                    with self._span("decode fetch"):
+                        got = np.asarray(flying[0])  # waits for step n
+                    fetch_s = time.perf_counter() - t
         except Exception as e:
             # each active stream is counted "failed" ONCE, by _retire
             self._reset_cache(ServingError(f"decode step failed: {e!r}"))
             return
-        # the step's ONE delivery reading: the end of decode_s, the stamp
-        # of every token this step emits, the start of `decode deliver`
+        # the turn's ONE delivery reading: the end of decode_s, the stamp
+        # of every token step n emits, the start of `decode deliver`
         now = time.perf_counter()
-        with self._span("decode deliver", n=len(active)):
-            self.batch_sizes.record(len(active))
+        with self._slock:
+            self._g["decode_s"] += now - t0
+            self._g["decode_dispatch_s"] += dispatch_s
+            self._g["decode_fetch_s"] += fetch_s
+            if riders and flying is not None:
+                self._g["decode_overlapped_steps"] += 1
+        if flying is not None:
+            self._deliver(got, flying[1], now)
+
+    def _deliver(self, got: np.ndarray, roster: List[_GenRequest],
+                 now: float):
+        """Hand a fetched step's tokens to the requests it was dispatched
+        for. One that has left its slot since (EOS or cancellation seen a
+        step late; the slot may hold another request by now) gets
+        nothing, and its slot-step is counted as discarded."""
+        live = [r for r in roster if self._slot_req[r.slot] is r]
+        with self._span("decode deliver", n=len(live)):
+            self.batch_sizes.record(len(live))
             if self._t_decoded is not None:
                 self.token_gap.record(now - self._t_decoded)
             self._t_decoded = now
             info = self._decode.last_info
             with self._slock:
                 self._g["decode_steps"] += 1
-                self._g["decode_slot_steps"] += len(active)
-                self._g["decode_s"] += now - t0
-                self._g["decode_dispatch_s"] += t_fetch - t_call
-                self._g["decode_fetch_s"] += t_got - t_fetch
-                self._g["tokens"] += len(active)
+                self._g["decode_slot_steps"] += len(live)
+                self._g["decode_discarded_slot_steps"] += \
+                    len(roster) - len(live)
+                self._g["tokens"] += len(live)
                 if info is not None:
                     self._flops_total += info.get("flops") or 0.0
                     self._bytes_total += info.get("bytes_accessed") or 0.0
                 steps = self._g["decode_steps"]
-            for r in active:
-                tok = int(nxt[r.slot])
+            for r in live:
+                tok = int(got[r.slot])
                 r.tokens_out.append(tok)
-                r.pos += 1
                 r.stream._t = now  # the step's one reading
                 r.stream._put(tok)
                 if r.stream.cancelled:
@@ -737,6 +802,14 @@ class GenerationEngine(InferenceEngine):
                 elif tok == r.eos_id \
                         or len(r.tokens_out) >= r.max_new_tokens:
                     self._retire(r, "ok")
+            flying = self._flying
+            if flying is not None and not any(
+                    self._slot_req[r.slot] is r for r in flying[1]):
+                # all the step in flight was dispatched for have ended:
+                # nobody waits for it, so it is not fetched
+                with self._slock:
+                    self._g["decode_discarded_slot_steps"] += len(flying[1])
+                self._flying = None
             if steps % self.emit_every == 0 and self.telemetry is not None:
                 self._emit_safe({"type": "generation",
                                  **self.generation_stats()})
@@ -771,11 +844,13 @@ class GenerationEngine(InferenceEngine):
         execution: fail every active stream (their KV history is gone),
         reallocate, and keep serving fresh requests."""
         self._cache = self.model.init_cache(self.slots, self.max_len)
+        self._flying = None
         for r in list(self._slot_req):
             if r is not None:
                 self._retire(r, "error", exc)
 
     def _abort_slots(self, exc: BaseException):
+        self._flying = None
         for r in list(self._slot_req):
             if r is not None:
                 self._retire(r, "cancelled", exc)
@@ -819,6 +894,8 @@ class GenerationEngine(InferenceEngine):
             "decode_dispatch_s_total": round(g["decode_dispatch_s"], 4),
             "decode_fetch_s_total": round(g["decode_fetch_s"], 4),
             "decode_deliver_s_total": round(g["decode_deliver_s"], 4),
+            "decode_overlapped_steps": g["decode_overlapped_steps"],
+            "decode_discarded_slot_steps": g["decode_discarded_slot_steps"],
             "slot_joins": g["slot_joins"],
             "slot_leaves": g["slot_leaves"],
             **self.ttft.snapshot("ttft_ms", scale=1e3),

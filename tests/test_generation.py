@@ -267,9 +267,12 @@ class TestGenerationEngine:
     def test_eos_stops_early_and_is_emitted(self):
         m = small_model()
         params = m.ensure_params()
-        prompt = np.array([4, 9, 2], np.int32)
+        # greedy from this prompt starts [1, 61, 3, 7, ...]: the token at
+        # index 2 has not come before it
+        prompt = np.array([3, 5, 7], np.int32)
         ref = greedy_decode_reference(m, params, prompt, 8, pad_to=32)
         eos = ref[2]
+        assert ref.index(eos) == 2
         with GenerationEngine(m, slots=2, max_len=32) as eng:
             out = eng.generate(prompt, max_new_tokens=8,
                                eos_id=eos).result(60.0)
