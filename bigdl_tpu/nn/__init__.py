@@ -39,7 +39,7 @@ from bigdl_tpu.nn.pooling import (Pooler, ResizeBilinear, SpatialAveragePooling,
 from bigdl_tpu.nn.fusion import (fusible_activation, fusible_bn,
                                  fusion_enabled, fusion_scope, set_fusion)
 from bigdl_tpu.nn.normalization import (BatchNormalization, LayerNormalization,
-                                        Normalize, NormalizeScale,
+                                        Normalize, NormalizeScale, RMSNorm,
                                         SpatialBatchNormalization,
                                         SpatialContrastiveNormalization,
                                         SpatialDivisiveNormalization,
@@ -102,7 +102,8 @@ from bigdl_tpu.nn.criterion import (AbsCriterion, BCECriterion,
                                     TimeDistributedCriterion,
                                     TimeDistributedMaskCriterion,
                                     TransformerCriterion)
-from bigdl_tpu.nn.attention import (MultiHeadAttention,
+from bigdl_tpu.nn.attention import (GroupedQueryAttention,
+                                    MultiHeadAttention,
                                     ScaledDotProductAttention,
                                     TransformerBlock, rope)
 from bigdl_tpu.nn import initialization

@@ -12,13 +12,16 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
+from bigdl_tpu.nn import kv_cache
 from bigdl_tpu.nn.initialization import Xavier
 from bigdl_tpu.nn.module import ApplyContext, Module
 from bigdl_tpu.nn.normalization import LayerNormalization
 from bigdl_tpu.ops.attention_kernel import (blockwise_attention,
-                                            flash_attention, naive_attention)
+                                            causal_grouped_attention,
+                                            flash_attention,
+                                            grouped_attention,
+                                            naive_attention)
 
 
 def rope(x, positions=None, base: float = 10000.0):
@@ -42,28 +45,10 @@ def rope(x, positions=None, base: float = 10000.0):
     return out.reshape(b, h, t, d).astype(x.dtype)
 
 
-def cache_write(cache, new, positions):
-    """Write `new` [B, H, T, hd] into `cache` [B, H, L, hd] starting at
-    per-row sequence position `positions` [B] — a per-row
-    `lax.dynamic_update_slice`, so under donation the decode step updates
-    its preallocated KV buffers in place (O(1) memory and step cost per
-    token; never a per-token concat/retrace)."""
-    def one(c, n, p):
-        return lax.dynamic_update_slice(c, n, (0, p, 0))
-    return jax.vmap(one)(cache, new, positions)
-
-
-def cache_commit(cache, new, slot_ids):
-    """Commit per-request prefill K/V `new` [B, H, T, hd] into slots of a
-    fleet-wide cache [S, H, L, hd] at sequence position 0. Rows may
-    repeat (bucket padding replicates the last request's row INCLUDING
-    its slot id): the scan writes in request order, so a padded
-    duplicate rewrites identical values and the last write wins."""
-    def body(c, inp):
-        n, s = inp
-        return lax.dynamic_update_slice(c, n[None], (s, 0, 0, 0)), None
-    out, _ = lax.scan(body, cache, (new, slot_ids))
-    return out
+# the K/V cache's format has one owner, nn/kv_cache.py; these are its
+# write and commit under the names this module has always exported
+cache_write = kv_cache.write
+cache_commit = kv_cache.commit
 
 
 class ScaledDotProductAttention(Module):
@@ -184,11 +169,103 @@ class MultiHeadAttention(Module):
         q, k, v = self.project_qkv(params, x, positions=positions[:, None])
         k_cache = cache_write(k_cache, k, positions)
         v_cache = cache_write(v_cache, v, positions)
-        length = k_cache.shape[2]
-        mask = (jnp.arange(length)[None, :]
-                <= positions[:, None])[:, None, None, :]
+        mask = kv_cache.step_mask(k_cache.shape[2], positions)
         o = naive_attention(q, k_cache, v_cache, mask=mask)
         return self._finish(params, o), k_cache, v_cache
+
+
+class GroupedQueryAttention(Module):
+    """Causal self-attention whose sizes are its own: `n_head` query
+    heads of `head_dim` (which the model width does not fix) over
+    `n_kv_head` K/V heads (query head j reads K/V head
+    j // (n_head // n_kv_head)), no bias; `window` keeps the last
+    `window` positions (self included) or, None, all of them;
+    `rope_base` is the rotary base or, None, no positional encoding at
+    all. Input [B, T, E] in any float type: it is cast to the weights'
+    type for the projections, and the result is float32 (the output
+    projection's accumulator, unrounded), for a residual stream kept in
+    float32. The serving cache of a layer is the pair `init_cache`
+    gives: a ring of `window` positions or `max_len` deep
+    (nn/kv_cache.py)."""
+
+    def __init__(self, embed_dim: int, n_head: int, n_kv_head: int,
+                 head_dim: int, window: Optional[int] = None,
+                 rope_base: Optional[float] = None, name=None):
+        super().__init__(name)
+        if n_head % n_kv_head:
+            raise ValueError(
+                f"n_head {n_head} % n_kv_head {n_kv_head} != 0")
+        if window is not None and window < 1:
+            raise ValueError(f"window must be >= 1, got {window}")
+        self.e, self.h, self.hk, self.hd = embed_dim, n_head, n_kv_head, \
+            head_dim
+        self.window, self.rope_base = window, rope_base
+
+    def init(self, rng):
+        k1, k2, k3, k4 = jax.random.split(rng, 4)
+        xav = Xavier()
+        return {"wq": xav(k1, (self.e, self.h * self.hd)),
+                "wk": xav(k2, (self.e, self.hk * self.hd)),
+                "wv": xav(k3, (self.e, self.hk * self.hd)),
+                "wo": xav(k4, (self.h * self.hd, self.e))}
+
+    def project_qkv(self, params, x, positions=None):
+        """q [B, H, T, hd] and k, v [B, Hkv, T, hd], rotated at
+        `positions` ([T], [B, T], or None = `arange`) where the layer
+        has a rotary base."""
+        b, t, _ = x.shape
+        x = x.astype(params["wq"].dtype)  # a float32 residual stream
+
+        def heads(z, n):
+            return jnp.transpose(z.reshape(b, t, n, self.hd), (0, 2, 1, 3))
+        q = heads(x @ params["wq"], self.h)
+        k = heads(x @ params["wk"], self.hk)
+        v = heads(x @ params["wv"], self.hk)
+        if self.rope_base is not None:
+            q = rope(q, positions, self.rope_base)
+            k = rope(k, positions, self.rope_base)
+        return q, k, v
+
+    def _finish(self, params, o):  # [B, H, T, hd] -> [B, T, E] float32
+        b, h, t, hd = o.shape
+        return jnp.dot(jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, h * hd),
+                       params["wo"], preferred_element_type=jnp.float32)
+
+    def _scope(self):
+        return jax.named_scope("full attention" if self.window is None
+                               else "window attention")
+
+    def apply_prefill(self, params, x):
+        """(out [B, T, E], k, v [B, Hkv, T, hd]) of the whole sequence;
+        k and v are what a serving prefill commits."""
+        with self._scope():
+            q, k, v = self.project_qkv(params, x)
+            o = causal_grouped_attention(q, k, v, self.window)
+            return self._finish(params, o), k, v
+
+    def apply(self, params, input, ctx):
+        return self.apply_prefill(params, input)[0]
+
+    def init_cache(self, slots: int, max_len: int, dtype=jnp.float32):
+        return tuple(kv_cache.init(slots, self.hk, max_len, self.hd,
+                                   self.window, dtype) for _ in "kv")
+
+    def apply_step(self, params, x, k_cache, v_cache, positions):
+        """One new token a row: `x` [B, 1, E] at `positions` [B] against
+        the layer's cache. Writes the token's K/V, then the `group` query
+        heads of each K/V head read its cache once, under the mask its
+        kind of cache gives. Returns (out [B, 1, E], k_cache, v_cache)."""
+        with self._scope():
+            q, k, v = self.project_qkv(params, x,
+                                       positions=positions[:, None])
+            k_cache = kv_cache.write(k_cache, k.astype(k_cache.dtype),
+                                     positions, self.window)
+            v_cache = kv_cache.write(v_cache, v.astype(v_cache.dtype),
+                                     positions, self.window)
+            mask = kv_cache.step_mask(k_cache.shape[2], positions,
+                                      self.window)
+            o = grouped_attention(q, k_cache, v_cache, mask[:, :, None])
+            return self._finish(params, o), k_cache, v_cache
 
 
 class TransformerBlock(Module):

@@ -1,0 +1,119 @@
+"""Dropless routed experts: every token's top-k experts are computed,
+whatever the imbalance; no capacity, no dropped token, no bias.
+
+`RoutedExperts` is the feed-forward half of a sparse decoder block. The
+router is not in it: the block hands in the router's logits, which it
+may compute from another tensor than the experts' own input (a router
+placed before attention reads the attention block's normed input).
+`parallel/moe.py::MoE` is the capacity-bound Switch/GShard layer with an
+expert-parallel exchange; this one has neither.
+
+Tokens are sorted by expert and each projection is ONE grouped product
+over the sorted rows (`jax.lax.ragged_dot`); at a decode step's shape
+(no more rows than experts, nearly every expert chosen by some row) each
+projection is one batched product of every row with every expert, which
+reads the weights once at the memory's pace (`_few_rows`).
+"""
+
+from __future__ import annotations
+
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from bigdl_tpu.nn.initialization import Xavier
+from bigdl_tpu.nn.module import Module
+
+
+def route(logits, top_k: int):
+    """Top-k of the router's logits [N, E] and their weights, in
+    float32: softmax over all E, top-k, renormalised to sum 1, which is
+    the softmax over the k chosen logits. Returns (experts [N, k] int32,
+    weights [N, k] float32)."""
+    vals, idx = lax.top_k(logits.astype(jnp.float32), top_k)
+    return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+
+
+class RoutedExperts(Module):
+    """`n_experts` gated experts of width `d_hidden`:
+    E_e(u) = (relu(u @ wg_e) * (u @ wu_e)) @ wd_e, and the layer's result
+    sum over a token's `top_k` experts of weight x E_e(u).
+
+    `apply_routed(params, x [N, d], logits [N, E])` returns (y [N, d]
+    float32, experts [N, top_k] int32, each token's chosen experts); x
+    is cast to the weights' type for the products, whose last keeps its
+    float32 accumulator. More than
+    `token_chunk` rows are taken `token_chunk` at a time, which bounds
+    the sorted copies a long prefill makes (k x N x d of them) and
+    changes no number."""
+
+    def __init__(self, d_model: int, d_hidden: int, n_experts: int,
+                 top_k: int, token_chunk: int = 8192, name=None):
+        super().__init__(name)
+        if not 1 <= top_k <= n_experts:
+            raise ValueError(f"top_k {top_k} of {n_experts} experts")
+        self.d, self.hidden, self.n_experts, self.top_k = \
+            d_model, d_hidden, n_experts, top_k
+        self.token_chunk = int(token_chunk)
+
+    def init(self, rng):
+        k1, k2, k3 = jax.random.split(rng, 3)
+        xav = Xavier()
+        e, d, h = self.n_experts, self.d, self.hidden
+        return {"wg": xav(k1, (e, d, h)), "wu": xav(k2, (e, d, h)),
+                "wd": xav(k3, (e, h, d))}
+
+    def _chunk(self, params, x, logits):
+        n, k = x.shape[0], self.top_k
+        experts, weights = route(logits, k)
+        x = x.astype(params["wg"].dtype)
+        if self.n_experts <= n * k and n <= self.n_experts:
+            return self._few_rows(params, x, experts, weights), experts
+        flat = experts.reshape(-1)                         # [N k]
+        order = jnp.argsort(flat)                          # by expert
+        sizes = jnp.zeros((self.n_experts,), jnp.int32).at[flat].add(1)
+        rows = x[order // k]                               # [N k, d]
+        gate = lax.ragged_dot(rows, params["wg"], sizes)
+        up = lax.ragged_dot(rows, params["wu"], sizes)
+        out = lax.ragged_dot(jax.nn.relu(gate) * up, params["wd"], sizes,
+                             preferred_element_type=jnp.float32)
+        # back to token order; the k weighted results summed in float32
+        out = out[jnp.argsort(order)].reshape(n, k, self.d)
+        return jnp.sum(out * weights[..., None], axis=1), experts
+
+    def _few_rows(self, params, x, experts, weights):
+        """A decode step's shape: no more rows than experts, yet enough
+        pairs (N k >= E) that nearly every expert is chosen by some row.
+        The step is then the reading of the weights, and a grouped
+        product over sorted rows reads them at some 56% of the memory's
+        bandwidth and slower the more experts are touched (0.49 ms a
+        product at 32 rows on the v5e, PERF.md PR 29); here every row
+        meets every expert in one batched product a projection, the
+        weights streamed once whatever the routing, and the top-k's
+        weights (zero for an expert not chosen) fold into the down
+        projection's left operand, so nothing unchosen reaches the sum."""
+        n = x.shape[0]
+        gate = jnp.einsum("nd,edh->neh", x, params["wg"])
+        up = jnp.einsum("nd,edh->neh", x, params["wu"])
+        mix = jnp.zeros((n, self.n_experts), jnp.float32).at[
+            jnp.arange(n)[:, None], experts].set(weights)
+        hidden = (jax.nn.relu(gate) * up).astype(jnp.float32) \
+            * mix[..., None]
+        return jnp.einsum("neh,ehd->nd", hidden.astype(x.dtype),
+                          params["wd"], preferred_element_type=jnp.float32)
+
+    def apply(self, params, input, ctx):
+        """`input` = (x [N, d], router logits [N, E]) -> y [N, d]."""
+        x, logits = list(input)
+        return self.apply_routed(params, x, logits)[0]
+
+    def apply_routed(self, params, x, logits):
+        with jax.named_scope("moe experts"):
+            n, c = x.shape[0], self.token_chunk
+            if n <= c or n % c:
+                return self._chunk(params, x, logits)
+            y, experts = lax.map(
+                lambda a: self._chunk(params, *a),
+                (x.reshape(n // c, c, -1), logits.reshape(n // c, c, -1)))
+            return y.reshape(n, -1), experts.reshape(n, -1)

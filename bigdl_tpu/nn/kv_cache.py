@@ -1,0 +1,97 @@
+"""What an attention layer keeps per serving slot, and how a decode step
+reads it. The one owner of the K/V cache's format (ROADMAP D3): the
+models build their caches from `init`, prefill lands through `commit`,
+a decode step writes through `write` and masks through `step_mask`.
+
+Two kinds of layer, told apart by `window`:
+
+* full (`window` None): `[slots, kv_heads, max_len, head_dim]`, position
+  p at index p.
+* window: a ring of the last `window` positions,
+  `[slots, kv_heads, window, head_dim]`, position p at index
+  p % window. After the step at position p has written, index j holds
+  position p - ((p - j) % window): the window, whole, once p >= window
+  - 1; before that the indices past p hold nothing yet (a negative
+  position) and are masked.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+
+def depth(max_len: int, window: Optional[int]) -> int:
+    """Positions a slot holds."""
+    return max_len if window is None else min(int(window), max_len)
+
+
+def init(slots: int, kv_heads: int, max_len: int, head_dim: int,
+         window: Optional[int] = None, dtype=jnp.float32):
+    """One layer's K (or V) buffer, zeroed."""
+    if slots < 1:
+        raise ValueError(f"slots must be >= 1, got {slots}")
+    if max_len < 1:
+        raise ValueError(f"max_len must be >= 1, got {max_len}")
+    return jnp.zeros((slots, kv_heads, depth(max_len, window), head_dim),
+                     dtype)
+
+
+def write(cache, new, positions, window: Optional[int] = None):
+    """Write `new` [B, H, T, hd] into `cache` [B, H, L, hd] starting at
+    per-row sequence position `positions` [B] — a per-row
+    `lax.dynamic_update_slice`, so under donation the decode step updates
+    its preallocated KV buffers in place (O(1) memory and step cost per
+    token; never a per-token concat/retrace). A window layer's ring
+    takes one position at a time (T = 1), at `position % L`."""
+    if window is not None:
+        positions = positions % cache.shape[2]
+
+    def one(c, n, p):
+        return lax.dynamic_update_slice(c, n, (0, p, 0))
+    return jax.vmap(one)(cache, new, positions)
+
+
+def commit(cache, new, slot_ids, lengths=None,
+           window: Optional[int] = None):
+    """Commit per-request prefill K/V `new` [B, H, T, hd] into slots of a
+    fleet-wide cache [S, H, L, hd] at sequence position 0. Rows may
+    repeat (bucket padding replicates the last request's row INCLUDING
+    its slot id): the scan writes in request order, so a padded
+    duplicate rewrites identical values and the last write wins.
+
+    A window layer whose prompt bucket is longer than its ring commits,
+    per row, the last L positions of the row's real `lengths` [B] at the
+    ring's indices (position p at p % L)."""
+    ring = cache.shape[2]
+    if window is not None and new.shape[2] > ring:
+        last = lengths.astype(jnp.int32)[:, None] - 1           # [B, 1]
+        held = last - (last - jnp.arange(ring)[None, :]) % ring  # [B, L]
+        held = jnp.clip(held, 0, new.shape[2] - 1)  # < 0: masked anyway
+        new = jnp.take_along_axis(new, held[:, None, :, None], axis=2)
+
+    def body(c, inp):
+        n, s = inp
+        return lax.dynamic_update_slice(c, n[None], (s, 0, 0, 0)), None
+    out, _ = lax.scan(body, cache, (new, slot_ids))
+    return out
+
+
+def step_mask(length: int, positions, window: Optional[int] = None):
+    """[B, 1, 1, L] mask of the cache indices the step at `positions` [B]
+    may read, its own (just written) included."""
+    idx = jnp.arange(length)[None, :]
+    if window is None:
+        keep = idx <= positions[:, None]
+    else:
+        keep = (positions[:, None] - idx) % length <= positions[:, None]
+    return keep[:, None, None, :]
+
+
+def positions_skipped(positions, window: int):
+    """Cache positions a one-depth cache would have given the step at
+    `positions` [B] to read, and the ring did not."""
+    return jnp.sum(jnp.maximum(positions + 1 - window, 0))

@@ -189,6 +189,25 @@ class LayerNormalization(Module):
         return y * params["weight"] + params["bias"]
 
 
+class RMSNorm(Module):
+    """Root-mean-square norm over the last axis: x / rms(x) * weight, no
+    mean and no bias. The statistics are taken in float32 whatever the
+    input's type; the result keeps the input's type."""
+
+    def __init__(self, hidden_size: int, eps: float = 1e-6, name=None):
+        super().__init__(name)
+        self.hidden_size, self.eps = hidden_size, eps
+
+    def init(self, rng):
+        return {"weight": jnp.ones((self.hidden_size,))}
+
+    def apply(self, params, input, ctx):
+        x = input.astype(jnp.float32)
+        y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                              + self.eps)
+        return (y * params["weight"]).astype(input.dtype)
+
+
 def _gaussian_kernel(size: int, sigma: float = None):
     """Default smoothing kernel used by the Torch-style normalization layers
     when none is given (reference passes an explicit kernel tensor)."""

@@ -278,7 +278,16 @@ RECORD_SCHEMAS: Dict[str, Dict] = {
                      "ttft_ms_p50": _NUM, "ttft_ms_p95": _NUM,
                      "ttft_ms_p99": _NUM, "ttft_ms_count": int,
                      "itl_ms_p50": _NUM, "itl_ms_p95": _NUM,
-                     "itl_ms_p99": _NUM, "itl_ms_count": int},
+                     "itl_ms_p99": _NUM, "itl_ms_count": int,
+                     # what a model counts on the device in its cache
+                     # pytree (`cache_stats`; models/decoder.py): routed
+                     # token-expert pairs, the busiest expert's load over
+                     # the mean, distinct experts a decode step read,
+                     # cache positions the window's ring did not read
+                     "moe_pairs_routed": int,
+                     "moe_expert_load_max_over_mean": _OPT_NUM,
+                     "moe_experts_touched_per_step": _OPT_NUM,
+                     "window_positions_skipped": _NUM},
     },
     # fleet-level counters/gauges (serving/fleet.py), one per
     # membership change or maintain() tick; PrometheusTextSink renders

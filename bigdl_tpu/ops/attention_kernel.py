@@ -232,14 +232,27 @@ def flash_attention_forward(q, k, v, causal: bool = False,
                             sm_scale: Optional[float] = None,
                             block_q: int = 256, block_k: int = 512,
                             interpret: Optional[bool] = None,
-                            return_lse: bool = False):
+                            return_lse: bool = False,
+                            window: Optional[int] = None):
     """Pallas flash-attention forward. q,k,v: [B,H,T,D]; T must be padded to
     the block sizes by the caller (`flash_attention` handles it).
-    `return_lse=True` also returns the [B,H,T] logsumexp (backward input)."""
+    `return_lse=True` also returns the [B,H,T] logsumexp (backward input).
+
+    `k`, `v` may hold fewer heads than `q` ([B, Hkv, T, D], H a multiple
+    of Hkv: query head j reads K/V head j // (H // Hkv)), and `window`
+    keeps, for the query at position p, the keys at p - window + 1 .. p.
+    Either one takes the grouped kernel below (causal, no logsumexp: the
+    serving prefill's); with neither this is the call it always was."""
     from jax.experimental import pallas as pl
 
     if interpret is None:
         interpret = INTERPRET
+    if window is not None or k.shape[1] != q.shape[1]:
+        if not causal or return_lse:
+            raise ValueError("the windowed / grouped-query forward is "
+                             "causal and returns no logsumexp")
+        return _flash_forward_grouped(q, k, v, sm_scale, block_q, block_k,
+                                      interpret, window)
     b, h, tq, d = q.shape
     tk = k.shape[2]
     sm_scale = sm_scale or d ** -0.5
@@ -560,6 +573,159 @@ def flash_attention_backward(q, k, v, out, lse, g, causal: bool = False,
     )(qr, kr, vr, dor, lser, delta)
     return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
             dv.reshape(b, h, tk, d))
+
+
+# --------------------------------------------------------------------------- #
+# grouped-query / sliding-window forward (serving prefill)
+# --------------------------------------------------------------------------- #
+
+def _grouped_kv_range(j, block_q: int, block_k: int, window: Optional[int]):
+    """First and last K/V block that the causal (and windowed) mask leaves
+    anything of for q block `j`."""
+    last = ((j + 1) * block_q - 1) // block_k
+    if window is None:
+        return 0, last
+    return jnp.maximum(j * block_q - (window - 1), 0) // block_k, last
+
+
+def _flash_fwd_grouped_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+                              l_ref, *, block_q: int, block_k: int,
+                              sm_scale: float, window: Optional[int]):
+    """One program = one (batch * K/V head, q block, K/V step). The q
+    block holds the `group` query heads that share the K/V head, folded
+    into its rows, so a K/V block is read once for all of them; K/V
+    blocks come through the grid (never a whole head in VMEM) and the
+    online-softmax state lives in scratch across the K/V steps. A step
+    past the q block's last needed K/V block computes nothing, and its
+    index map repeats the block before, so nothing is fetched either."""
+    from jax.experimental import pallas as pl
+
+    group, _, d = q_ref.shape[1:]
+    rows = group * block_q
+    j, step = pl.program_id(1), pl.program_id(2)
+    first, last = _grouped_kv_range(j, block_q, block_k, window)
+
+    @pl.when(step == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(first + step <= last)
+    def _():
+        q = q_ref[0].reshape(rows, d)
+        s = jax.lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * sm_scale
+        gq = j * block_q + jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, s.shape, 0), block_q)
+        gk = (first + step) * block_k \
+            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        keep = gq >= gk
+        if window is not None:
+            keep = jnp.logical_and(keep, gk > gq - window)
+        s = jnp.where(keep, s, NEG_INF)
+        m = m_ref[...]                                  # [rows, 1]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        # a row's own position is always kept, and its block is never
+        # skipped, so m_new is finite from the row's first step on
+        p = jnp.exp(s - m_new)
+        scale_old = jnp.exp(m - m_new)
+        l_ref[...] = l_ref[...] * scale_old + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+        acc_ref[...] = acc_ref[...] * scale_old + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).reshape(
+            group, block_q, d).astype(o_ref.dtype)
+
+
+def _flash_forward_grouped(q, k, v, sm_scale, block_q, block_k, interpret,
+                           window):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, t, d = q.shape
+    hk = k.shape[1]
+    if h % hk or k.shape != v.shape or k.shape[2] != t:
+        raise ValueError(f"q {q.shape} against k {k.shape}, v {v.shape}")
+    group = h // hk
+    sm_scale = sm_scale or d ** -0.5
+    # `group` query heads share a q block's rows: keep it near 1024 rows
+    block_q = min(block_q, t, max(128, 1024 // group // 128 * 128))
+    block_k = min(block_k, t)
+    assert t % block_q == 0 and t % block_k == 0
+    n_kb = t // block_k
+    if window is None:
+        steps = n_kb
+    else:  # the most K/V blocks a q block's window can touch
+        steps = min(n_kb, (window - 1 + block_q - 1) // block_k + 2)
+
+    def kv_map(i, j, s):
+        first, last = _grouped_kv_range(j, block_q, block_k, window)
+        return i, jnp.minimum(first + s, last), 0
+
+    kernel = functools.partial(_flash_fwd_grouped_kernel, block_q=block_q,
+                               block_k=block_k, sm_scale=sm_scale,
+                               window=window)
+    rows = group * block_q
+    out = pl.pallas_call(
+        kernel,
+        grid=(b * hk, t // block_q, steps),
+        in_specs=[
+            pl.BlockSpec((1, group, block_q, d), lambda i, j, s: (i, 0, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, group, block_q, d),
+                               lambda i, j, s: (i, 0, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * hk, group, t, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_fwd_gqa" if window is None else "flash_fwd_window",
+    )(q.reshape(b * hk, group, t, d), k.reshape(b * hk, t, d),
+      v.reshape(b * hk, t, d))
+    return out.reshape(b, h, t, d)
+
+
+def grouped_attention(q, k, v, keep):
+    """Plain-XLA attention of q [B, H, Tq, D] over k, v [B, Hkv, Tk, D]
+    (query head j reads K/V head j // (H // Hkv), so K/V are read once a
+    K/V head) under the mask `keep`, broadcastable to
+    [B, Hkv, H // Hkv, Tq, Tk]; scores and softmax in float32."""
+    b, h, tq, d = q.shape
+    hk = k.shape[1]
+    s = jnp.einsum("bkgqd,bktd->bkgqt", q.reshape(b, hk, h // hk, tq, d), k,
+                   preferred_element_type=jnp.float32) * d ** -0.5
+    p = jax.nn.softmax(jnp.where(keep, s, NEG_INF), axis=-1)
+    o = jnp.einsum("bkgqt,bktd->bkgqd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.astype(q.dtype).reshape(b, h, tq, d)
+
+
+def causal_grouped_attention(q, k, v, window: Optional[int] = None):
+    """Inference-only causal self-attention, q [B, H, T, D] over k, v
+    [B, Hkv, T, D], the last `window` keys a query (all with None): the
+    grouped flash kernel on a TPU where T fills its 128-row blocks,
+    else plain XLA with the mask written out (tests, tiny shapes)."""
+    use_pallas = jax.default_backend() == "tpu" or INTERPRET
+    t = q.shape[2]
+    if use_pallas and t % 128 == 0 and (t <= 512 or t % 512 == 0):
+        return flash_attention_forward(q, k, v, causal=True, window=window)
+    i = lax.broadcasted_iota(jnp.int32, (t, t), 0)
+    j = lax.broadcasted_iota(jnp.int32, (t, t), 1)
+    keep = j <= i
+    if window is not None:
+        keep = jnp.logical_and(keep, j > i - window)
+    return grouped_attention(q, k, v, keep)
 
 
 # --------------------------------------------------------------------------- #

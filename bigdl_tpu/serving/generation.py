@@ -372,6 +372,12 @@ class GenerationEngine(InferenceEngine):
                 seq_buckets.append(self.max_len)
         self.seq_buckets = list(seq_buckets)
         self._cache = model.init_cache(self.slots, self.max_len)
+        # the cache is donated to every step: whoever reads a leaf of it
+        # off the dispatcher's thread (`generation_stats()`) holds this
+        # lock, as the dispatcher does around each call that donates it
+        self._cache_lock = threading.Lock()
+        # callers of warmup() waiting for a turn with no slot active
+        self._warm_waiters: List[Tuple[threading.Event, Dict]] = []
         # slot table: dispatcher-thread-owned; _active mirrors it under
         # _slock for stats()/generation_stats() readers
         self._slot_req: List[Optional[_GenRequest]] = [None] * self.slots
@@ -487,29 +493,68 @@ class GenerationEngine(InferenceEngine):
     # ------------------------------------------------------------ warmup
     def warmup(self, sample=None) -> int:
         """Precompile EVERY prefill (batch-bucket, seq-bucket) executable
-        plus the single decode executable — against a SCRATCH cache (the
-        live cache is dispatcher-owned), blocking until each is built, so
-        first-request latency never pays a compile. `sample` is accepted
-        for engine-protocol compatibility (the fleet re-warms rejoining
+        plus the single decode executable, blocking until each is built
+        and has run once, so first-request latency never pays a compile.
+        They run against the LIVE cache (a second one does not fit beside
+        a large model's weights), on the dispatcher's own thread in a
+        turn in which no slot is active: a warm-up asked for under
+        traffic holds admissions back until the slots have drained, then
+        runs, then admissions go on. What it writes (one position of
+        slot 0, position 0 of every slot) lies where a later prefill
+        overwrites it or the mask hides it. `sample` is accepted for
+        engine-protocol compatibility (the fleet re-warms rejoining
         replicas) and ignored: generation signatures are fully determined
         by the engine's own buckets. Returns the compile count."""
-        scratch = self.model.init_cache(self.slots, self.max_len)
-        for t_pad in self.seq_buckets:
-            for b in self.buckets:
-                tokens = np.ones((b, t_pad), np.int32)
-                ids = np.zeros((b,), np.int32)
-                lengths = np.ones((b,), np.int32)
-                tok, scratch = self._prefill(self._params, scratch,
-                                             tokens, ids, lengths)
-                np.asarray(tok)  # block: the compile must finish here
-                with self._slock:
-                    self._compiled.add((self._gen_sig(t_pad), b))
-        tok, scratch = self._decode(
-            self._params, scratch, self._no_prev,
-            np.ones((self.slots,), np.int32), np.ones((self.slots,), bool),
-            np.zeros((self.slots,), np.int32))
-        np.asarray(tok)
+        with self._lock:
+            started = self._thread is not None and not self._closing
+            if started:
+                done, box = threading.Event(), {}
+                self._warm_waiters.append((done, box))
+                self._not_empty.notify_all()
+        if not started:
+            self._warm_now()
+        else:
+            done.wait()
+            if "error" in box:
+                raise box["error"]
         return self.compile_count()
+
+    def _warm_now(self):
+        """Run every program once against the live cache; dispatcher
+        thread (or the caller's, before the engine starts)."""
+        with self._cache_lock:
+            for t_pad in self.seq_buckets:
+                for b in self.buckets:
+                    tokens = np.ones((b, t_pad), np.int32)
+                    ids = np.zeros((b,), np.int32)
+                    lengths = np.ones((b,), np.int32)
+                    tok, self._cache = self._prefill(
+                        self._params, self._cache, tokens, ids, lengths)
+                    np.asarray(tok)  # block: the compile must finish here
+                    with self._slock:
+                        self._compiled.add((self._gen_sig(t_pad), b))
+            tok, self._cache = self._decode(
+                self._params, self._cache, self._no_prev,
+                np.ones((self.slots,), np.int32),
+                np.ones((self.slots,), bool),
+                np.zeros((self.slots,), np.int32))
+            np.asarray(tok)
+
+    def _serve_warm_waiters(self, error: Optional[BaseException] = None):
+        with self._lock:
+            waiters, self._warm_waiters = self._warm_waiters, []
+        if not waiters:
+            return
+        if error is None:
+            try:
+                self._warm_now()
+            except Exception as e:  # the donated cache is unknowable now
+                error = e
+                self._reset_cache(ServingError(f"warm-up failed: {e!r}"))
+        for done, box in waiters:
+            if error is not None:
+                box["error"] = error
+            done.set()
 
     def compile_count(self) -> int:
         """Distinct compiled signatures across the prefill buckets and
@@ -536,21 +581,30 @@ class GenerationEngine(InferenceEngine):
             while True:
                 with self._lock:
                     if not self._q and self._active == 0 \
-                            and not self._closing:
+                            and not self._closing \
+                            and not self._warm_waiters:
                         with self._span("await request"):
                             while not self._q and self._active == 0 \
-                                    and not self._closing:
+                                    and not self._closing \
+                                    and not self._warm_waiters:
                                 self._not_empty.wait()
                     if self._closing:
                         if not self._drain:
                             break
                         if not self._q and self._active == 0:
                             break
+                    warm = bool(self._warm_waiters)
+                # lint: unguarded-ok(the dispatcher thread is the only _active writer; _slock exists for cross-thread stats readers, not this owner-thread read)
+                if warm and self._active == 0 and self._flying is None:
+                    self._serve_warm_waiters()
+                    continue
                 # lint: unguarded-ok(the dispatcher thread is the only _active writer; _slock exists for cross-thread stats readers, not this owner-thread read)
                 with self._span("generate step", n_active=self._active):
-                    self._admit_into_slots()
+                    if not warm:  # a waiting warm-up lets the slots drain
+                        self._admit_into_slots()
                     self._decode_once()
         finally:
+            self._serve_warm_waiters(EngineClosedError("engine closed"))
             self._abort_slots(EngineClosedError("engine closed"))
             self._emit_safe({"type": "generation",
                              **self.generation_stats()})
@@ -638,8 +692,10 @@ class GenerationEngine(InferenceEngine):
                             t_pad=t_pad):
                 faults.fire("serve.forward", bucket=bucket, n=n, sig=sig)
                 dispatched = True
-                first, self._cache = self._prefill(
-                    self._params, self._cache, tokens, slot_ids, lengths)
+                with self._cache_lock:
+                    first, self._cache = self._prefill(
+                        self._params, self._cache, tokens, slot_ids,
+                        lengths)
                 first = np.asarray(first)  # slot state must be real
                 # before the next decode step reads it
         except Exception as e:
@@ -739,7 +795,7 @@ class GenerationEngine(InferenceEngine):
                 if riders:
                     faults.fire(SITE_DECODE, n=len(riders))
                     t = time.perf_counter()
-                    with self._span("decode dispatch"):
+                    with self._span("decode dispatch"), self._cache_lock:
                         nxt, self._cache = self._decode(
                             self._params, self._cache,
                             self._no_prev if flying is None else flying[0],
@@ -843,7 +899,8 @@ class GenerationEngine(InferenceEngine):
         """The donated cache's buffers are unknown after a failed
         execution: fail every active stream (their KV history is gone),
         reallocate, and keep serving fresh requests."""
-        self._cache = self.model.init_cache(self.slots, self.max_len)
+        with self._cache_lock:
+            self._cache = self.model.init_cache(self.slots, self.max_len)
         self._flying = None
         for r in list(self._slot_req):
             if r is not None:
@@ -877,6 +934,15 @@ class GenerationEngine(InferenceEngine):
         with self._lock:
             depth = len(self._q)
         elapsed = time.monotonic() - self._t0_mono
+        # what the model counts on the device, carried in the cache
+        # pytree (no fetch a step): read here, in one fetch
+        model_counters = {}
+        if hasattr(self.model, "cache_stats"):
+            try:
+                with self._cache_lock:
+                    model_counters = self.model.cache_stats(self._cache)
+            except Exception:  # a cache lost to a failed step
+                logger.exception("cache_stats failed; counters left out")
         occ = g["decode_slot_steps"] / (g["decode_steps"] * self.slots) \
             if g["decode_steps"] else None
         return {
@@ -900,6 +966,7 @@ class GenerationEngine(InferenceEngine):
             "slot_leaves": g["slot_leaves"],
             **self.ttft.snapshot("ttft_ms", scale=1e3),
             **self.token_gap.snapshot("itl_ms", scale=1e3),
+            **model_counters,
         }
 
     def _gen_trace(self, r: _GenRequest, status: str,

@@ -108,6 +108,7 @@ SPECS = {
     "SpatialBatchNormalization": (lambda: nn.SpatialBatchNormalization(3),
                                   IMG),
     "LayerNormalization": (lambda: nn.LayerNormalization(4), MAT),
+    "RMSNorm": (lambda: nn.RMSNorm(4), MAT),
     "SpatialCrossMapLRN": (lambda: nn.SpatialCrossMapLRN(), IMG),
     "SpatialWithinChannelLRN": (lambda: nn.SpatialWithinChannelLRN(), IMG),
     "SpatialContrastiveNormalization": (
@@ -199,6 +200,10 @@ SPECS = {
     # attention / transformer
     "MultiHeadAttention": (
         lambda: nn.MultiHeadAttention(8, 2), np.ones((2, 5, 8), np.float32)),
+    "GroupedQueryAttention": (
+        lambda: nn.GroupedQueryAttention(8, 4, 2, 4, window=3,
+                                         rope_base=1e4),
+        np.ones((2, 5, 8), np.float32)),
     "ScaledDotProductAttention": (
         lambda: nn.ScaledDotProductAttention(), Table(
             np.ones((2, 2, 5, 4), np.float32), np.ones((2, 2, 5, 4), np.float32),

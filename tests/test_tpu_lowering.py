@@ -29,7 +29,8 @@ from bigdl_tpu.dataset.dataset import LocalDataSet
 from bigdl_tpu.dataset.sample import MiniBatch
 from bigdl_tpu.nn import fusion
 from bigdl_tpu.ops import bn_relu_kernel as bk
-from bigdl_tpu.ops.attention_kernel import flash_attention
+from bigdl_tpu.ops.attention_kernel import (flash_attention,
+                                            flash_attention_forward)
 from bigdl_tpu.optim.distri_optimizer import DistriOptimizer
 from bigdl_tpu.parallel.mesh import build_mesh
 from bigdl_tpu.parallel.sequence import make_sequence_parallel_attention
@@ -99,6 +100,21 @@ class TestFlashLowers:
                 q, k, v, True, None, True).astype(jnp.float32)),
                 argnums=(0, 1, 2)), q, q, q)
         assert n_mosaic(grad) == 3  # forward, dq, dk/dv
+
+    @pytest.mark.parametrize("t,window", [(128, None), (128, 4096),
+                                          (12288, None), (12288, 4096)])
+    def test_grouped_query_and_windowed_forward(self, t, window):
+        """The serving prefill's kernel at the smallthinker-21b widths:
+        28 query heads over 4 K/V heads of 128, a window of 4096."""
+        q, kv = struct((2, 28, t, 128), jnp.bfloat16), \
+            struct((2, 4, t, 128), jnp.bfloat16)
+        fwd = lower_for_tpu(
+            lambda q, k, v: flash_attention_forward(
+                q, k, v, causal=True, interpret=False, window=window),
+            q, kv, kv)
+        assert n_mosaic(fwd) == 1
+        name = "flash_fwd_gqa" if window is None else "flash_fwd_window"
+        assert name in fwd.as_text(debug_info=True)
 
     @pytest.mark.parametrize("scheme,kernels", [("ring", 4), ("zigzag", 12)])
     def test_sequence_parallel_hops(self, tpu_routing, scheme, kernels):
@@ -199,6 +215,20 @@ class TestMosaicCompiles:
             struct((n, c), jnp.float32, on), struct((c,), jnp.float32, on),
             struct((c,), jnp.float32, on),
             struct((n, c), jnp.bfloat16, on)).compile()
+
+    @pytest.mark.parametrize("t,window", [(128, 4096), (16384, None),
+                                          (16384, 4096)])
+    def test_grouped_query_and_windowed_forward(self, t, window):
+        # K/V blocks come through the grid: a whole head of 16 384
+        # positions (4 MB, twice, double-buffered) would not fit VMEM
+        on = _v5e_device()
+        with jax.default_matmul_precision("bfloat16"):
+            lower_for_tpu(
+                lambda q, k, v: flash_attention_forward(
+                    q, k, v, causal=True, interpret=False, window=window),
+                struct((2, 28, t, 128), jnp.bfloat16, on),
+                struct((2, 4, t, 128), jnp.bfloat16, on),
+                struct((2, 4, t, 128), jnp.bfloat16, on)).compile()
 
     @pytest.mark.parametrize("t", FLASH_LENGTHS)
     def test_flash_forward_and_grad(self, t):
