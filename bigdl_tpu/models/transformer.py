@@ -17,6 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from bigdl_tpu.nn import kv_cache
 from bigdl_tpu.nn.attention import TransformerBlock
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.nn.initialization import Xavier
@@ -82,6 +83,13 @@ class TransformerLM(Module):
         shape = (slots, attn.h, max_len, attn.hd)
         return {"k": [jnp.zeros(shape, dtype) for _ in self.blocks],
                 "v": [jnp.zeros(shape, dtype) for _ in self.blocks]}
+
+    @staticmethod
+    def decode_depths(max_len: int):
+        """The depths a decode step over a cache of `max_len` reads to
+        (`MultiHeadAttention.apply_step`'s ladder), for the engine's
+        `decode_depth_share`."""
+        return kv_cache.depth_rungs(max_len)
 
     def apply_step(self, params, tokens, cache, positions):
         """One decode step over ALL cache slots: `tokens` [S] (1-based

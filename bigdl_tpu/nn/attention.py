@@ -8,10 +8,12 @@ kernels in ops/attention_kernel.py, bf16-friendly, fully jittable.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from bigdl_tpu.nn import kv_cache
 from bigdl_tpu.nn.initialization import Xavier
@@ -165,12 +167,29 @@ class MultiHeadAttention(Module):
         `positions` via `cache_write`, then attends over the causal cache
         prefix (key position <= row position) — mask-correct for MIXED
         row ages, so cache slots at different depths batch into one
-        fixed-shape step. Returns (out [B, 1, E], k_cache, v_cache)."""
+        fixed-shape step. Returns (out [B, 1, E], k_cache, v_cache).
+
+        The cache is read only as deep as the deepest row needs: to the
+        rung of `kv_cache.depth_rungs` that covers `max(positions)`,
+        chosen on the device by a `lax.switch` over one static prefix a
+        rung, inside the one program. The positions dropped are masked
+        for every row, so they weigh exp(-1e30 - m) = 0: the result is
+        the whole depth's but for the order of a float sum."""
         q, k, v = self.project_qkv(params, x, positions=positions[:, None])
         k_cache = cache_write(k_cache, k, positions)
         v_cache = cache_write(v_cache, v, positions)
-        mask = kv_cache.step_mask(k_cache.shape[2], positions)
-        o = naive_attention(q, k_cache, v_cache, mask=mask)
+
+        def read(d, q, k_cache, v_cache, positions):
+            mask = kv_cache.step_mask(d, positions)
+            return naive_attention(q, k_cache[:, :, :d], v_cache[:, :, :d],
+                                   mask=mask)
+        rungs = kv_cache.depth_rungs(k_cache.shape[2])
+        if len(rungs) == 1:  # no switch, and no index to compute
+            o = read(rungs[0], q, k_cache, v_cache, positions)
+        else:
+            o = lax.switch(kv_cache.rung_index(rungs, positions),
+                           [partial(read, d) for d in rungs],
+                           q, k_cache, v_cache, positions)
         return self._finish(params, o), k_cache, v_cache
 
 

@@ -13,14 +13,21 @@ Two kinds of layer, told apart by `window`:
   position p - ((p - j) % window): the window, whole, once p >= window
   - 1; before that the indices past p hold nothing yet (a negative
   position) and are masked.
+
+A decode step over a full layer reads the cache only as deep as a rung
+of `depth_rungs(max_len)` that covers the deepest slot of the step: the
+ladder is a constant of the format, chosen by `rung_index` from the
+step's positions.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 
@@ -38,6 +45,31 @@ def init(slots: int, kv_heads: int, max_len: int, head_dim: int,
         raise ValueError(f"max_len must be >= 1, got {max_len}")
     return jnp.zeros((slots, kv_heads, depth(max_len, window), head_dim),
                      dtype)
+
+
+def depth_rungs(max_len: int) -> Tuple[int, ...]:
+    """The depths, ascending, a decode step may read a full layer's cache
+    to: `max_len` and its half, quarter and eighth, those below `max_len`
+    rounded up to a multiple of 128 positions; a rung under 128, over
+    `max_len` or equal to another is dropped (2048 has 256 / 512 / 1024 /
+    2048, 64 has 64 alone). Coarse on purpose: a serving mix's median
+    step has to lie well inside one rung, or its median latency flips
+    between two (docs/serving.md)."""
+    rungs = {max_len}
+    for parts in (2, 4, 8):
+        d = 128 * math.ceil(max_len / parts / 128)
+        if 128 <= d < max_len:
+            rungs.add(d)
+    return tuple(sorted(rungs))
+
+
+def rung_index(rungs: Sequence[int], positions):
+    """Index of the smallest of `rungs` that covers every slot of the
+    step at `positions` [B] (the deepest, just written, included). Idle
+    slots ride at position 0 and ask for nothing. `positions` may be
+    traced (the decode step, on the device) or a numpy array (the
+    engine's counters, on the host)."""
+    return (positions.max() + 1 > np.asarray(rungs[:-1])).sum()
 
 
 def write(cache, new, positions, window: Optional[int] = None):

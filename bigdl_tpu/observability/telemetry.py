@@ -272,6 +272,13 @@ RECORD_SCHEMAS: Dict[str, Dict] = {
                      # computed for a request that had already ended
                      "decode_overlapped_steps": int,
                      "decode_discarded_slot_steps": int,
+                     # how deep the decode steps read the cache: the
+                     # ladder's rung (nn/kv_cache.py) summed over the
+                     # steps dispatched, that over those steps x max_len
+                     # (1.0: the whole depth every step), steps by rung
+                     "decode_read_depth_total": int,
+                     "decode_depth_share": _OPT_NUM,
+                     "decode_steps_by_depth": dict,
                      # engine-side token clock over the recent window
                      # (WindowedHistogram.snapshot: quantiles absent
                      # before the first observation)

@@ -73,6 +73,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from bigdl_tpu.nn import kv_cache
 from bigdl_tpu.observability.compilation import CompiledFunction
 from bigdl_tpu.observability.spans import TraceContext
 from bigdl_tpu.resilience import faults
@@ -400,6 +401,13 @@ class GenerationEngine(InferenceEngine):
                    # delivery of the step's tokens after it
                    "decode_dispatch_s": 0.0, "decode_fetch_s": 0.0,
                    "decode_deliver_s": 0.0}
+        # how deep a decode step reads a full layer's cache: the model's
+        # ladder (nn/kv_cache.py), or the whole depth where its step has
+        # none; dispatched steps counted by the rung they read to
+        depths = getattr(model, "decode_depths", None)
+        self._depths = depths(self.max_len) if depths is not None \
+            else (self.max_len,)
+        self._steps_by_rung = [0] * len(self._depths)
         # engine-side token clock (seconds): first token after submit,
         # one a request; gap between two decode steps' deliveries, ONE a
         # step (every slot that decoded in both saw the same gap)
@@ -787,6 +795,7 @@ class GenerationEngine(InferenceEngine):
                     else:  # its last token is step n's, on the device
                         fresh[r.slot] = False
                     positions[r.slot] = r.pos
+                rung = int(kv_cache.rung_index(self._depths, positions))
         self._flying = None  # step n is this turn's to fetch
         t0 = time.perf_counter()
         dispatch_s = fetch_s = 0.0
@@ -820,8 +829,10 @@ class GenerationEngine(InferenceEngine):
             self._g["decode_s"] += now - t0
             self._g["decode_dispatch_s"] += dispatch_s
             self._g["decode_fetch_s"] += fetch_s
-            if riders and flying is not None:
-                self._g["decode_overlapped_steps"] += 1
+            if riders:
+                self._steps_by_rung[rung] += 1
+                if flying is not None:
+                    self._g["decode_overlapped_steps"] += 1
         if flying is not None:
             self._deliver(got, flying[1], now)
 
@@ -930,6 +941,7 @@ class GenerationEngine(InferenceEngine):
         docs/observability.md)."""
         with self._slock:
             g = dict(self._g)
+            by_rung = list(self._steps_by_rung)
             active = self._active
         with self._lock:
             depth = len(self._q)
@@ -945,6 +957,9 @@ class GenerationEngine(InferenceEngine):
                 logger.exception("cache_stats failed; counters left out")
         occ = g["decode_slot_steps"] / (g["decode_steps"] * self.slots) \
             if g["decode_steps"] else None
+        # over the steps dispatched: one in flight, or dropped unfetched
+        # because all its requests had ended, is not yet in decode_steps
+        read_depth = sum(d * n for d, n in zip(self._depths, by_rung))
         return {
             "slots": self.slots, "active_slots": active,
             "queue_depth": depth, "max_len": self.max_len,
@@ -953,6 +968,12 @@ class GenerationEngine(InferenceEngine):
             if elapsed > 0 and g["tokens"] else None,
             "decode_steps": g["decode_steps"],
             "decode_occupancy": round(occ, 4) if occ is not None else None,
+            "decode_read_depth_total": read_depth,
+            "decode_depth_share": round(
+                read_depth / (sum(by_rung) * self.max_len), 4)
+            if any(by_rung) else None,
+            "decode_steps_by_depth": {str(d): n for d, n in
+                                      zip(self._depths, by_rung)},
             "prefill_requests": g["prefill_requests"],
             "prefill_batches": g["prefill_batches"],
             "prefill_s_total": round(g["prefill_s"], 4),

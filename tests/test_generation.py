@@ -116,9 +116,9 @@ class TestIncrementalApply:
                                        atol=1e-5)
 
     def test_prefill_matches_full_apply_and_mixed_ages_decode(self):
-        """Prefill's last-token log-probs are the full apply's (bitwise:
-        same math, causal mask hides right-padding), and a decode step
-        over slots at MIXED positions continues each slot correctly."""
+        """Prefill's last-token log-probs are the full apply's (same
+        math, causal mask hides right-padding), and a decode step over
+        slots at MIXED positions continues each slot correctly."""
         m = small_model()
         params = m.ensure_params()
         rs = np.random.RandomState(6)
@@ -133,8 +133,13 @@ class TestIncrementalApply:
                                       jnp.array([2, 0], np.int32),
                                       jnp.asarray(lengths))
         last = np.asarray(last)
-        np.testing.assert_array_equal(last[0], full[0, 4])
-        np.testing.assert_array_equal(last[1], full[1, 8])
+        # not bitwise: the padded prefill is [2, 16] and the full apply
+        # [2, 9], and XLA orders a float32 sum by the shape (seen: 9.5e-07,
+        # two ulps of log-probs between -4 and -8). 1e-5 is twenty ulps;
+        # one product rounded to bf16 (2^-9 of a log-prob of 5: 1e-2)
+        # would miss it a thousandfold
+        np.testing.assert_allclose(last[0], full[0, 4], rtol=0, atol=1e-5)
+        np.testing.assert_allclose(last[1], full[1, 8], rtol=0, atol=1e-5)
         # mixed slot ages: slot 2 decodes at position 5, slot 0 at 9
         nxt = last.argmax(-1).astype(np.int32) + 1
         step_toks = np.ones(4, np.int32)

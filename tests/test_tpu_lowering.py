@@ -244,3 +244,80 @@ class TestMosaicCompiles:
                 jax.grad(lambda q, k, v: jnp.sum(flash_attention(
                     q, k, v, True, None, True).astype(jnp.float32)),
                     argnums=(0, 1, 2)), q, q, q).compile()
+
+
+# ---------------------------------------------------------------------- #
+# the serving engine's decode program at the serving cell's shapes
+# ---------------------------------------------------------------------- #
+
+class TestDecodeProgramCompiles:
+    """`GenerationEngine`'s own `jit__decode_fn` over `TransformerLM` at
+    the shapes of `neox-3.6b.serve-chat` (32 slots x 2048, 22 heads of
+    128, 4 layers, bf16 weights and cache), compiled by libtpu for a v5e
+    that is not attached. Nothing runs, so nothing here is a timing."""
+
+    def test_one_four_branch_conditional_a_layer_and_no_kernel(self):
+        import re
+        from bigdl_tpu.models.transformer import TransformerLM
+        from bigdl_tpu.nn import kv_cache
+        from bigdl_tpu.serving import GenerationEngine
+        on = _v5e_device()
+        slots, max_len, n_layer = 32, 2048, 4
+        model = TransformerLM(32000, embed_dim=2816, n_layer=n_layer,
+                              n_head=22, mlp_ratio=4, max_len=max_len)
+
+        def on_chip(tree, dtype=None):
+            return jax.tree_util.tree_map(
+                lambda a: struct(a.shape, dtype or a.dtype, on), tree)
+        params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                         jnp.bfloat16)
+        cache = on_chip(jax.eval_shape(
+            lambda: model.init_cache(slots, max_len, jnp.bfloat16)))
+        # the engine only keeps the parameters; its own cache stays tiny
+        model.set_params(params)
+        eng = GenerationEngine(model, slots=1, max_len=2, start=False)
+        try:
+            ids = struct((slots,), jnp.int32, on)
+            with jax.default_matmul_precision("bfloat16"):
+                compiled = eng._decode._jit.trace(
+                    params, cache, ids, ids, struct((slots,), jnp.bool_, on),
+                    ids).lower(lowering_platforms=("tpu",)).compile()
+        finally:
+            eng.close(drain=False)
+        hlo = compiled.as_text()
+        assert "jit__decode_fn" in hlo
+        conditionals = re.findall(
+            r" conditional\(.*branch_computations=\{([^}]*)\}", hlo)
+        assert len(conditionals) == n_layer
+        assert all(len(c.split(",")) == 4 for c in conditionals)
+        # the branch index is ONE value for the four layers, computed
+        # from `positions` against the thresholds the host counts by
+        # (`kv_cache.rung_index` over `depth_rungs`: the engine's
+        # `decode_steps_by_depth`): walk its operands back
+        index = set(re.findall(r" conditional\((%[\w.\-]+),", hlo))
+        assert len(index) == 1
+        defs = dict(re.findall(r"^\s*(%[\w.\-]+) = (.*)$", hlo, re.M))
+        reached, todo = set(), list(index)
+        while todo:
+            name = todo.pop()
+            if name in defs and name not in reached:
+                reached.add(name)
+                todo += re.findall(r"%[\w.\-]+",
+                                   defs[name].split(", metadata=")[0])
+        sources = [defs[n] for n in reached]
+        below = ", ".join(map(str, kv_cache.depth_rungs(max_len)[:-1]))
+        assert any(f"constant({{{below}}})" in d for d in sources)
+        inputs = [d for d in sources if " parameter(" in d]
+        assert len(inputs) == 1 and 'op_name="positions"' in inputs[0]
+        # a Mosaic kernel over the [32, 22, 2048, 128] cache would be
+        # handed to counts/flash_attention.py by `flash_roofline.serve`
+        # (benchmarks/trace/reduce.py: a custom-call with
+        # kernel_metadata) and read thousands of percent
+        assert "kernel_metadata" not in hlo
+        assert "tpu_custom_call" not in hlo
+        # the donated cache is updated in place and only read by the
+        # branches: no second buffer of its size is planned (one layer's
+        # K is 369 MB)
+        m = compiled.memory_analysis()
+        assert m.alias_size_in_bytes >= 2 * n_layer * 369_000_000
+        assert m.temp_size_in_bytes < 100_000_000
