@@ -36,6 +36,20 @@ def percentile(values, q: float) -> float:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
+def depth_share(stats0, stats1, max_len: int):
+    """Share of the cache's depth that the window's own decode steps
+    read: the engine's `decode_depth_share` counts the warm-up too.
+    Nothing where the program does not count it."""
+    try:
+        steps = sum(stats1["decode_steps_by_depth"].values()) \
+            - sum(stats0["decode_steps_by_depth"].values())
+        read = stats1["decode_read_depth_total"] \
+            - stats0["decode_read_depth_total"]
+    except KeyError:
+        return None
+    return read / (steps * max_len) if steps else None
+
+
 class Client:
     """One request's life on the client's side."""
 
@@ -172,6 +186,9 @@ def run(ctx) -> Dict[str, Any]:
           "tokens_total")}
     d["decode_s"] = stats1["decode_s_total"] - stats0["decode_s_total"]
     d["prefill_s"] = stats1["prefill_s_total"] - stats0["prefill_s_total"]
+    share = depth_share(stats0, stats1, ctx.mix["engine"]["max_len"])
+    if share is not None:
+        d["decode_depth_share"] = share
     return {
         "t_window": t0, "window_s": t_last - t0,
         "note": f"{len(clients)} requests, {len(failed)} failed, "
@@ -179,6 +196,7 @@ def run(ctx) -> Dict[str, Any]:
                 f"{max(late_ms):.1f} ms, {lowerings} programs lowered in the "
                 f"window",
         "attempted": len(clients), "failed": len(failed),
+        # a cell reports those of these that its manifest lists for it
         "end_to_end": {"itl_p50_ms": percentile(gaps_ms, 50),
                        "itl_p99_ms": percentile(gaps_ms, 99)},
         "counters": {**d, "requests": len(clients), "gaps": len(gaps_ms),

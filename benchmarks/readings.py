@@ -19,6 +19,17 @@ cell's own size, one JSON line a seed and reading:
               second, to find the knee (the highest rate with no growing
               backlog and no refusal) that the mix then stores
 
+  gaps        serving cells: one line a seed with the run's candidate
+              tail statistics (the median, the 99th percentile, the mean
+              of the slowest 1%, 2% and 5% of all token gaps), the count
+              of gaps well over the median, the steps and the sender's
+              lateness (a slow spell of the machine shows in both), the
+              ten longest gaps (a pause of the whole process reads as
+              one long gap in every live slot), and a histogram of the
+              gaps in quarter milliseconds; with `--out <dir>` every
+              gap, as `gaps-<cell>-<seed>.npy`. What a `benchmark` issue
+              chooses a cell's tail metric and its bound from
+
 The benchmark's own runs never call this; PERF.md holds what it read.
 """
 
@@ -88,12 +99,64 @@ def _sweep(workload, seed, seconds, rates):
         print(json.dumps({
             "rate_per_s": rate, "requests": len(ttft),
             "failed": res["failed"], "correct": res["correct"],
-            **{n: v["value"] for n, v in res["metrics"].items()},
+            **out["end_to_end"],
+            "setup_s": res["metrics"]["setup_s"]["value"],
             "ttft_p50_first_third_ms": float(np.median(ttft[:k])),
             "ttft_p50_last_third_ms": float(np.median(ttft[-k:])),
             "drain_s": out["window_s"] - seconds,
             "tokens_per_s": out["counters"]["tokens_out"] / out["window_s"],
         }), flush=True)
+
+
+#: the gaps' histogram: quarter milliseconds up to 64 ms, the rest last
+_HIST_BIN_MS, _HIST_BINS = 0.25, 256
+
+
+def _gaps(workload, seeds, seconds, out_dir, **run_kw):
+    """One dict a seed; `run_kw` goes to `run_cell` (a test's tiny
+    manifest, past the look for a chip)."""
+    import os
+    import numpy as np
+    from benchmarks.files import load_py
+    from benchmarks.harness import run_cell
+    tail_mean = load_py("readers", "counter_tail_mean").tail_mean
+
+    def percentile(values, q):
+        return float(np.percentile(values, q))
+
+    for seed in seeds:
+        keep = {}
+        res = run_cell(workload, seed, seconds, False, keep=keep, **run_kw)
+        ctx, out = keep["ctx"], keep["out"]
+        c = out["counters"]
+        gaps = np.asarray(c["itl_ms"], np.float64)
+        p50 = percentile(gaps, 50)
+        hist = np.bincount(np.minimum((gaps / _HIST_BIN_MS).astype(np.int64),
+                                      _HIST_BINS), minlength=_HIST_BINS + 1)
+        if out_dir:
+            os.makedirs(out_dir, exist_ok=True)
+            np.save(os.path.join(out_dir, f"gaps-{workload}-{seed}.npy"),
+                    gaps.astype(np.float32))
+        yield {
+            "seed": seed, "what": "gaps", "correct": res["correct"],
+            "failed": res["failed"], "requests": c["requests"],
+            "gaps": int(gaps.size), "itl_p50_ms": p50,
+            "itl_p99_ms": percentile(gaps, 99),
+            **{f"itl_tail{k}_ms": tail_mean(gaps, k / 100)
+               for k in (1, 2, 5)},
+            "gaps_over_p50_plus_5ms": int((gaps > p50 + 5).sum()),
+            "gaps_over_p50_plus_10ms": int((gaps > p50 + 10).sum()),
+            "decode_steps": c["decode_steps"],
+            "decode_depth_share": c.get("decode_depth_share"),
+            "gen_late_ms_p99": percentile(c["gen_late_ms"], 99),
+            "gen_late_ms_max": max(c["gen_late_ms"]),
+            "ttft_p95_ms": percentile(c["ttft_ms"], 95),
+            "lowerings_in_window": c["lowerings_in_window"],
+            "longest_gaps_ms": np.sort(gaps)[-10:][::-1].tolist(),
+            "window_s": out["window_s"],
+            "setup_s": out["t_window"] - ctx.t_start,
+            "hist_bin_ms": _HIST_BIN_MS, "hist": hist.tolist(),
+        }
 
 
 def main(argv=None) -> int:
@@ -104,12 +167,19 @@ def main(argv=None) -> int:
     ap.add_argument("--control", default="fp8")
     ap.add_argument("--seconds", type=float, default=2.0)
     ap.add_argument("--rates", default="")
+    ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
     from benchmarks.harness import run_cell
     what = args.what.split(",")
     if what == ["sweep"]:
         _sweep(args.workload, int(args.seeds.split(",")[0]), args.seconds,
                [float(r) for r in args.rates.split(",")])
+        return 0
+    if what == ["gaps"]:
+        for line in _gaps(args.workload,
+                          [int(s) for s in args.seeds.split(",")],
+                          args.seconds, args.out):
+            print(json.dumps(line), flush=True)
         return 0
     for seed in (int(s) for s in args.seeds.split(",")):
         keep = {}

@@ -39,7 +39,7 @@ def test_sound_serving_run_is_correct(run_tiny):
     result = run_tiny("tiny-lm.serve", seconds=1.0)
     assert result["correct"] is True
     assert result["attempted"] == 20 and result["failed"] == 0
-    _line_is_well_formed(result, {"itl_p50_ms", "itl_p99_ms", "setup_s"})
+    _line_is_well_formed(result, {"itl_p50_ms", "setup_s"})
     assert set(result["compared"]) == {"answers_short", "logit_gap_max"}
 
 
