@@ -3,7 +3,8 @@
 Parallax (arXiv 1808.02621) treats gradient exchange as a bandwidth
 budget to overlap and shrink rather than a barrier; the TPU-native
 translation for our explicit exchange plan (the elastic per-shard loop,
-`DistriOptimizer._optimize_elastic_impl`) is: split the gradient tree
+`DistriOptimizer._optimize_elastic_impl`, whose `_build_bucket_add`
+chains feed `_build_elastic_update`) is: split the gradient tree
 into size-bounded buckets ordered REVERSE-topologically (output-side
 layers' gradients exist first during the backward pass, and the flat
 param order follows the forward build), then launch each bucket's

@@ -29,19 +29,17 @@ from __future__ import annotations
 
 import logging
 import time
-from typing import Dict, List, Optional
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from bigdl_tpu.dataset.sample import MiniBatch
 from bigdl_tpu.nn.criterion import Criterion
-from bigdl_tpu.nn.module import Module, functional_apply, merge_state
-from bigdl_tpu.optim.local_optimizer import BaseOptimizer, _to_device
+from bigdl_tpu.nn.module import Module, merge_state
+from bigdl_tpu.optim.local_optimizer import BaseOptimizer
 from bigdl_tpu.optim.metrics import Timer
-from bigdl_tpu.optim.trigger import Trigger
 from bigdl_tpu.parallel.mesh import build_mesh, shard_batch
 from bigdl_tpu.parallel.sharding import ShardingRules, infer_param_specs
 from bigdl_tpu.resilience import faults
@@ -85,8 +83,6 @@ class DistriOptimizer(BaseOptimizer):
         self.retry_times = retry_times
         self.retry_interval_s = retry_interval_s
         self.retry_policy = retry_policy
-        self._step = None
-        self._param_shardings = None
         self._elastic = None
         self._bucketing = None
 
@@ -104,25 +100,23 @@ class DistriOptimizer(BaseOptimizer):
         whole-mesh program, so peak scales by the mesh size."""
         return int(np.prod(self.mesh.devices.shape))
 
-    def _place(self, params, model_state, opt_state):
+    def _place(self, params, model_state):
         mesh = self.mesh
-        if self._single_device:
-            dev = mesh.devices.reshape(-1)[0]
-            put1 = lambda leaf: jax.device_put(leaf, dev)
-            return (jax.tree_util.tree_map(put1, params),
-                    jax.tree_util.tree_map(put1, model_state))
-        specs = infer_param_specs(params, mesh, self.rules)
-        self._param_specs = specs
-        put = lambda leaf, spec: jax.device_put(leaf, NamedSharding(mesh, spec))
-        params = jax.tree_util.tree_map(put, params, specs)
-        # model state (BN stats) is small: replicate. Optimizer slots are
-        # created from the already-placed params in optimize(), so
-        # jnp.zeros_like inherits each param's sharding automatically —
-        # the analogue of the reference's per-partition optimMethod state.
-        model_state = jax.tree_util.tree_map(
-            lambda leaf: jax.device_put(leaf, NamedSharding(mesh, P())),
-            model_state)
-        return params, model_state
+        with self._span("place params"):
+            if self._single_device:
+                dev = mesh.devices.reshape(-1)[0]
+                return jax.device_put((params, model_state), dev)
+            specs = infer_param_specs(params, mesh, self.rules)
+            params = jax.tree_util.tree_map(
+                lambda leaf, spec: jax.device_put(
+                    leaf, NamedSharding(mesh, spec)), params, specs)
+            # model state (BN stats) is small: replicate. Optimizer slots
+            # are created from the already-placed params in _begin_run, so
+            # jnp.zeros_like inherits each param's sharding automatically —
+            # the analogue of the reference's per-partition optimMethod
+            # state.
+            return params, jax.device_put(model_state,
+                                          NamedSharding(mesh, P()))
 
     def _build_step(self, state_shardings=None):
         """The jitted train step. `state_shardings`: the shardings of the
@@ -132,81 +126,10 @@ class DistriOptimizer(BaseOptimizer):
         weight back split over 'model'; the next call then finds its
         donated inputs laid out differently from what the executable was
         compiled for, and compiles again."""
-        model, criterion = self.model, self.criterion
-        optim = self.optim_method
-        clip = self._clip_grads_expr
-        precision_scope = self._precision_scope
-        accum = int(getattr(self, "grad_accum_steps", 1) or 1)
-
-        mixed = self._mixed_bf16
-        cast = self._cast_floats
-        guard, need_norms = self._aux_flags()
-        guards = self._apply_step_guards
-
-        def loss_and_grads(params, model_state, x, y, rng):
-            def loss_fn(p):
-                with precision_scope():
-                    # mixed precision: bf16 compute, f32 masters — the cast
-                    # sits INSIDE value_and_grad so its adjoint upcasts the
-                    # gradients back to f32 before clip/update
-                    xc = cast(x, jnp.bfloat16) if mixed else x
-                    if mixed:
-                        p = cast(p, jnp.bfloat16)
-                    out, new_ms = functional_apply(model, p, xc,
-                                                   state=model_state,
-                                                   training=True, rng=rng)
-                    if mixed:
-                        out = cast(out, jnp.float32)
-                    return criterion.apply(out, y), new_ms
-            return jax.value_and_grad(loss_fn, has_aux=True)(params)
-
-        def step(params, opt_state, model_state, x, y, lr, rng):
-            # rng chain lives ON DEVICE: split inside the jitted step and
-            # return the successor, so the host never dispatches a separate
-            # split per iteration
-            rng, step_rng = jax.random.split(rng)
-            if accum > 1:
-                # gradient accumulation: split the batch into `accum`
-                # micro-batches and lax.scan the grad computation, so peak
-                # activation memory shrinks by ~accum while the weight
-                # update sees the FULL batch gradient (mean over micros).
-                def micro(xy):
-                    return jnp.reshape(
-                        xy, (accum, xy.shape[0] // accum) + xy.shape[1:])
-
-                def body(carry, mb):
-                    g_acc, l_acc, ms = carry
-                    mx, my, mrng = mb
-                    (l, new_ms), g = loss_and_grads(params, ms, mx, my,
-                                                    mrng)
-                    g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
-                    return (g_acc, l_acc + l, new_ms), None
-
-                zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
-                rngs = jax.random.split(step_rng, accum)
-                (g_sum, l_sum, new_ms), _ = jax.lax.scan(
-                    body, (zeros, 0.0, model_state),
-                    (micro(x), micro(y), rngs))
-                grads = jax.tree_util.tree_map(lambda g: g / accum, g_sum)
-                loss = l_sum / accum
-            else:
-                (loss, new_ms), grads = loss_and_grads(params, model_state,
-                                                       x, y, step_rng)
-            grads = clip(grads)
-            # full merged state out (model_state is donated: untouched
-            # leaves must alias through the step, not dangle on host)
-            new_ms = merge_state(model_state, new_ms)
-            new_params, new_opt = optim.update_with_masters(
-                grads, opt_state, params, lr)
-            (new_params, new_opt, new_ms), aux = guards(
-                guard, need_norms, loss, grads,
-                (params, opt_state, model_state),
-                (new_params, new_opt, new_ms))
-            if not self._single_device:
-                new_ms = jax.lax.with_sharding_constraint(
-                    new_ms, NamedSharding(self.mesh, P()))
-            return new_params, new_opt, new_ms, loss, rng, aux
-
+        step = self._step_body(
+            None if self._single_device else
+            lambda ms: jax.lax.with_sharding_constraint(
+                ms, NamedSharding(self.mesh, P())))
         abstract_mesh = self.mesh.abstract_mesh
 
         def step_under_mesh(*args):
@@ -218,23 +141,12 @@ class DistriOptimizer(BaseOptimizer):
 
         # jit with sharding propagated from the placed inputs; XLA SPMD
         # partitions the computation and inserts the ICI collectives;
-        # donated: params, optimizer slots, model state, and the rng
-        # chain. With telemetry attached, the compile-telemetry wrapper
-        # emits one `compile` record per distinct (x, y) signature and
-        # carries the executable's FLOP count for step-record
-        # attribution; without it the plain jit fast path is kept
-        # (attribution is observability — an unobserved run must not pay
-        # for it)
-        jitted = jax.jit(
-            step_under_mesh, donate_argnums=(0, 1, 2, 6),
+        # donated: params, optimizer slots, model state, and the rng chain
+        return self._compiled(
+            step_under_mesh, f"distri.step/{type(self.model).__name__}",
+            donate_argnums=(0, 1, 2, 6), sig_argnums=(3, 4),
             out_shardings=None if state_shardings is None
             else (*state_shardings, None, None, None, None))
-        if self.telemetry is None:
-            return jitted
-        from bigdl_tpu.observability.compilation import CompiledFunction
-        return CompiledFunction(
-            jitted=jitted, label=f"distri.step/{type(self.model).__name__}",
-            telemetry=self.telemetry, sig_argnums=(3, 4))
 
     # ------------------------------------------------------------------ #
     def _retry_policy(self) -> RetryPolicy:
@@ -249,21 +161,9 @@ class DistriOptimizer(BaseOptimizer):
         return self.retry_policy
 
     def optimize(self) -> Module:
-        # a snapshot left over from a previous run is stale: the retry
-        # handler must never restore pre-last-run weights after an early
-        # failure in THIS run (each attempt re-snapshots on entry)
-        self._pristine_params = self._pristine_state = None
-        self._maybe_optimize_graph()
-        if self._preemption is not None:
-            # clear any stale latch from a previous preempted run before
-            # re-arming (train-more on the same instance must train)
-            self._preemption.reset()
-            self._preemption.install()
-        try:
+        with self._run_scope():
+            self._maybe_optimize_graph()
             return self._optimize_with_retry()
-        finally:
-            if self._preemption is not None:
-                self._preemption.uninstall()
 
     def _optimize_with_retry(self) -> Module:
         policy = self._retry_policy()
@@ -317,15 +217,12 @@ class DistriOptimizer(BaseOptimizer):
                 # same loader as cold-start resume — digest-verified,
                 # falls back through older snapshots, handles both the
                 # pickle and the orbax-sharded checkpoint formats
-                if self.resume_from_latest_checkpoint():
-                    pass
-                elif self._pristine_params is not None:
+                if not self.resume_from_latest_checkpoint():
                     # crashed before the first checkpoint: the jitted step
                     # DONATED the model's device arrays, so they are dead —
                     # restart from the pristine host snapshot instead of
                     # failing again with "Array has been deleted"
-                    self.model.set_params(self._pristine_params)
-                    self.model._state = self._pristine_state
+                    self._restore_pristine()
                 backoff_spent += delay
                 if delay > 0:
                     policy.sleep(delay)
@@ -336,216 +233,36 @@ class DistriOptimizer(BaseOptimizer):
             # per-replica loop; non-elastic-recoverable failures fall
             # through to the same job-level retry wrapping this call
             return self._optimize_elastic_impl()
-        mesh = self.mesh
-        params = self.model.ensure_params()
-        model_state = self.model._state
-        # host snapshot for pre-first-checkpoint crash recovery (the step
-        # donates the placed arrays, so a failed attempt kills them)
-        self._pristine_params = jax.device_get(params)
-        self._pristine_state = jax.device_get(model_state)
-        with self._span("place params"):
-            params, model_state = self._place(params, model_state, None)
-        resume_slots = getattr(self, "_resume_slots", None)
-        if resume_slots is not None:
-            # restore checkpointed optimizer moments, placed like the
-            # params. COPY, never alias (jnp.array, not asarray): the
-            # donated step would otherwise delete the checkpoint loader's
-            # arrays out from under the retry/`_resume_slots` handling
-            # when they are already jax.Arrays (orbax sharded restores)
-            opt_state = jax.tree_util.tree_map(jnp.array, resume_slots)
-            self._resume_slots = None
-        else:
-            opt_state = self.optim_method.init_state_with_masters(params)
+        run = self._begin_run("distri", self._place)
         # hold the new params and slots to the mesh shardings of the
         # placed ones (a scalar slot made by jnp.zeros is uncommitted:
         # leave it free)
-        step = self._step_fn = self._build_step(jax.tree_util.tree_map(
+        step = self._build_step(jax.tree_util.tree_map(
             lambda a: a.sharding if isinstance(a.sharding, NamedSharding)
-            else None, (params, opt_state)))
-        driver_state = self.optim_method.state
-        # per-host shard feeds this loop; scale records by host count so
-        # epoch triggers fire on global progress
-        num_hosts = getattr(self.dataset, "num_hosts", 1)
-        epoch_size = getattr(self.dataset, "global_size", None) or \
-            self.dataset.size() * num_hosts
-        _, src = self._open_data_pipeline()
-        data_iter = self._fast_forward_data(src, driver_state)
-        self._init_cursor_positions()
-        n_dev = int(np.prod(mesh.devices.shape))
+            else None, (run.params, run.opt_state)))
+        return self._train(run, step,
+                           f"({self._n_compute_devices} devices)")
 
-        def fetch_and_place():
-            """Pull the next host batch and start its async H2D transfer.
+    def _place_batch(self, batch):
+        mesh = self.mesh
 
-            Called right after the train step is dispatched, so the numpy
-            work and the device_put DMA overlap the running step — the
-            reference's analogue is the data-fetch Spark task overlapping
-            the parameter-sync jobs (DistriOptimizer.scala:330-339). With
-            `set_prefetch` armed, `next(data_iter)` pops the background
-            input pipeline (dataset/prefetch.py) instead of running the
-            transformer chain inline, so chains slower than one device
-            step stop serializing the loop.
+        def place_any(v):
+            if v is None:
+                return None
+            if isinstance(v, list):
+                return Table(*[shard_batch(mesh, e) for e in v])
+            return shard_batch(mesh, v)
 
-            The two phase timers here run while the previous step is still
-            executing on-device, so their wall time OVERLAPS "computing
-            time average" (which spans dispatch -> loss sync); the phase
-            table is intentionally not additive."""
-            with Timer(self.metrics, "data fetch time"), \
-                    self._span("data fetch"):
-                batch: MiniBatch = next(data_iter, None)
-                if batch is None:  # finite stream exhausted
-                    logger.warning(
-                        "training data stream exhausted before the end "
-                        "trigger fired; stopping early (train=True datasets "
-                        "normally loop forever)")
-                    return None
-                self._note_pull()
-            with Timer(self.metrics, "put batch on mesh"), \
-                    self._span("put batch on mesh"):
-                x = batch.get_input()
-                y = batch.get_target()
-                def place_any(v):
-                    if v is None:
-                        return None
-                    if isinstance(v, list):
-                        return Table(*[shard_batch(mesh, e) for e in v])
-                    return shard_batch(mesh, v)
+        with Timer(self.metrics, "put batch on mesh"), \
+                self._span("put batch on mesh"):
+            return place_any(batch.get_input()), \
+                place_any(batch.get_target())
 
-                x = place_any(x)
-                y = place_any(y)
-            return batch, x, y
-
-        sync_every = max(1, int(getattr(self, "sync_interval", 1)))
-        self._telemetry_run_start("distri")
-        win = self._SyncWindow()
-        loss_val = float("nan")  # last synced loss
-        loss = None  # device array of the most recent step's loss
-        lr = None
-        preempted = False
-        aux_pending = []  # per-dispatch instrumentation scalars (tiny)
-        # device-resident rng chain, advanced inside the donated step; a
-        # COPY so self.rng survives donation and the retry path can seed a
-        # fresh chain after a failed attempt killed the in-flight buffers
-        rng_dev = jnp.asarray(self.rng) + 0
-        pending = fetch_and_place()
-        while pending is not None and not self.end_trigger(driver_state):
-            batch, x, y = pending
-            with self._span("step prepare"):
-                # chaos hook: a no-op unless a FaultInjector is installed
-                # — lets tests crash the loop at an exact iteration and
-                # drive the retry/reload machinery deterministically
-                faults.fire("train.step", step=driver_state["neval"] + 1)
-                lr = self.optim_method.current_lr()
-            with self._span("step dispatch", step=driver_state["neval"] + 1):
-                params, opt_state, new_ms, loss, rng_dev, aux = step(
-                    params, opt_state, model_state, x, y, lr, rng_dev)
-            if aux:
-                aux_pending.append(aux)
-            # prefetch while the dispatched step runs on-device (deliberate
-            # one-batch lookahead: the final prefetch of an optimize() call
-            # is discarded — one batch of host work per run buys the
-            # fetch/H2D overlap on every iteration)
-            pending = fetch_and_place()
-            do_sync = (driver_state["neval"] + 1) % sync_every == 0
-            if do_sync:
-                # waits for the step; donation chains steps, so this means
-                # every dispatched step up to here has completed
-                with self._span("loss sync"):
-                    loss_val = float(loss)
-            # the host's tail of the step, one span whether it synced or
-            # not: counters, the sync's records and log line, summaries,
-            # epoch roll-over, validation, checkpoint, hook
-            with self._span("step bookkeeping"):
-                model_state = new_ms  # step returns the FULL merged state
-
-                n = batch.size() * num_hosts  # global records this step
-                driver_state["neval"] += 1
-                driver_state["recordsProcessedThisEpoch"] += n
-                driver_state["loss"] = loss_val
-                win.add(n)
-                if do_sync:
-                    # throughput + per-iteration compute time over the sync
-                    # window: exact wall time between device-drained points,
-                    # valid for any sync_interval (per iteration when 1,
-                    # reference semantics). The window counts ONLY
-                    # dispatch+device time — it restarts after the
-                    # validation/checkpoint/hook tail at the iteration end —
-                    # and recording the metric only at sync keeps "computing
-                    # time average" a true per-step figure (per-dispatch
-                    # timing is meaningless under async).
-                    throughput = win.throughput(self.metrics)
-                    self._observe_sync(driver_state, loss_val, lr, throughput,
-                                       win.step_time_s, n, aux_pending)
-                    logger.info(
-                        f"[Epoch {driver_state['epoch'] + 1} "
-                        f"{driver_state['recordsProcessedThisEpoch']}/"
-                        f"{epoch_size}]"
-                        f"[Iteration {driver_state['neval']}] Training cost "
-                        f"{loss_val}. Throughput is {throughput} "
-                        f"records/second. ({n_dev} devices)")
-                if do_sync and self.train_summary is not None:
-                    it = driver_state["neval"]
-                    self.train_summary.add_scalar("Loss", loss_val, it)
-                    self.train_summary.add_scalar("LearningRate",
-                                                  self._lr_scalar(lr), it)
-                    self.train_summary.add_scalar("Throughput",
-                                                  throughput, it)
-                    # Parameters histograms only behind an explicit trigger —
-                    # they pull every sharded weight to host
-                    # (AbstractOptimizer.scala:47-92)
-                    trig = getattr(self.train_summary, "get_summary_trigger",
-                                   lambda _n: None)("Parameters")
-                    if trig is not None and trig(driver_state):
-                        host = jax.device_get(params)
-                        flat = jax.tree_util.tree_flatten_with_path(host)[0]
-                        for path, leaf in flat:
-                            tag = "/".join(
-                                str(getattr(p, "key", getattr(p, "idx", p)))
-                                for p in path)
-                            self.train_summary.add_histogram(tag, leaf, it)
-
-                if driver_state["recordsProcessedThisEpoch"] >= epoch_size:
-                    driver_state["epoch"] += 1
-                    driver_state["recordsProcessedThisEpoch"] = 0
-                    self._shuffle_dataset()
-
-                with self._span("validation"):
-                    self._validate(params, model_state, driver_state)
-                if self.checkpoint_trigger \
-                        and self.checkpoint_trigger(driver_state):
-                    with Timer(self.metrics, "checkpoint time"), \
-                            self._span("checkpoint"):
-                        self._save_checkpoint(
-                            params, model_state,
-                            tag=f"iter{driver_state['neval']}",
-                            opt_slots=opt_state)
-                if self.iteration_hook is not None:
-                    self.iteration_hook(driver_state)
-                if self._check_preemption(params, model_state, opt_state,
-                                          driver_state, loss):
-                    preempted = True
-                    break
-                if do_sync:
-                    win.restart()  # exclude the tail work from the next window
-
-        if sync_every > 1 and loss is not None and \
-                driver_state["neval"] % sync_every != 0:
-            # the loop ended between syncs: surface the true final loss
-            driver_state["loss"] = loss_val = float(loss)
-        if aux_pending:
-            # partial tail window: guards/monitors still see those steps
-            self._observe_sync(driver_state, loss_val, lr, float("nan"),
-                               float("nan"), 0, aux_pending)
-        if not preempted:  # a preempted run already closed with run_abort
-            self._telemetry_run_end(driver_state)
-        # persist the advanced rng chain so a subsequent optimize() call
-        # (resume / train-more) continues the dropout/noise stream instead
-        # of replaying it (LocalOptimizer advances self.rng the same way)
+    def _return_state(self, params, model_state, rng):
+        # gather back to host (reference getModel:646 pulls partitions)
         with self._span("gather params"):
-            self.rng = jax.device_get(rng_dev)
-            # gather back to host (reference getModel:646 pulls partitions)
-            self.model.set_params(jax.device_get(params))
-            self.model._state = jax.device_get(model_state)
-        return self.model
+            super()._return_state(
+                *jax.device_get((params, model_state)), rng)
 
 
     # ------------------------------------------------------------------ #
@@ -620,33 +337,13 @@ class DistriOptimizer(BaseOptimizer):
     setElastic = set_elastic
 
     def _build_elastic_shard_fn(self):
-        """One jitted per-logical-shard (loss, grads, new_state) fn. The
-        SAME function object serves every shard on every device — jax
-        caches one executable per device placement, and identical HLO on
-        identical device types is what makes shard results independent of
-        WHICH device computed them (the elastic determinism contract)."""
-        model, criterion = self.model, self.criterion
-        precision_scope = self._precision_scope
-        mixed = self._mixed_bf16
-        cast = self._cast_floats
-
-        def shard_step(params, model_state, x, y, rng):
-            def loss_fn(p):
-                with precision_scope():
-                    xc = cast(x, jnp.bfloat16) if mixed else x
-                    if mixed:
-                        p = cast(p, jnp.bfloat16)
-                    out, new_ms = functional_apply(model, p, xc,
-                                                   state=model_state,
-                                                   training=True, rng=rng)
-                    if mixed:
-                        out = cast(out, jnp.float32)
-                    return criterion.apply(out, y), new_ms
-            (l, new_ms), g = jax.value_and_grad(loss_fn,
-                                                has_aux=True)(params)
-            return l, g, new_ms
-
-        return jax.jit(shard_step)
+        """One jitted per-logical-shard `((loss, new_state), grads)` fn:
+        the loops' one loss closure. The SAME function object serves
+        every shard on every device — jax caches one executable per
+        device placement, and identical HLO on identical device types is
+        what makes shard results independent of WHICH device computed
+        them (the elastic determinism contract)."""
+        return jax.jit(self._loss_and_grads())
 
     def set_gradient_bucketing(self, bucket_mb: float = 4.0,
                                enabled: bool = True):
@@ -681,50 +378,37 @@ class DistriOptimizer(BaseOptimizer):
 
     setGradientBucketing = set_gradient_bucketing
 
-    @staticmethod
-    def _elastic_mean(losses, states, R0: int):
-        """Shared post-reduction tail of both exchange plans: mean loss
-        over shards plus float-leaf-averaged model state (counters take
-        shard 0's value)."""
-        loss = losses[0]
-        for li in losses[1:]:
-            loss = loss + li
-        loss = loss / R0
-
-        def avg(*ls):
-            a = ls[0]
-            if not (hasattr(a, "dtype")
-                    and jnp.issubdtype(a.dtype, jnp.floating)):
-                return a  # counters etc. take shard 0's value
-            s = a
-            for o in ls[1:]:
-                s = s + o
-            return s / R0
-
-        ms = states[0] if R0 == 1 else jax.tree_util.tree_map(avg, *states)
-        return loss, ms
-
-    def _build_elastic_combine(self, R0: int):
-        """Jitted fixed-order reduction + weight update on the lead
-        device: sum the R0 shard gradients SEQUENTIALLY (never a psum —
-        reduction order must not depend on the mesh shape), mean, clip,
-        update. Model-state float leaves average the same way."""
+    def _build_elastic_update(self, R0: int):
+        """Jitted tail of both exchange plans on the lead device: mean,
+        clip, weight update, mean loss and float-leaf-averaged model
+        state. `grads` is a tuple of gradient trees summed here
+        SEQUENTIALLY (never a psum — reduction order must not depend on
+        the mesh shape): the R0 shards' trees under the barrier plan, the
+        one tree the per-bucket donated chains already summed under the
+        bucketed one."""
         optim = self.optim_method
         clip = self._clip_grads_expr
-        mean_tail = self._elastic_mean
 
-        def combine(params, opt_state, lr, losses, grads, states):
-            g = grads[0]
-            for gi in grads[1:]:
-                g = jax.tree_util.tree_map(jnp.add, g, gi)
-            g = jax.tree_util.tree_map(lambda a: a / R0, g)
-            g = clip(g)
-            new_params, new_opt = optim.update_with_masters(g, opt_state,
-                                                            params, lr)
-            loss, ms = mean_tail(losses, states, R0)
-            return new_params, new_opt, ms, loss
+        def total(first, *rest):
+            for o in rest:
+                first = first + o
+            return first
 
-        return jax.jit(combine)
+        def avg(*ls):
+            if not (hasattr(ls[0], "dtype")
+                    and jnp.issubdtype(ls[0].dtype, jnp.floating)):
+                return ls[0]  # counters etc. take shard 0's value
+            return total(*ls) / R0
+
+        def update(params, opt_state, lr, grads, losses, states):
+            g = jax.tree_util.tree_map(lambda *ls: total(*ls) / R0, *grads)
+            new_params, new_opt = optim.update_with_masters(
+                clip(g), opt_state, params, lr)
+            ms = states[0] if R0 == 1 \
+                else jax.tree_util.tree_map(avg, *states)
+            return new_params, new_opt, ms, total(*losses) / R0
+
+        return jax.jit(update)
 
     def _build_bucket_add(self):
         """ONE accumulate callable for every bucket: adds a shard's
@@ -735,30 +419,8 @@ class DistriOptimizer(BaseOptimizer):
         def bucket_add(acc, g):
             return tuple(a + b for a, b in zip(acc, g))
 
-        if self.telemetry is None:
-            return jax.jit(bucket_add, donate_argnums=(0,))
-        from bigdl_tpu.observability.compilation import CompiledFunction
-        return CompiledFunction(bucket_add, label="distri.bucket_add",
-                                telemetry=self.telemetry,
-                                donate_argnums=(0,))
-
-    def _build_elastic_finalize(self, R0: int):
-        """Jitted tail of the BUCKETED exchange: the gradients arrive
-        already summed over shards (per-bucket donated chains), so only
-        mean, clip, update, and the loss/state averaging remain."""
-        optim = self.optim_method
-        clip = self._clip_grads_expr
-        mean_tail = self._elastic_mean
-
-        def finalize(params, opt_state, lr, g_sum, losses, states):
-            g = jax.tree_util.tree_map(lambda a: a / R0, g_sum)
-            g = clip(g)
-            new_params, new_opt = optim.update_with_masters(g, opt_state,
-                                                            params, lr)
-            loss, ms = mean_tail(losses, states, R0)
-            return new_params, new_opt, ms, loss
-
-        return jax.jit(finalize)
+        return self._compiled(bucket_add, "distri.bucket_add",
+                              donate_argnums=(0,))
 
     @staticmethod
     def _elastic_recoverable(e: BaseException) -> bool:
@@ -821,51 +483,29 @@ class DistriOptimizer(BaseOptimizer):
             registry.telemetry = self.telemetry
         self._step_fn = None  # no compiled-step attribution in elastic mode
 
-        def place(tree, d):
-            return jax.tree_util.tree_map(
-                lambda l: jax.device_put(l, d), tree)
+        place = jax.device_put  # a whole tree onto one device
 
         registry.sweep()
         total_dev = registry.total_devices()
         plan = controller.plan(registry.alive_devices(), total_dev)
         lead = plan.lead
 
-        params = place(self.model.ensure_params(), lead)
-        model_state = place(self.model._state, lead)
-        resume_slots = getattr(self, "_resume_slots", None)
-        if resume_slots is not None:
-            opt_state = place(jax.tree_util.tree_map(np.asarray,
-                                                     resume_slots), lead)
-            self._resume_slots = None
-        else:
-            opt_state = self.optim_method.init_state_with_masters(params)
+        run = self._begin_run(
+            "distri_elastic",
+            lambda params, ms: (place(params, lead), place(ms, lead)))
+        run.opt_state = place(run.opt_state, lead)  # resumed slots too
+        state = run.state
         shard_fn = self._build_elastic_shard_fn()
-        combine_fn = self._build_elastic_combine(R0)
-        bplan = bucket_add = finalize_fn = None
+        update_fn = self._build_elastic_update(R0)
+        bplan = bucket_add = None
         if self._bucketing is not None:
             from bigdl_tpu.optim.bucketing import GradientBucketPlan
-            bplan = GradientBucketPlan(params,
+            bplan = GradientBucketPlan(run.params,
                                        self._bucketing["bucket_bytes"])
             bucket_add = self._build_bucket_add()
-            finalize_fn = self._build_elastic_finalize(R0)
             if self.telemetry is not None:
                 self.telemetry.event("bucket_plan", **bplan.describe())
-        driver_state = self.optim_method.state
-        num_hosts = getattr(self.dataset, "num_hosts", 1)
-        epoch_size = getattr(self.dataset, "global_size", None) or \
-            self.dataset.size() * num_hosts
-        _, src = self._open_data_pipeline()
-        data_iter = self._fast_forward_data(src, driver_state)
-        self._init_cursor_positions()
         rng = jnp.asarray(self.rng) + 0  # host-driven chain, committable
-
-        sync_every = max(1, int(getattr(self, "sync_interval", 1)))
-        self._telemetry_run_start("distri_elastic")
-        win = self._SyncWindow()
-        loss_val = float("nan")
-        loss = None
-        lr = None
-        preempted = False
         recoveries = 0  # consecutive recoveries with no committed progress
         replay_q = collections.deque()  # batches awaiting re-training
         window_batches: List = []       # batches consumed since commit
@@ -879,39 +519,31 @@ class DistriOptimizer(BaseOptimizer):
                 # re-validates: everything buffered is retrained by then)
                 self._cursor_valid = False
             else:
-                with Timer(self.metrics, "data fetch time"), \
-                        self._span("data fetch"):
-                    b = next(data_iter, None)
-                if b is None:
-                    logger.warning(
-                        "training data stream exhausted before the end "
-                        "trigger fired; stopping early")
-                else:
-                    self._note_pull()
+                b = self._pull_batch(run.data_iter)
             if b is not None:
                 window_batches.append(b)
             return b
 
         def commit():
-            return {"params": jax.device_get(params),
-                    "opt": jax.device_get(opt_state),
-                    "ms": jax.device_get(model_state),
+            return {"params": jax.device_get(run.params),
+                    "opt": jax.device_get(run.opt_state),
+                    "ms": jax.device_get(run.model_state),
                     "rng": jax.device_get(rng),
-                    "state": dict(driver_state),
-                    "loss_val": loss_val}
+                    "state": dict(state),
+                    "loss_val": run.loss_val}
 
         committed = commit()
-        while not self.end_trigger(driver_state):
+        while not self.end_trigger(state):
             batch = fetch()
             if batch is None:
                 break
-            step_no = driver_state["neval"] + 1
+            step_no = state["neval"] + 1
             try:
                 with self._span("step prepare"):
                     faults.fire("train.step", step=step_no)
                     faults.fire("mesh.device_loss", step=step_no,
                                 n_active=plan.n_active)
-                    lr = self.optim_method.current_lr()
+                    run.lr = self.optim_method.current_lr()
                     rng, step_rng = jax.random.split(rng)
                     # shard rng streams key off the LOGICAL index — a
                     # shard's dropout/noise draw survives remapping to
@@ -922,8 +554,9 @@ class DistriOptimizer(BaseOptimizer):
                 with self._span("step dispatch", step=step_no):
                     per_dev = {}
                     for d in plan.devices:
-                        per_dev[d] = (params, model_state) if d is lead \
-                            else (place(params, d), place(model_state, d))
+                        per_dev[d] = (run.params, run.model_state) \
+                            if d is lead else (place(run.params, d),
+                                               place(run.model_state, d))
                     losses_d, grads_d, ms_d = [], [], []
                     acc = [None] * len(bplan) if bplan is not None else None
                     for i in range(R0):
@@ -937,7 +570,7 @@ class DistriOptimizer(BaseOptimizer):
                         with self._worker_span(
                                 wid, "shard dispatch", shard=i,
                                 step=step_no, device=str(d)):
-                            l_i, g_i, m_i = shard_fn(
+                            (l_i, m_i), g_i = shard_fn(
                                 p_d, ms_dv, jax.device_put(xs[i], d),
                                 jax.device_put(ys[i], d),
                                 jax.device_put(shard_rngs[i], d))
@@ -956,7 +589,7 @@ class DistriOptimizer(BaseOptimizer):
                         # the lead reduces shard i's gradients while
                         # shard i+1's backward still runs on its device.
                         # Shard order per bucket matches the barrier
-                        # combine's sequential sum, so the trajectory
+                        # plan's sequential sum, so the trajectory
                         # stays BIT-identical.
                         for b, leaves in enumerate(bplan.split(g_i)):
                             if d is not lead:
@@ -966,18 +599,15 @@ class DistriOptimizer(BaseOptimizer):
                                 else bucket_add(acc[b], leaves)
                     faults.fire("mesh.collective", step=step_no,
                                 n_active=plan.n_active)
-                    if bplan is None:
-                        params, opt_state, new_ms, loss = combine_fn(
-                            params, opt_state, lr, tuple(losses_d),
-                            tuple(grads_d), tuple(ms_d))
-                    else:
-                        params, opt_state, new_ms, loss = finalize_fn(
-                            params, opt_state, lr, bplan.join(acc),
-                            tuple(losses_d), tuple(ms_d))
-                do_sync = step_no % sync_every == 0
+                    run.params, run.opt_state, new_ms, run.loss = update_fn(
+                        run.params, run.opt_state, run.lr,
+                        tuple(grads_d) if bplan is None
+                        else (bplan.join(acc),),
+                        tuple(losses_d), tuple(ms_d))
+                do_sync = step_no % run.sync_every == 0
                 if do_sync:
                     with self._span("loss sync"):
-                        loss_val = float(loss)
+                        run.loss_val = float(run.loss)
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:
@@ -1028,13 +658,13 @@ class DistriOptimizer(BaseOptimizer):
                         degraded_capacity=new_plan.degraded_capacity,
                         error=repr(e))
                 plan, lead = new_plan, new_plan.lead
-                params = place(committed["params"], lead)
-                opt_state = place(committed["opt"], lead)
-                model_state = place(committed["ms"], lead)
+                run.params = place(committed["params"], lead)
+                run.opt_state = place(committed["opt"], lead)
+                run.model_state = place(committed["ms"], lead)
                 rng = jnp.asarray(committed["rng"])
-                driver_state.clear()
-                driver_state.update(committed["state"])
-                loss, loss_val = None, committed["loss_val"]
+                state.clear()
+                state.update(committed["state"])
+                run.loss, run.loss_val = None, committed["loss_val"]
                 # a failure mid-replay keeps the still-queued tail
                 replay = window_batches + list(replay_q)
                 replay_q.clear()
@@ -1044,110 +674,53 @@ class DistriOptimizer(BaseOptimizer):
                     self.telemetry.event(
                         "elastic_replay", batches=len(replay_q),
                         from_step=controller.replay_boundary(
-                            driver_state.get("neval", 0)))
-                win.restart()
+                            state.get("neval", 0)))
+                run.win.restart()
                 continue
 
-            # the host's tail of the step (see the SPMD loop), with the
-            # elastic commit and boundary replan inside it
-            with self._span("step bookkeeping"):
-                model_state = merge_state(model_state, new_ms)
-                n = batch.size() * num_hosts
-                driver_state["neval"] += 1
-                driver_state["recordsProcessedThisEpoch"] += n
-                driver_state["loss"] = loss_val
-                win.add(n)
-                if do_sync:
-                    throughput = win.throughput(self.metrics)
-                    self._observe_sync(driver_state, loss_val, lr, throughput,
-                                       win.step_time_s, n, [])
+            run.model_state = merge_state(run.model_state, new_ms)
+            boundary = self._finish_iteration(
+                run, batch, do_sync, f"({plan.n_active} devices, elastic)")
+            if run.preempted:
+                break
+            if do_sync or boundary:
+                # commit: this state is now the rollback target. Epoch
+                # boundaries ALWAYS commit so a rollback never replays a
+                # dataset reshuffle (the tail's shuffle already consumed
+                # the dataset rng).
+                committed = commit()
+                window_batches.clear()
+                recoveries = 0  # committed progress past the failures
+                # boundary replan: lease expiries shrink proactively,
+                # revived workers grow the fleet back — both at a
+                # committed point, so no rollback is needed
+                registry.sweep()
+                new_plan = controller.plan(registry.alive_devices(),
+                                           total_dev)
+                if new_plan.devices != plan.devices:
+                    grow = new_plan.n_active > plan.n_active
+                    if self.telemetry is not None:
+                        self.telemetry.event(
+                            "elastic_grow" if grow else "elastic_shrink",
+                            step=state["neval"],
+                            n_active_before=plan.n_active,
+                            n_active=new_plan.n_active,
+                            alive_workers=len(registry.alive()),
+                            degraded_capacity=new_plan.degraded_capacity)
                     logger.info(
-                        f"[Epoch {driver_state['epoch'] + 1} "
-                        f"{driver_state['recordsProcessedThisEpoch']}/"
-                        f"{epoch_size}]"
-                        f"[Iteration {driver_state['neval']}] Training cost "
-                        f"{loss_val}. Throughput is {throughput} "
-                        f"records/second. ({plan.n_active} devices, elastic)")
-                    if self.train_summary is not None:
-                        it = driver_state["neval"]
-                        self.train_summary.add_scalar("Loss", loss_val, it)
-                        self.train_summary.add_scalar(
-                            "LearningRate", self._lr_scalar(lr), it)
-                        self.train_summary.add_scalar("Throughput",
-                                                      throughput, it)
-
-                boundary = driver_state["recordsProcessedThisEpoch"] >= \
-                    epoch_size
-                if boundary:
-                    driver_state["epoch"] += 1
-                    driver_state["recordsProcessedThisEpoch"] = 0
-                    self._shuffle_dataset()
-
-                with self._span("validation"):
-                    self._validate(params, model_state, driver_state)
-                if self.checkpoint_trigger and \
-                        self.checkpoint_trigger(driver_state):
-                    with Timer(self.metrics, "checkpoint time"), \
-                            self._span("checkpoint"):
-                        self._save_checkpoint(
-                            params, model_state,
-                            tag=f"iter{driver_state['neval']}",
-                            opt_slots=opt_state)
-                if self.iteration_hook is not None:
-                    self.iteration_hook(driver_state)
-                if self._check_preemption(params, model_state, opt_state,
-                                          driver_state, loss):
-                    preempted = True
-                    break
-
-                if do_sync or boundary:
-                    # commit: this state is now the rollback target. Epoch
-                    # boundaries ALWAYS commit so a rollback never replays a
-                    # dataset reshuffle (the shuffle above already consumed
-                    # the dataset rng).
-                    committed = commit()
-                    window_batches.clear()
-                    recoveries = 0  # committed progress past the failures
-                    # boundary replan: lease expiries shrink proactively,
-                    # revived workers grow the fleet back — both at a
-                    # committed point, so no rollback is needed
-                    registry.sweep()
-                    new_plan = controller.plan(registry.alive_devices(),
-                                               total_dev)
-                    if new_plan.devices != plan.devices:
-                        grow = new_plan.n_active > plan.n_active
-                        if self.telemetry is not None:
-                            self.telemetry.event(
-                                "elastic_grow" if grow else "elastic_shrink",
-                                step=driver_state["neval"],
-                                n_active_before=plan.n_active,
-                                n_active=new_plan.n_active,
-                                alive_workers=len(registry.alive()),
-                                degraded_capacity=new_plan.degraded_capacity)
-                        logger.info(
-                            "elastic %s at step %d: %d -> %d active devices",
-                            "grow" if grow else "shrink",
-                            driver_state["neval"], plan.n_active,
-                            new_plan.n_active)
-                        plan = new_plan
-                        if plan.lead is not lead:
-                            params = place(params, plan.lead)
-                            opt_state = place(opt_state, plan.lead)
-                            model_state = place(model_state, plan.lead)
-                            lead = plan.lead
+                        "elastic %s at step %d: %d -> %d active devices",
+                        "grow" if grow else "shrink",
+                        state["neval"], plan.n_active, new_plan.n_active)
+                    plan = new_plan
+                    if plan.lead is not lead:
+                        lead = plan.lead
+                        run.params = place(run.params, lead)
+                        run.opt_state = place(run.opt_state, lead)
+                        run.model_state = place(run.model_state, lead)
                 if do_sync:
-                    win.restart()
-
-        if sync_every > 1 and loss is not None and \
-                driver_state["neval"] % sync_every != 0:
-            driver_state["loss"] = loss_val = float(loss)
-        if not preempted:
-            self._telemetry_run_end(driver_state)
-        with self._span("gather params"):
-            self.rng = jax.device_get(rng)
-            self.model.set_params(jax.device_get(params))
-            self.model._state = jax.device_get(model_state)
-        return self.model
+                    # the commit's host snapshot is not training time
+                    run.win.restart()
+        return self._finish_run(run, rng)
 
 
 class ParallelOptimizer(DistriOptimizer):

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu.dataset.dataset import AbstractDataSet
-from bigdl_tpu.dataset.sample import MiniBatch
 from bigdl_tpu.nn.criterion import Criterion
 from bigdl_tpu.nn.module import Module, functional_apply, merge_state
 from bigdl_tpu.optim.metrics import Metrics, Timer
@@ -928,65 +926,23 @@ class BaseOptimizer:
                 sched.record(driver_state["score"], self.optim_method)
         return results
 
-
-class LocalOptimizer(BaseOptimizer):
-    """Train on the local device (one TPU chip / CPU)."""
-
-    def __init__(self, model: Module, dataset, criterion: Criterion,
-                 batch_size: int = 32):
-        super().__init__(model, dataset, criterion)
-        self.batch_size = batch_size
-
-    def optimize(self) -> Module:
-        # a snapshot left over from a PREVIOUS run is stale: a failure
-        # early in this run (before _optimize_impl re-snapshots) must
-        # not revert the model to pre-last-run weights
-        self._pristine_params = self._pristine_state = None
-        if self._preemption is not None:
-            # a latch left set by a previous preempted run is stale: the
-            # next optimize() (train-more / drill reuse) must train, not
-            # instantly re-abort
-            self._preemption.reset()
-            self._preemption.install()
-        try:
-            return self._optimize_impl()
-        except (KeyboardInterrupt, SystemExit):
-            self._restore_pristine()
-            raise
-        except Exception as e:
-            self._telemetry_run_abort(e)
-            # the donated step killed the model's device arrays; put the
-            # pre-run host snapshot back so the instance stays usable
-            # (pre-donation behavior: params unchanged on failure)
-            self._restore_pristine()
-            raise
-        finally:
-            # join prefetch workers whether the run finished or died —
-            # repeated optimize() calls must never accumulate threads
-            self._close_data_pipeline(self._active_pipeline)
-            if self._preemption is not None:
-                self._preemption.uninstall()
-
-    def _restore_pristine(self):
-        """Put the pre-run host snapshot back on the model after a failed
-        donated run (the step aliased the model's old device buffers)."""
-        if self._pristine_params is not None:
-            self.model.set_params(self._pristine_params)
-            self.model._state = self._pristine_state
-
-    def _build_step(self):
+    # -- the train step: one loss closure, one body, one compile choice --
+    def _loss_and_grads(self):
+        """The loss closure every loop differentiates: `(params,
+        model_state, x, y, rng) -> ((loss, new_ms), grads)`. The step body
+        calls it once or once per micro-batch; the elastic loop jits it
+        as its per-shard executable."""
         model, criterion = self.model, self.criterion
-        optim = self.optim_method
-        clip = self._clip_grads_expr
         precision_scope = self._precision_scope
         mixed = self._mixed_bf16
         cast = self._cast_floats
-        guard, need_norms = self._aux_flags()
-        guards = self._apply_step_guards
 
-        def step(params, opt_state, model_state, x, y, lr, rng):
+        def loss_and_grads(params, model_state, x, y, rng):
             def loss_fn(p):
                 with precision_scope():
+                    # mixed precision: bf16 compute, f32 masters — the cast
+                    # sits INSIDE value_and_grad so its adjoint upcasts the
+                    # gradients back to f32 before clip/update
                     xc = cast(x, jnp.bfloat16) if mixed else x
                     if mixed:
                         p = cast(p, jnp.bfloat16)
@@ -996,8 +952,54 @@ class LocalOptimizer(BaseOptimizer):
                     if mixed:
                         out = cast(out, jnp.float32)
                     return criterion.apply(out, y), new_ms
+            return jax.value_and_grad(loss_fn, has_aux=True)(params)
 
-            (loss, new_ms), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+        return loss_and_grads
+
+    def _step_body(self, constrain_state=None):
+        """The train step, traced: `step(params, opt_state, model_state,
+        x, y, lr, rng) -> (params, opt_state, model_state, loss, rng,
+        aux)`. `constrain_state`, the one thing a loop adds, is applied to
+        the new model state (the SPMD loop holds it replicated)."""
+        loss_and_grads = self._loss_and_grads()
+        optim = self.optim_method
+        clip = self._clip_grads_expr
+        accum = int(self.grad_accum_steps or 1)
+        guard, need_norms = self._aux_flags()
+        guards = self._apply_step_guards
+
+        def step(params, opt_state, model_state, x, y, lr, rng):
+            # rng chain lives ON DEVICE: split inside the jitted step and
+            # return the successor, so the host never dispatches a separate
+            # split per iteration
+            rng, step_rng = jax.random.split(rng)
+            if accum > 1:
+                # gradient accumulation: split the batch into `accum`
+                # micro-batches and lax.scan the grad computation, so peak
+                # activation memory shrinks by ~accum while the weight
+                # update sees the FULL batch gradient (mean over micros).
+                def micro(xy):
+                    return jnp.reshape(
+                        xy, (accum, xy.shape[0] // accum) + xy.shape[1:])
+
+                def body(carry, mb):
+                    g_acc, l_acc, ms = carry
+                    mx, my, mrng = mb
+                    (l, new_ms), g = loss_and_grads(params, ms, mx, my,
+                                                    mrng)
+                    g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
+                    return (g_acc, l_acc + l, new_ms), None
+
+                zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
+                rngs = jax.random.split(step_rng, accum)
+                (g_sum, l_sum, new_ms), _ = jax.lax.scan(
+                    body, (zeros, 0.0, model_state),
+                    (micro(x), micro(y), rngs))
+                grads = jax.tree_util.tree_map(lambda g: g / accum, g_sum)
+                loss = l_sum / accum
+            else:
+                (loss, new_ms), grads = loss_and_grads(params, model_state,
+                                                       x, y, step_rng)
             grads = clip(grads)
             # return the FULL merged state, not the partial update:
             # model_state is donated, so untouched old leaves must flow
@@ -1010,38 +1012,68 @@ class LocalOptimizer(BaseOptimizer):
                 guard, need_norms, loss, grads,
                 (params, opt_state, model_state),
                 (new_params, new_opt, new_ms))
-            return new_params, new_opt, new_ms, loss, aux
+            if constrain_state is not None:
+                new_ms = constrain_state(new_ms)
+            return new_params, new_opt, new_ms, loss, rng, aux
 
-        # donation: params, optimizer slots, and model state alias their
-        # output buffers (PERF.md measured a ~20x dispatch penalty for
-        # non-donated same-shape probes on the distri path; the local
-        # loop now gets the same aliasing). The guards' skip-mode revert
-        # stays donation-safe: jnp.where selects between traced values.
-        #
-        # With telemetry attached, route the step through the
-        # compile-telemetry wrapper: one `compile` record per distinct
-        # step signature, FLOPs/bytes off the executable for the step
-        # records' attribution fields. Signature = the batch args only —
-        # param/opt trees keep constant avals within a run. Without
-        # telemetry the plain jit path (and its C++ fast dispatch) is
-        # kept — attribution is observability, and an unobserved run
-        # must not pay for it
+        return step
+
+    def _compiled(self, fn, label: str, donate_argnums, sig_argnums=None,
+                  **jit_kwargs):
+        """`jax.jit(fn)`; with telemetry attached, routed through the
+        compile-telemetry wrapper: one `compile` record per distinct
+        signature (`sig_argnums`: the batch args only — param/opt trees
+        keep constant avals within a run) and FLOPs/bytes off the
+        executable for the step records' attribution fields. Without
+        telemetry the plain jit path (and its C++ fast dispatch) is kept:
+        attribution is observability, and an unobserved run must not pay
+        for it."""
+        jitted = jax.jit(fn, donate_argnums=donate_argnums, **jit_kwargs)
         if self.telemetry is None:
-            return jax.jit(step, donate_argnums=(0, 1, 2))
+            return jitted
         from bigdl_tpu.observability.compilation import CompiledFunction
-        return CompiledFunction(
-            step, label=f"local.step/{type(self.model).__name__}",
-            telemetry=self.telemetry, sig_argnums=(3, 4),
-            donate_argnums=(0, 1, 2))
+        return CompiledFunction(jitted=jitted, label=label,
+                                telemetry=self.telemetry,
+                                sig_argnums=sig_argnums)
 
-    def _optimize_impl(self) -> Module:
-        self._maybe_optimize_graph()
+    # -- the run: set-up, the iteration, the iteration's tail, the end --
+    class _Run:
+        """One optimize() call's variables: what the step carries from
+        iteration to iteration, and what the iteration's tail and the
+        run's tail read and write."""
+
+        def __init__(self, opt, params, opt_state, model_state, data_iter):
+            self.params, self.opt_state = params, opt_state
+            self.model_state = model_state
+            self.data_iter = data_iter
+            self.state = opt.optim_method.state  # epoch/neval bookkeeping
+            # a per-host shard feeds the loop; scale records by host count
+            # so epoch triggers fire on global progress
+            self.num_hosts = getattr(opt.dataset, "num_hosts", 1)
+            self.epoch_size = getattr(opt.dataset, "global_size", None) \
+                or opt.dataset.size() * self.num_hosts
+            self.sync_every = max(1, int(opt.sync_interval))
+            self.win = opt._SyncWindow()
+            self.loss_val = float("nan")  # last synced loss
+            self.loss = None  # device array of the most recent step's loss
+            self.lr = None
+            self.aux_pending: List = []  # per-dispatch aux scalars (tiny)
+            self.preempted = False
+
+    def _begin_run(self, loop: str, place=None) -> "_Run":
+        """Set-up every loop shares. `place(params, model_state)` puts
+        them where the loop's step wants them; the optimizer slots are
+        created from the placed params, so `zeros_like` inherits each
+        param's placement."""
         params = self.model.ensure_params()
         model_state = self.model._state
         # host snapshot BEFORE the first donated step kills these buffers:
-        # a failed run restores it so the model instance stays usable
+        # a failed run (or retry attempt) restores it so the model
+        # instance stays usable
         self._pristine_params = jax.device_get(params)
         self._pristine_state = jax.device_get(model_state)
+        if place is not None:
+            params, model_state = place(params, model_state)
         resume_slots = getattr(self, "_resume_slots", None)
         if resume_slots is not None:
             # checkpointed optimizer moments (Adam m/v, SGD velocity)
@@ -1054,137 +1086,254 @@ class LocalOptimizer(BaseOptimizer):
             self._resume_slots = None
         else:
             opt_state = self.optim_method.init_state_with_masters(params)
-        step = self._step_fn = self._build_step()
-        state = self.optim_method.state  # epoch/neval bookkeeping
-        driver_state = state
-        epoch_size = self.dataset.size()
         _, src = self._open_data_pipeline()
-        data_iter = self._fast_forward_data(src, driver_state)
+        data_iter = self._fast_forward_data(src, self.optim_method.state)
         self._init_cursor_positions()
+        self._telemetry_run_start(loop)
+        return self._Run(self, params, opt_state, model_state, data_iter)
+
+    def _pull_batch(self, data_iter):
+        """Next host batch, or None when a finite stream ran out. With
+        set_prefetch armed, `next(data_iter)` is a queue pop off the
+        background pipeline instead of inline transformer work."""
+        with Timer(self.metrics, "data fetch time"), \
+                self._span("data fetch"):
+            batch = next(data_iter, None)
+            if batch is None:
+                logger.warning(
+                    "training data stream exhausted before the end "
+                    "trigger fired; stopping early (train=True datasets "
+                    "normally loop forever)")
+                return None
+            self._note_pull()
+        return batch
+
+    def _place_batch(self, batch):
+        """A host batch as the step's `(x, y)`: starts the async device
+        transfer, which overlaps the dispatched step."""
+        return _to_device(batch.get_input()), _to_device(batch.get_target())
+
+    def _train(self, run: "_Run", step, suffix: str = "") -> Module:
+        """The loop of the local and the SPMD optimizer: one dispatch of
+        the compiled step per iteration and, but for the loss sync, no
+        host sync and no further device dispatch."""
+        self._step_fn = step
+        state = run.state
 
         def fetch_and_place():
-            """Next host batch + async device transfer; overlaps the
-            dispatched step like DistriOptimizer's prefetch. With
-            set_prefetch armed, `next(data_iter)` is a queue pop off the
-            background pipeline instead of inline transformer work."""
-            with Timer(self.metrics, "data fetch time"), \
-                    self._span("data fetch"):
-                batch = next(data_iter, None)
-                if batch is None:
-                    logger.warning(
-                        "training data stream exhausted before the end "
-                        "trigger fired; stopping early")
-                    return None
-                self._note_pull()
-                x = _to_device(batch.get_input())
-                y = _to_device(batch.get_target())
-            return batch, x, y
+            # called right after the step is dispatched, so the numpy work
+            # and the H2D DMA overlap the running step (the reference's
+            # data-fetch Spark task overlapping the parameter-sync jobs,
+            # DistriOptimizer.scala:330-339). The phase timers therefore
+            # OVERLAP "computing time average" (dispatch -> loss sync);
+            # the phase table is intentionally not additive
+            batch = self._pull_batch(run.data_iter)
+            return None if batch is None else (batch,
+                                               *self._place_batch(batch))
 
-        sync_every = max(1, int(getattr(self, "sync_interval", 1)))
-        self._telemetry_run_start("local")
-        win = self._SyncWindow()
-        loss_val = float("nan")
-        loss = None
-        lr = None
-        preempted = False
-        aux_pending: List = []
+        # device-resident rng chain, advanced inside the donated step; a
+        # COPY so self.rng survives donation: a failed run leaves it where
+        # it was, and a retry seeds a fresh chain from it
+        rng = jnp.asarray(self.rng) + 0
         pending = fetch_and_place()
-        while pending is not None and not self.end_trigger(driver_state):
+        while pending is not None and not self.end_trigger(state):
             batch, x, y = pending
+            step_no = state["neval"] + 1
             with self._span("step prepare"):
-                # chaos hook (resilience/faults.py): no-op unless a
-                # FaultInjector is installed
-                faults.fire("train.step", step=driver_state["neval"] + 1)
-                lr = self.optim_method.current_lr()
-                self.rng, step_rng = jax.random.split(self.rng)
-            with self._span("step dispatch", step=driver_state["neval"] + 1):
-                params, opt_state, new_ms, loss, aux = step(
-                    params, opt_state, model_state, x, y, lr, step_rng)
+                # chaos hook (resilience/faults.py): a no-op unless a
+                # FaultInjector is installed — lets tests crash the loop
+                # at an exact iteration
+                faults.fire("train.step", step=step_no)
+                run.lr = self.optim_method.current_lr()
+            with self._span("step dispatch", step=step_no):
+                (run.params, run.opt_state, run.model_state, run.loss, rng,
+                 aux) = step(run.params, run.opt_state, run.model_state,
+                             x, y, run.lr, rng)
             if aux:
-                aux_pending.append(aux)
-            pending = fetch_and_place()  # overlaps the running step
-            do_sync = (driver_state["neval"] + 1) % sync_every == 0
+                run.aux_pending.append(aux)
+            # prefetch while the dispatched step runs on-device (deliberate
+            # one-batch lookahead: the final prefetch of an optimize() call
+            # is discarded — one batch of host work per run buys the
+            # fetch/H2D overlap on every iteration)
+            pending = fetch_and_place()
+            do_sync = step_no % run.sync_every == 0
             if do_sync:
+                # waits for the step; donation chains steps, so this means
+                # every dispatched step up to here has completed
                 with self._span("loss sync"):
-                    loss_val = float(loss)  # waits for the step to finish
-            # the host's tail of the step, one span whether it synced or
-            # not: counters, the sync's records and log line, summaries,
-            # epoch roll-over, validation, checkpoint, hook
-            with self._span("step bookkeeping"):
-                model_state = new_ms  # step returns the FULL merged state
+                    run.loss_val = float(run.loss)
+            self._finish_iteration(run, batch, do_sync, suffix)
+            if run.preempted:
+                break
+        return self._finish_run(run, rng)
 
-                n = batch.size()
-                driver_state["neval"] += 1
-                driver_state["recordsProcessedThisEpoch"] += n
-                driver_state["loss"] = loss_val
-                win.add(n)
-                if do_sync:
-                    # per-window figures: dispatch+device only (the window
-                    # restarts AFTER the validation/checkpoint/hook tail)
-                    throughput = win.throughput(self.metrics)
-                    self._observe_sync(driver_state, loss_val, lr, throughput,
-                                       win.step_time_s, n, aux_pending)
-                    logger.info(
-                        f"[Epoch {driver_state['epoch'] + 1} "
-                        f"{driver_state['recordsProcessedThisEpoch']}/"
-                        f"{epoch_size}]"
-                        f"[Iteration {driver_state['neval']}] Training cost "
-                        f"{loss_val}. Throughput is {throughput} "
-                        f"records/second. ")
-                if do_sync and self.train_summary is not None:
-                    it = driver_state["neval"]
-                    self.train_summary.add_scalar("Loss", loss_val, it)
+    def _finish_iteration(self, run: "_Run", batch, do_sync: bool,
+                          suffix: str = "") -> bool:
+        """The host's tail of an iteration, one span whether it synced or
+        not: counters, the sync's records and log line, summaries, epoch
+        roll-over, validation, checkpoint, hook, preemption poll (sets
+        `run.preempted`). Returns whether an epoch boundary was crossed."""
+        state = run.state
+        with self._span("step bookkeeping"):
+            n = batch.size() * run.num_hosts  # global records this step
+            state["neval"] += 1
+            state["recordsProcessedThisEpoch"] += n
+            state["loss"] = run.loss_val
+            run.win.add(n)
+            if do_sync:
+                # throughput + per-iteration compute time over the sync
+                # window: exact wall time between device-drained points,
+                # valid for any sync_interval. The window counts ONLY
+                # dispatch+device time — it restarts after the
+                # validation/checkpoint/hook tail below — and recording
+                # the metric only at sync keeps "computing time average"
+                # a true per-step figure (per-dispatch timing is
+                # meaningless under async)
+                throughput = run.win.throughput(self.metrics)
+                self._observe_sync(state, run.loss_val, run.lr, throughput,
+                                   run.win.step_time_s, n, run.aux_pending)
+                logger.info(
+                    f"[Epoch {state['epoch'] + 1} "
+                    f"{state['recordsProcessedThisEpoch']}/"
+                    f"{run.epoch_size}]"
+                    f"[Iteration {state['neval']}] Training cost "
+                    f"{run.loss_val}. Throughput is {throughput} "
+                    f"records/second. {suffix}")
+                if self.train_summary is not None:
+                    it = state["neval"]
+                    self.train_summary.add_scalar("Loss", run.loss_val, it)
                     self.train_summary.add_scalar("LearningRate",
-                                                  self._lr_scalar(lr), it)
-                    self.train_summary.add_scalar("Throughput",
-                                                  throughput, it)
-                    # Parameters histograms only behind an explicit
-                    # trigger — they pull every weight to host
+                                                  self._lr_scalar(run.lr), it)
+                    self.train_summary.add_scalar("Throughput", throughput, it)
+                    # Parameters histograms only behind an explicit trigger —
+                    # they pull every (sharded) weight to host
                     # (AbstractOptimizer.scala:47-92)
                     trig = getattr(self.train_summary, "get_summary_trigger",
                                    lambda _n: None)("Parameters")
-                    if trig is not None and trig(driver_state):
-                        import jax as _jax
-                        flat = _jax.tree_util.tree_flatten_with_path(params)[0]
-                        for path, leaf in flat:
+                    if trig is not None and trig(state):
+                        host = jax.device_get(run.params)
+                        for path, leaf in \
+                                jax.tree_util.tree_flatten_with_path(host)[0]:
                             tag = "/".join(
                                 str(getattr(p, "key", getattr(p, "idx", p)))
                                 for p in path)
                             self.train_summary.add_histogram(tag, leaf, it)
 
-                if driver_state["recordsProcessedThisEpoch"] >= epoch_size:
-                    driver_state["epoch"] += 1
-                    driver_state["recordsProcessedThisEpoch"] = 0
-                    self._shuffle_dataset()
+            boundary = state["recordsProcessedThisEpoch"] >= run.epoch_size
+            if boundary:
+                state["epoch"] += 1
+                state["recordsProcessedThisEpoch"] = 0
+                self._shuffle_dataset()
 
-                with self._span("validation"):
-                    self._validate(params, model_state, driver_state)
-                if self.checkpoint_trigger \
-                        and self.checkpoint_trigger(driver_state):
-                    with self._span("checkpoint"):
-                        self._save_checkpoint(
-                            params, model_state,
-                            tag=f"iter{driver_state['neval']}",
-                            opt_slots=opt_state)
-                if self.iteration_hook is not None:
-                    self.iteration_hook(driver_state)
-                if self._check_preemption(params, model_state, opt_state,
-                                          driver_state, loss):
-                    preempted = True
-                    break
-                if do_sync:
-                    win.restart()  # exclude the tail work from the next window
+            with self._span("validation"):
+                self._validate(run.params, run.model_state, state)
+            if self.checkpoint_trigger and self.checkpoint_trigger(state):
+                with Timer(self.metrics, "checkpoint time"), \
+                        self._span("checkpoint"):
+                    self._save_checkpoint(
+                        run.params, run.model_state,
+                        tag=f"iter{state['neval']}",
+                        opt_slots=run.opt_state)
+            if self.iteration_hook is not None:
+                self.iteration_hook(state)
+            run.preempted = self._check_preemption(
+                run.params, run.model_state, run.opt_state, state, run.loss)
+            if do_sync and not run.preempted:
+                run.win.restart()  # exclude the tail from the next window
+        return boundary
 
-        if sync_every > 1 and loss is not None and \
-                driver_state["neval"] % sync_every != 0:
-            driver_state["loss"] = loss_val = float(loss)  # true final loss
-        if aux_pending:
+    def _finish_run(self, run: "_Run", rng) -> Module:
+        """The run's tail: the true final loss when the loop ended between
+        syncs, the partial aux window, `run_end`, the state handed back."""
+        state = run.state
+        if run.sync_every > 1 and run.loss is not None and \
+                state["neval"] % run.sync_every != 0:
+            state["loss"] = run.loss_val = float(run.loss)
+        if run.aux_pending:
             # partial tail window (end trigger fired between syncs): the
             # guards/monitors must still see those steps' aux
-            self._observe_sync(driver_state, loss_val, lr, float("nan"),
-                               float("nan"), 0, aux_pending)
-        if not preempted:  # a preempted run already closed with run_abort
-            self._telemetry_run_end(driver_state)
+            self._observe_sync(state, run.loss_val, run.lr, float("nan"),
+                               float("nan"), 0, run.aux_pending)
+        if not run.preempted:  # a preempted run already closed with run_abort
+            self._telemetry_run_end(state)
+        self._return_state(run.params, run.model_state, rng)
+        return self.model
+
+    def _restore_pristine(self):
+        """Put the pre-run host snapshot back on the model after a failed
+        donated run (the step aliased the model's old device buffers)."""
+        if self._pristine_params is not None:
+            self.model.set_params(self._pristine_params)
+            self.model._state = self._pristine_state
+
+    @contextlib.contextmanager
+    def _run_scope(self):
+        """Around one `optimize()`: no stale snapshot, the preemption
+        handler armed and its previous disposition restored after."""
+        # a snapshot left over from a PREVIOUS run is stale: a failure
+        # early in this run (before _begin_run re-snapshots) must not
+        # revert the model to pre-last-run weights
+        self._pristine_params = self._pristine_state = None
+        if self._preemption is not None:
+            # a latch left set by a previous preempted run is stale: the
+            # next optimize() (train-more / drill reuse) must train, not
+            # instantly re-abort
+            self._preemption.reset()
+            self._preemption.install()
+        try:
+            yield
+        finally:
+            if self._preemption is not None:
+                self._preemption.uninstall()
+
+    def _return_state(self, params, model_state, rng):
+        """Hand the trained state back: the advanced rng chain, so a
+        subsequent optimize() call (resume / train-more) continues the
+        dropout/noise stream instead of replaying it, and the model's
+        parameters and state (as they are; DistriOptimizer gathers)."""
+        self.rng = jax.device_get(rng)
         self.model.set_params(params)
         self.model._state = model_state
-        return self.model
+
+
+class LocalOptimizer(BaseOptimizer):
+    """Train on the local device (one TPU chip / CPU)."""
+
+    def __init__(self, model: Module, dataset, criterion: Criterion,
+                 batch_size: int = 32):
+        super().__init__(model, dataset, criterion)
+        self.batch_size = batch_size
+
+    def optimize(self) -> Module:
+        with self._run_scope():
+            try:
+                return self._optimize_impl()
+            except (KeyboardInterrupt, SystemExit):
+                self._restore_pristine()
+                raise
+            except Exception as e:
+                self._telemetry_run_abort(e)
+                # the donated step killed the model's device arrays; put
+                # the pre-run host snapshot back so the instance stays
+                # usable (pre-donation behavior: params unchanged on
+                # failure)
+                self._restore_pristine()
+                raise
+            finally:
+                # join prefetch workers whether the run finished or died —
+                # repeated optimize() calls must never accumulate threads
+                self._close_data_pipeline(self._active_pipeline)
+
+    def _build_step(self):
+        # donation: params, optimizer slots, model state and the rng chain
+        # alias their output buffers (PERF.md measured a ~20x dispatch
+        # penalty for non-donated same-shape probes). The guards'
+        # skip-mode revert stays donation-safe: jnp.where selects between
+        # traced values.
+        return self._compiled(
+            self._step_body(), f"local.step/{type(self.model).__name__}",
+            donate_argnums=(0, 1, 2, 6), sig_argnums=(3, 4))
+
+    def _optimize_impl(self) -> Module:
+        self._maybe_optimize_graph()
+        return self._train(self._begin_run("local"), self._build_step())

@@ -6,8 +6,10 @@ the iteration, the whole job retries from the newest snapshot
 the WINDOW: when a replica disappears mid-step the run rolls back to the
 last committed sync boundary, rebuilds over the survivors, replays the
 interrupted batches, and keeps going — degraded, not dead. This module
-is the policy half of that story; the mechanism (commit/rollback/replay)
-lives in `DistriOptimizer._optimize_elastic_impl`.
+is the policy half of that story; the mechanism (per-shard dispatch,
+commit/rollback/replay) lives in `DistriOptimizer._optimize_elastic_impl`,
+around the iteration's tail and the run's tail every loop shares
+(`BaseOptimizer._finish_iteration` / `_finish_run`).
 
 Two decisions:
 

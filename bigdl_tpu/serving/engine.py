@@ -21,9 +21,10 @@ caller (up to its deadline), `admission="reject"` fails fast with
 Bucket floor: the default buckets start at 2, not 1, because XLA lowers a
 batch-1 matmul through a gemv path whose row results differ BITWISE from
 the gemm path every other batch size takes — padding singles up to 2 keeps
-serving outputs bit-identical to offline `LocalPredictor.predict` batches
-(asserted in tests/test_serving.py). Pass `buckets=[1, ...]` explicitly to
-trade that identity for the smaller padded forward.
+serving outputs equal to offline `LocalPredictor.predict` batches to float32
+rounding, and bit for bit at the same padded shape (asserted in
+tests/test_serving.py). Pass `buckets=[1, ...]` explicitly to trade that
+for the smaller padded forward.
 
 Robustness contracts (all under test):
 - a failed batch (bad feature shape, trace error) rejects only its OWN

@@ -171,10 +171,11 @@ def rename(src: str, dst: str) -> None:
     a second attempt over a half-moved tree hits FileNotFoundError on
     the already-deleted entries, and a mid-copy failure leaves a visible
     partial destination. So remote moves are decomposed into per-file
-    copies — each idempotent and individually retried, with
-    manifest.json ordered LAST so a torn checkpoint publish has no
-    manifest and stays invisible to resume scans — followed by a source
-    delete that treats FileNotFoundError as already-done."""
+    copies (the target's parent created first) — each idempotent and
+    individually retried, with manifest.json ordered LAST so a torn
+    checkpoint publish has no manifest and stays invisible to resume
+    scans — followed by a source delete that treats FileNotFoundError as
+    already-done."""
     scheme, local_src = _split(src)
     _, local_dst = _split(dst)
     if scheme is None:
@@ -185,10 +186,18 @@ def rename(src: str, dst: str) -> None:
     sp_dst = fs._strip_protocol(str(dst)).rstrip("/")
     if _remote("isdir", src, lambda: fs.isdir(sp_src)):
         names = _remote("find", src, lambda: fs.find(sp_src))
+        made = set()
         for f in sorted(names, key=lambda p: (
                 posixpath.basename(p) == "manifest.json", p)):
             rel = f[len(sp_src):].lstrip("/")
             target = posixpath.join(sp_dst, rel) if rel else sp_dst
+            parent = posixpath.dirname(target)
+            if parent not in made:
+                # an object store has no directories; an HDFS-like store
+                # refuses a copy into one that does not exist yet
+                _remote("makedirs", parent, lambda d=parent: fs.makedirs(
+                    d, exist_ok=True))
+                made.add(parent)
             _remote("copy", f, lambda f=f, t=target: fs.copy(f, t))
     else:
         _remote("copy", src, lambda: fs.copy(sp_src, sp_dst))

@@ -93,8 +93,11 @@ def main(argv=None):
         return results
 
     served = serve(model, "fp32 engine", convert=True)
-    for i, row in enumerate(served):  # bit-identical to offline predict
-        np.testing.assert_array_equal(row, offline[i])
+    # equal to offline predict to float32 rounding: a micro-batch's size
+    # follows the clients' timing, and this backend's gemm is bit-equal
+    # only at the same padded shape
+    for i, row in enumerate(served):
+        np.testing.assert_allclose(row, offline[i], rtol=0, atol=1e-6)
 
     q = Quantizer.quantize(model, weight_only=True)
     q_served = serve(q, "int8 (weight-only) engine", convert=False)

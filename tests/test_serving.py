@@ -102,9 +102,11 @@ class TestBuckets:
 
 
 class TestBucketPaddingParity:
-    """Satellite: padded-batch outputs are bit-identical to the unpadded
-    forward for every bucket size — the floor-2 bucket default exists
-    exactly because XLA's batch-1 gemv path is NOT bit-identical."""
+    """Satellite: padded-batch outputs match the unpadded forward for
+    every bucket size, bit for bit where this backend keeps them so and to
+    float32 rounding in `test_multi_feature_model` — the floor-2 bucket
+    default exists exactly because XLA's batch-1 gemv path is NOT
+    bit-identical."""
 
     def test_every_batch_size_matches_offline_predict(self):
         model = _conv_model()
@@ -141,8 +143,12 @@ class TestBucketPaddingParity:
                    for _ in range(5)]
         ref = LocalPredictor(model, batch_size=5).predict(samples)
         out, _ = _serve_one_batch(model, samples, max_batch_size=8)
+        # the forward padded to bucket 8 against one at batch 5: this
+        # backend's gemm promises no bit-equality across batch sizes (it
+        # reads one float32 rounding apart, 3e-8); 1e-6 is what a bf16
+        # product would miss a thousandfold (ROADMAP D9)
         for i in range(5):
-            np.testing.assert_array_equal(out[i], ref[i])
+            np.testing.assert_allclose(out[i], ref[i], rtol=0, atol=1e-6)
 
 
 class TestCompileCount:
@@ -491,9 +497,13 @@ class TestPredictionService:
             # the old cold-start path ran _forward twice for the first
             # request (compile + recompute); the engine runs it once
             assert len(calls) == 1
-            np.testing.assert_array_equal(out, ref[0])
+            # one request padded to the floor-2 bucket against the
+            # offline forward at batch 5: float32 rounding apart (1e-7
+            # read), not bit-equal on this backend's gemm (ROADMAP D9)
+            np.testing.assert_allclose(out, ref[0], rtol=0, atol=1e-6)
             for i, s in enumerate(samples):
-                np.testing.assert_array_equal(svc.predict(s), ref[i])
+                np.testing.assert_allclose(svc.predict(s), ref[i],
+                                           rtol=0, atol=1e-6)
 
     def test_facade_defaults_to_zero_gather_window(self):
         # a serial legacy caller blocked on its own future cannot feed
