@@ -12,7 +12,11 @@ Tokens are sorted by expert and each projection is ONE grouped product
 over the sorted rows (`jax.lax.ragged_dot`); at a decode step's shape
 (no more rows than experts, nearly every expert chosen by some row) each
 projection is one batched product of every row with every expert, which
-reads the weights once at the memory's pace (`_few_rows`).
+reads the weights once at the memory's pace (`_few_rows`: on the v5e XLA
+makes two fusions a layer of it, the gate product and the up product
+fused into the down projection as the producer of its left operand, and
+both stream their weights at 92% of the memory's bandwidth: PERF.md
+PR 35).
 """
 
 from __future__ import annotations
@@ -92,16 +96,36 @@ class RoutedExperts(Module):
         meets every expert in one batched product a projection, the
         weights streamed once whatever the routing, and the top-k's
         weights (zero for an expert not chosen) fold into the down
-        projection's left operand, so nothing unchosen reaches the sum."""
-        n = x.shape[0]
-        gate = jnp.einsum("nd,edh->neh", x, params["wg"])
-        up = jnp.einsum("nd,edh->neh", x, params["wu"])
-        mix = jnp.zeros((n, self.n_experts), jnp.float32).at[
-            jnp.arange(n)[:, None], experts].set(weights)
-        hidden = (jax.nn.relu(gate) * up).astype(jnp.float32) \
-            * mix[..., None]
-        return jnp.einsum("neh,ehd->nd", hidden.astype(x.dtype),
-                          params["wd"], preferred_element_type=jnp.float32)
+        projection's left operand, so nothing unchosen reaches the sum.
+
+        The down projection is one product over both the expert and the
+        width axis, `[N, E h] x [E h, d]` with a float32 accumulator.
+        On the v5e (32 rows, 64 experts of 768 over 2560, bf16; PERF.md
+        PR 35) XLA fuses the up product into it as the producer of its
+        left operand: one fusion that reads `wu` AND `wd`, 503 MB in
+        0.667 ms, beside the gate product's 252 MB in 0.335 ms, each at
+        755 GB/s, 92% of the memory's pace, 1.003 ms a layer. Written
+        as a 2-D product of reshaped operands it compiles to the same
+        fusion (1.003); with `hidden` behind an optimization barrier it
+        splits into 0.335 + 0.337 (1.007); with the top-k's weights
+        applied after a batched product by expert it stays one fusion
+        (1.003) and moves a rounding point. So this form stays.
+        The top-k's weights `[N, E]` are built by comparison, not by a
+        scatter: 192 serial updates took 12 us a layer, the comparison
+        is lost in a neighbouring fusion; the values are the same bit
+        for bit, a row's k experts being distinct."""
+        with jax.named_scope("moe gate up"):
+            gate = jnp.einsum("nd,edh->neh", x, params["wg"])
+            up = jnp.einsum("nd,edh->neh", x, params["wu"])
+        with jax.named_scope("moe down"):
+            chosen = experts[..., None] == jnp.arange(
+                self.n_experts, dtype=experts.dtype)       # [N, k, E]
+            mix = jnp.sum(jnp.where(chosen, weights[..., None], 0.0), axis=1)
+            hidden = (jax.nn.relu(gate) * up).astype(jnp.float32) \
+                * mix[..., None]
+            return jnp.einsum("neh,ehd->nd", hidden.astype(x.dtype),
+                              params["wd"],
+                              preferred_element_type=jnp.float32)
 
     def apply(self, params, input, ctx):
         """`input` = (x [N, d], router logits [N, E]) -> y [N, d]."""

@@ -23,7 +23,7 @@ def _fans(shape: Sequence[int]) -> Tuple[int, int]:
     if len(shape) == 2:  # (in, out) linear convention used throughout this lib
         return shape[0], shape[1]
     # conv kernels stored HWIO (TPU-native layout): receptive = H*W
-    receptive = int(jnp.prod(jnp.array(shape[:-2])))
+    receptive = math.prod(shape[:-2])
     fan_in = shape[-2] * receptive
     fan_out = shape[-1] * receptive
     return fan_in, fan_out

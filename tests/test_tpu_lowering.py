@@ -250,6 +250,24 @@ class TestMosaicCompiles:
 # the serving engine's decode program at the serving cell's shapes
 # ---------------------------------------------------------------------- #
 
+def _compile_decode_program(model, params, cache, slots, on):
+    """`GenerationEngine`'s own `jit__decode_fn` for `model`, compiled by
+    libtpu for the described device `on` from shapes alone, at the
+    precision the serving cells run under."""
+    from bigdl_tpu.serving import GenerationEngine
+    # the engine only keeps the parameters; its own cache stays tiny
+    model.set_params(params)
+    eng = GenerationEngine(model, slots=1, max_len=2, start=False)
+    try:
+        ids = struct((slots,), jnp.int32, on)
+        with jax.default_matmul_precision("bfloat16"):
+            return eng._decode._jit.trace(
+                params, cache, ids, ids, struct((slots,), jnp.bool_, on),
+                ids).lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        eng.close(drain=False)
+
+
 class TestDecodeProgramCompiles:
     """`GenerationEngine`'s own `jit__decode_fn` over `TransformerLM` at
     the shapes of `neox-3.6b.serve-chat` (32 slots x 2048, 22 heads of
@@ -260,7 +278,6 @@ class TestDecodeProgramCompiles:
         import re
         from bigdl_tpu.models.transformer import TransformerLM
         from bigdl_tpu.nn import kv_cache
-        from bigdl_tpu.serving import GenerationEngine
         on = _v5e_device()
         slots, max_len, n_layer = 32, 2048, 4
         model = TransformerLM(32000, embed_dim=2816, n_layer=n_layer,
@@ -273,17 +290,7 @@ class TestDecodeProgramCompiles:
                          jnp.bfloat16)
         cache = on_chip(jax.eval_shape(
             lambda: model.init_cache(slots, max_len, jnp.bfloat16)))
-        # the engine only keeps the parameters; its own cache stays tiny
-        model.set_params(params)
-        eng = GenerationEngine(model, slots=1, max_len=2, start=False)
-        try:
-            ids = struct((slots,), jnp.int32, on)
-            with jax.default_matmul_precision("bfloat16"):
-                compiled = eng._decode._jit.trace(
-                    params, cache, ids, ids, struct((slots,), jnp.bool_, on),
-                    ids).lower(lowering_platforms=("tpu",)).compile()
-        finally:
-            eng.close(drain=False)
+        compiled = _compile_decode_program(model, params, cache, slots, on)
         hlo = compiled.as_text()
         assert "jit__decode_fn" in hlo
         conditionals = re.findall(
@@ -321,3 +328,64 @@ class TestDecodeProgramCompiles:
         m = compiled.memory_analysis()
         assert m.alias_size_in_bytes >= 2 * n_layer * 369_000_000
         assert m.temp_size_in_bytes < 100_000_000
+
+
+class TestSparseDecodeProgramCompiles:
+    """`GenerationEngine`'s `jit__decode_fn` over `SparseDecoderLM` at
+    the shapes of `smallthinker-21b.serve-mixed` (32 slots x 16384, 28
+    query over 4 K/V heads of 128, 64 experts of 768 with 6 active, 8
+    layers [full, window x3] x 2, bf16 weights and cache, float32 norms
+    and router), compiled by libtpu for a v5e that is not attached: the
+    decode step takes `RoutedExperts._few_rows`, whose three products a
+    layer must read `wg`, `wu` and `wd` where they lie. Nothing runs, so
+    nothing here is a timing."""
+
+    def test_experts_are_read_in_place_and_named(self):
+        import re
+        from bigdl_tpu.models.decoder import LayerSpec, SparseDecoderLM
+        on = _v5e_device()
+        slots, max_len, n_layer = 32, 16384, 8
+        layers = [LayerSpec(window=4096 if i % 4 else None,
+                            rope_base=1.5e6 if i % 4 else None)
+                  for i in range(n_layer)]
+        model = SparseDecoderLM(151936, embed_dim=2560, n_head=28,
+                                n_kv_head=4, head_dim=128, layers=layers,
+                                n_experts=64, expert_dim=768, top_k=6,
+                                max_len=max_len, cache_dtype=jnp.bfloat16)
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, a: struct(
+                a.shape, jnp.float32 if {"router", "ln1", "ln2", "norm"}
+                & {getattr(k, "key", None) for k in path} else jnp.bfloat16,
+                on),
+            jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+        cache = jax.tree_util.tree_map(
+            lambda a: struct(a.shape, a.dtype, on),
+            jax.eval_shape(lambda: model.init_cache(slots, max_len)))
+        compiled = _compile_decode_program(model, params, cache, slots, on)
+        hlo = compiled.as_text()
+        entry = hlo[hlo.index("ENTRY"):]
+        assert "jit__decode_fn" in hlo and "ragged-dot" not in hlo
+        # no second copy and no relayout of a layer's experts: 252 MB is
+        # 0.31 ms of the memory's time, and the chip has no room for it
+        # (the cell peaks at 14.34 of 16 GB)
+        expert_shapes = ("bf16[64,768,2560]", "bf16[64,2560,768]")
+        moved = [line for line in entry.splitlines()
+                 if re.search(r" (copy|copy-start|transpose)\(", line)
+                 and line.split(" = ", 1)[-1].lstrip().startswith(
+                     expert_shapes)]
+        assert not moved, moved
+        m = compiled.memory_analysis()
+        assert m.temp_size_in_bytes < 100_000_000
+        assert m.alias_size_in_bytes >= 3_700_000_000    # the donated cache
+        # each layer's three expert weights are operands of the step's
+        # own fusions, once each, and a device trace can tell the down
+        # projection from the gate and up products by their scopes
+        for leaf in ("wg", "wu", "wd"):
+            for i in range(n_layer):
+                readers = re.findall(
+                    rf"^\s*%[\w.\-]+ = .*\(.*%params__block{i}____experts"
+                    rf"____{leaf}__[\w.]*[,)]", entry, re.M)
+                assert len(readers) == 1, (leaf, i, readers)
+        assert entry.count("moe experts/moe down/") >= n_layer
+        assert entry.count("moe experts/moe gate up/") >= n_layer
+        assert "tpu_custom_call" not in hlo
