@@ -1,20 +1,29 @@
 """A decoder-only language model assembled from a layer pattern.
 
-Each layer is a `LayerSpec`: what its attention keeps (a sliding
-`window` or everything) and how it encodes position (a rotary base or
-nothing at all). Every block is pre-norm with RMSNorm, grouped-query
-attention (`nn.GroupedQueryAttention`) and dropless routed experts
-(`nn.experts.RoutedExperts`) whose router reads the ATTENTION block's
-normed input, before attention runs:
+Each layer is a `LayerSpec`: its token mixer (grouped-query attention,
+`nn.GroupedQueryAttention`, over a sliding `window` or everything, with
+a rotary base or no positional encoding at all; or the gated delta
+rule's linear attention, `nn.GatedDeltaRule`, which keeps a fixed-size
+state instead of keys and values), its feed-forward (dropless routed
+experts, `nn.experts.RoutedExperts`, or one dense gated one,
+`nn.experts.GatedFFN`) and where its RMSNorms sit: on each sub-layer's
+input,
 
     h = norm1(x);  r = h @ router;  x = x + attn(h)
-    u = norm2(x);  x = x + experts(u, routed by r)
+    u = norm2(x);  x = x + ffn(u)        experts routed by r
+
+(a router reads the ATTENTION block's normed input, before attention
+runs), or on each sub-layer's output,
+
+    x = x + norm1(attn(x));  x = x + norm2(ffn(x)).
 
 The serving side is what `GenerationEngine` calls (`init_cache`,
-`apply_prefill`, `apply_step`): the cache gives each layer the depth its
-kind needs (nn/kv_cache.py), prefill takes the head over each prompt's
-last real position only, and the cache pytree carries device-side
-counters of the routing and of the window (`cache_stats`).
+`apply_prefill`, `apply_step`, `cache_stats`): the cache gives each
+layer what its kind keeps a slot (nn/kv_cache.py: K and V as deep as
+the kind needs, or a recurrent state and its convolution's tail),
+prefill takes the head over each prompt's last real position only, and
+the cache pytree carries device-side counters of the routing, of the
+window and of the recurrent state.
 """
 
 from __future__ import annotations
@@ -28,38 +37,71 @@ import numpy as np
 
 from bigdl_tpu.nn import kv_cache
 from bigdl_tpu.nn.attention import GroupedQueryAttention
-from bigdl_tpu.nn.experts import RoutedExperts
+from bigdl_tpu.nn.experts import GatedFFN, RoutedExperts
 from bigdl_tpu.nn.initialization import Xavier
+from bigdl_tpu.nn.linear_attention import GatedDeltaRule
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.nn.normalization import RMSNorm
+
+#: what a layer may keep a serving slot, by the cache's names: an
+#: attention layer "k" and "v", a recurrent one "state" and "tail"
+_KEPT = ("k", "v", "state", "tail")
 
 
 @dataclass(frozen=True)
 class LayerSpec:
-    """One layer of the pattern: `window` positions kept (None: all),
-    `rope_base` of its rotary encoding (None: no positional encoding)."""
+    """One layer of the pattern. `mixer`: "attention" (over the last
+    `window` positions, None: all; `rope_base` of its rotary encoding,
+    None: no positional encoding) or "gated_delta" (linear attention;
+    neither applies). `ffn`: "experts" (routed) or "dense". `norm`: the
+    RMSNorms on each sub-layer's "input" or "output"."""
     window: Optional[int] = None
     rope_base: Optional[float] = None
+    mixer: str = "attention"
+    ffn: str = "experts"
+    norm: str = "input"
+
+    def __post_init__(self):
+        for field, known in (("mixer", ("attention", "gated_delta")),
+                             ("ffn", ("experts", "dense")),
+                             ("norm", ("input", "output"))):
+            if getattr(self, field) not in known:
+                raise ValueError(f"LayerSpec.{field} is one of {known}, "
+                                 f"got {getattr(self, field)!r}")
+        if self.mixer == "gated_delta" and (self.window is not None
+                                            or self.rope_base is not None):
+            raise ValueError("LayerSpec.window and rope_base belong to "
+                             "mixer='attention'; a gated_delta layer keeps "
+                             "a state, not positions")
 
 
-class SparseDecoderBlock(Module):
-    def __init__(self, embed_dim: int, n_head: int, n_kv_head: int,
-                 head_dim: int, spec: LayerSpec, n_experts: int,
-                 expert_dim: int, top_k: int, eps: float = 1e-6, name=None):
+class DecoderBlock(Module):
+    """`attn` is the layer's token mixer and `keeps` the names of the two
+    things it keeps a serving slot ("k", "v" or "state", "tail"); the
+    feed-forward is `experts` (with the block's `router`) or `ffn`."""
+
+    def __init__(self, embed_dim: int, spec: LayerSpec, attn: Module,
+                 ffn: Module, eps: float = 1e-6, name=None):
         super().__init__(name)
-        self.e, self.n_experts = embed_dim, n_experts
-        self.attn = GroupedQueryAttention(
-            embed_dim, n_head, n_kv_head, head_dim, window=spec.window,
-            rope_base=spec.rope_base)
-        self.experts = RoutedExperts(embed_dim, expert_dim, n_experts, top_k)
+        self.e, self.window = embed_dim, spec.window
+        self.attn = attn
+        self.keeps = ("k", "v") if spec.mixer == "attention" \
+            else ("state", "tail")
+        self.experts = ffn if spec.ffn == "experts" else None
+        self.ffn = ffn if spec.ffn == "dense" else None
+        self.norm_output = spec.norm == "output"
         self.ln1, self.ln2 = RMSNorm(embed_dim, eps), RMSNorm(embed_dim, eps)
 
     def init(self, rng):
         k1, k2, k3 = jax.random.split(rng, 3)
-        return {"ln1": self.ln1.init(None), "ln2": self.ln2.init(None),
-                "attn": self.attn.init(k1),
-                "router": Xavier()(k2, (self.e, self.n_experts)),
-                "experts": self.experts.init(k3)}
+        p = {"ln1": self.ln1.init(None), "ln2": self.ln2.init(None),
+             "attn": self.attn.init(k1)}
+        if self.experts is not None:
+            p["router"] = Xavier()(k2, (self.e, self.experts.n_experts))
+            p["experts"] = self.experts.init(k3)
+        else:
+            p["ffn"] = self.ffn.init(k3)
+        return p
 
     def _route(self, params, h):
         """Router logits in float32 from the attention block's normed
@@ -70,61 +112,109 @@ class SparseDecoderBlock(Module):
                            params["router"].astype(jnp.float32),
                            precision=jax.lax.Precision.HIGHEST)
 
-    def _experts(self, params, x, logits):
-        shape = x.shape
-        u = self.ln2.apply(params["ln2"], x, None).reshape(-1, self.e)
-        y, chosen = self.experts.apply_routed(
-            params["experts"], u, logits.reshape(-1, self.n_experts))
-        return x + y.reshape(shape), chosen.reshape(*shape[:-1], -1)
+    def _mix(self, params, x, mixer):
+        """x + the mixer's sub-layer, what the mixer keeps, and the
+        router's logits (None with no router); `mixer(params, h)` is the
+        layer's prefill or step."""
+        h = x if self.norm_output else self.ln1.apply(params["ln1"], x, None)
+        logits = None if self.experts is None else self._route(params, h)
+        a, kept_a, kept_b = mixer(params["attn"], h)
+        if self.norm_output:
+            a = self.ln1.apply(params["ln1"], a, None)
+        return x + a, kept_a, kept_b, logits
 
-    def apply_prefill(self, params, x):
-        """Whole-sequence inference apply: (out [B, T, E], this layer's
-        k, v [B, Hkv, T, hd], each token's experts [B, T, top_k])."""
-        h = self.ln1.apply(params["ln1"], x, None)
-        logits = self._route(params, h)
-        a, k, v = self.attn.apply_prefill(params["attn"], h)
-        x, chosen = self._experts(params, x + a, logits)
-        return x, k, v, chosen
+    def _feed(self, params, x, logits):
+        """x + the feed-forward's sub-layer, and each token's experts
+        [..., top_k] (None for a dense one)."""
+        shape = x.shape
+        u = x if self.norm_output else self.ln2.apply(params["ln2"], x, None)
+        u = u.reshape(-1, self.e)
+        if self.experts is not None:
+            y, chosen = self.experts.apply_routed(
+                params["experts"], u,
+                logits.reshape(-1, self.experts.n_experts))
+        else:
+            y, chosen = self.ffn.apply(params["ffn"], u, None), None
+        y = y.reshape(shape)
+        if self.norm_output:
+            y = self.ln2.apply(params["ln2"], y, None)
+        x = x + y
+        if chosen is not None:
+            chosen = chosen.reshape(*shape[:-1], -1)
+        return x, chosen
+
+    def apply_prefill(self, params, x, lengths=None):
+        """Whole-sequence inference apply over right-padded rows of real
+        `lengths` (None: whole rows): (out [B, T, E], the two things
+        this layer keeps a slot, each token's experts [B, T, top_k] or
+        None)."""
+        x, kept_a, kept_b, logits = self._mix(
+            params, x, lambda p, h: self.attn.apply_prefill(p, h, lengths))
+        x, chosen = self._feed(params, x, logits)
+        return x, kept_a, kept_b, chosen
 
     def apply(self, params, input, ctx):
         return self.apply_prefill(params, input)[0]
 
-    def apply_step(self, params, x, k_cache, v_cache, positions):
-        """One token a row against the layer's cache: (out [B, 1, E],
-        k_cache, v_cache, experts [B, 1, top_k])."""
-        h = self.ln1.apply(params["ln1"], x, None)
-        logits = self._route(params, h)
-        a, k_cache, v_cache = self.attn.apply_step(
-            params["attn"], h, k_cache, v_cache, positions)
-        x, chosen = self._experts(params, x + a, logits)
-        return x, k_cache, v_cache, chosen
+    def apply_step(self, params, x, kept_a, kept_b, positions):
+        """One token a row against what the layer keeps: (out [B, 1, E],
+        the two kept things updated, experts [B, 1, top_k] or None)."""
+        x, kept_a, kept_b, logits = self._mix(
+            params, x, lambda p, h: self.attn.apply_step(
+                p, h, kept_a, kept_b, positions))
+        x, chosen = self._feed(params, x, logits)
+        return x, kept_a, kept_b, chosen
 
 
-class SparseDecoderLM(Module):
+class DecoderLM(Module):
     """[B, T] int tokens (1-based) -> [B, T, vocab] log-probs; `layers`
-    is the pattern, one `LayerSpec` a layer. The residual stream, the
-    norms, the router and the log-probs are float32 whatever the
-    weights' type; the matmuls take their operands in the weights' type
-    and add their float32 accumulators to the stream. (A bfloat16 stream
-    rounds 2^-8 of every element away a layer, which is what turns a
-    token's sixth and seventh router logits over: on the chip the
-    served tokens then lay up to 0.35 under the float32 reference's best
-    logit where an fp8 computation lies 0.26: PERF.md, PR 29.)"""
+    is the pattern, one `LayerSpec` a layer. Attention layers have
+    `n_head` query heads of `head_dim` over `n_kv_head` K/V heads
+    (`qk_norm`: an RMSNorm over the whole q and k projections);
+    "experts" layers `n_experts` of `expert_dim` with `top_k` active,
+    "dense" ones `ffn_dim`; "gated_delta" layers `linear_heads` heads of
+    `linear_key_dim` and `linear_value_dim` behind a convolution of
+    `conv_taps`, prefilled in chunks of `chunk`. The residual stream,
+    the norms, the router, the recurrent state and the log-probs are
+    float32 whatever the weights' type; the matmuls take their operands
+    in the weights' type and add their float32 accumulators to the
+    stream. (A bfloat16 stream rounds 2^-8 of every element away a
+    layer, which is what turns a token's sixth and seventh router
+    logits over: on the chip the served tokens then lay up to 0.35 under
+    the float32 reference's best logit where an fp8 computation lies
+    0.26: PERF.md, PR 29.)"""
 
     def __init__(self, vocab_size: int, embed_dim: int, n_head: int,
                  n_kv_head: int, head_dim: int, layers: Sequence[LayerSpec],
-                 n_experts: int, expert_dim: int, top_k: int,
+                 n_experts: int = 0, expert_dim: int = 0, top_k: int = 0,
                  eps: float = 1e-6, max_len: Optional[int] = None,
-                 cache_dtype=jnp.float32, name=None):
+                 cache_dtype=jnp.float32, name=None, *, ffn_dim: int = 0,
+                 qk_norm: bool = False, linear_heads: int = 0,
+                 linear_key_dim: int = 0, linear_value_dim: int = 0,
+                 conv_taps: int = 4, chunk: int = 64):
         super().__init__(name)
         self.vocab, self.e, self.max_len = vocab_size, embed_dim, max_len
         self.cache_dtype = cache_dtype
-        self.n_experts = n_experts
-        self.blocks = [SparseDecoderBlock(embed_dim, n_head, n_kv_head,
-                                          head_dim, spec, n_experts,
-                                          expert_dim, top_k, eps)
-                       for spec in layers]
+        self.n_experts, self.chunk = n_experts, chunk
+
+        def block(spec):
+            attn = GroupedQueryAttention(
+                embed_dim, n_head, n_kv_head, head_dim, window=spec.window,
+                rope_base=spec.rope_base, qk_norm=eps if qk_norm else None) \
+                if spec.mixer == "attention" else GatedDeltaRule(
+                    embed_dim, linear_heads, linear_key_dim,
+                    linear_value_dim, conv_taps, chunk, eps)
+            ffn = RoutedExperts(embed_dim, expert_dim, n_experts, top_k) \
+                if spec.ffn == "experts" else GatedFFN(embed_dim, ffn_dim)
+            return DecoderBlock(embed_dim, spec, attn, ffn, eps)
+        self.blocks = [block(spec) for spec in layers]
         self.norm = RMSNorm(embed_dim, eps)
+        # what the cache's counters count, by the kinds of layer there are
+        self._routed = [i for i, b in enumerate(self.blocks)
+                        if b.experts is not None]
+        self._recurrent = [i for i, b in enumerate(self.blocks)
+                           if b.keeps == ("state", "tail")]
+        self._windowed = any(b.window is not None for b in self.blocks)
 
     def init(self, rng):
         keys = jax.random.split(rng, len(self.blocks) + 2)
@@ -158,19 +248,29 @@ class SparseDecoderLM(Module):
 
     # ------------------------------------------------------------- serving
     def init_cache(self, slots: int, max_len: int, dtype=None):
-        """Per layer K and V of `[slots, n_kv_head, depth, head_dim]`,
-        depth `window` on window layers and `max_len` on full ones, and
-        the counters a step and a prefill add to on the device."""
-        kv = [blk.attn.init_cache(slots, max_len, dtype or self.cache_dtype)
-              for blk in self.blocks]
-        n = len(self.blocks)
-        return {"k": [k for k, _ in kv], "v": [v for _, v in kv],
-                "counters": {
-                    "moe_expert_load": jnp.zeros((n, self.n_experts),
-                                                 jnp.int32),
-                    "moe_experts_touched": jnp.zeros((n,), jnp.int32),
-                    "decode_steps": jnp.zeros((), jnp.int32),
-                    "window_positions_skipped": jnp.zeros((), jnp.float32)}}
+        """Per layer what its kind keeps (`None` under the other kind's
+        names): "k" and "v" `[slots, n_kv_head, depth, head_dim]`, depth
+        `window` on window layers and `max_len` on full ones; "state"
+        `[slots, heads, key_dim, value_dim]` float32 and "tail"
+        `[slots, taps - 1, channels]` on recurrent ones; and the
+        counters a step and a prefill add to on the device."""
+        cache = {name: [None] * len(self.blocks) for name in _KEPT}
+        for i, blk in enumerate(self.blocks):
+            cache[blk.keeps[0]][i], cache[blk.keeps[1]][i] = \
+                blk.attn.init_cache(slots, max_len, dtype or self.cache_dtype)
+        c = {"decode_steps": jnp.zeros((), jnp.int32)}
+        if self._routed:
+            n = len(self._routed)
+            c["moe_expert_load"] = jnp.zeros((n, self.n_experts), jnp.int32)
+            c["moe_experts_touched"] = jnp.zeros((n,), jnp.int32)
+        if self._windowed:
+            c["window_positions_skipped"] = jnp.zeros((), jnp.float32)
+        if self._recurrent:
+            c["recurrent_slot_steps"] = jnp.zeros((), jnp.int32)
+            c["recurrent_chunks_scanned"] = jnp.zeros((), jnp.int32)
+            c["recurrent_state_absmax"] = jnp.zeros((), jnp.float32)
+        cache["counters"] = c
+        return cache
 
     def _load(self, chosen, counted):
         """[n_experts] pairs given to each expert by the tokens `counted`
@@ -184,34 +284,46 @@ class SparseDecoderLM(Module):
         `positions` [S] each slot's 0-based position (mixed ages). An
         idle slot rides along at position 0 (a live one is past its
         prompt): its experts are computed and read, so they count as
-        touched, but it adds nothing to the experts' load."""
+        touched, but it adds nothing to the experts' load; its recurrent
+        state is replaced like any other's (a later prefill sets it
+        whole) and counts neither as a slot-step nor towards the
+        largest |S|."""
         x = self._embed(params, tokens)[:, None, :]
         live = positions > 0
         everyone = jnp.ones_like(live)
-        ks, vs, loads, touched = [], [], [], []
+        new = {name: list(cache[name]) for name in _KEPT}
+        loads, touched = [], []
         skipped = jnp.zeros((), jnp.float32)
+        absmax = jnp.zeros((), jnp.float32)
         for i, blk in enumerate(self.blocks):
-            x, k, v, chosen = blk.apply_step(
-                params[f"block{i}"], x, cache["k"][i], cache["v"][i],
-                positions)
-            ks.append(k)
-            vs.append(v)
-            loads.append(self._load(chosen[:, 0], live))
-            touched.append(jnp.sum(self._load(chosen[:, 0], everyone) > 0))
-            if blk.attn.window is not None:
+            a, b = blk.keeps
+            x, new[a][i], new[b][i], chosen = blk.apply_step(
+                params[f"block{i}"], x, cache[a][i], cache[b][i], positions)
+            if chosen is not None:
+                loads.append(self._load(chosen[:, 0], live))
+                touched.append(jnp.sum(self._load(chosen[:, 0], everyone)
+                                       > 0))
+            if blk.window is not None:
                 skipped += kv_cache.positions_skipped(
                     jnp.where(live, positions, 0),
-                    blk.attn.window).astype(jnp.float32)
-        c = cache["counters"]
-        counters = {
-            "moe_expert_load": c["moe_expert_load"] + jnp.stack(loads),
-            "moe_experts_touched": c["moe_experts_touched"]
-            + jnp.stack(touched).astype(jnp.int32),
-            "decode_steps": c["decode_steps"] + 1,
-            "window_positions_skipped": c["window_positions_skipped"]
-            + skipped}
-        return self._logp(params, x[:, 0]), {"k": ks, "v": vs,
-                                             "counters": counters}
+                    blk.window).astype(jnp.float32)
+            if a == "state":
+                absmax = jnp.maximum(absmax, jnp.max(jnp.where(
+                    live, jnp.max(jnp.abs(new[a][i]), axis=(1, 2, 3)), 0.0)))
+        c = dict(cache["counters"])
+        if self._routed:
+            c["moe_expert_load"] = c["moe_expert_load"] + jnp.stack(loads)
+            c["moe_experts_touched"] = c["moe_experts_touched"] \
+                + jnp.stack(touched).astype(jnp.int32)
+        c["decode_steps"] = c["decode_steps"] + 1
+        if self._windowed:
+            c["window_positions_skipped"] = c["window_positions_skipped"] \
+                + skipped
+        if self._recurrent:
+            c["recurrent_slot_steps"] = c["recurrent_slot_steps"] \
+                + jnp.sum(live).astype(jnp.int32)
+            c["recurrent_state_absmax"] = absmax
+        return self._logp(params, x[:, 0]), {**new, "counters": c}
 
     def apply_prefill(self, params, tokens, cache, slot_ids, lengths):
         """Prefill right-padded prompts `tokens` [B, T] of real `lengths`
@@ -226,43 +338,82 @@ class SparseDecoderLM(Module):
                                  slot_ids[1:] != slot_ids[:-1]])
         valid = (jnp.arange(tokens.shape[1])[None, :] < lengths[:, None]) \
             & first[:, None]
-        ks, vs, loads = [], [], []
+        new = {name: list(cache[name]) for name in _KEPT}
+        loads = []
         for i, blk in enumerate(self.blocks):
-            x, k, v, chosen = blk.apply_prefill(params[f"block{i}"], x)
-            w = blk.attn.window
-            ks.append(kv_cache.commit(cache["k"][i],
-                                      k.astype(cache["k"][i].dtype),
-                                      slot_ids, lengths, w))
-            vs.append(kv_cache.commit(cache["v"][i],
-                                      v.astype(cache["v"][i].dtype),
-                                      slot_ids, lengths, w))
-            loads.append(self._load(chosen, valid))
+            x, kept_a, kept_b, chosen = blk.apply_prefill(
+                params[f"block{i}"], x, lengths)
+            for name, kept in zip(blk.keeps, (kept_a, kept_b)):
+                new[name][i] = kv_cache.commit(
+                    cache[name][i], kept.astype(cache[name][i].dtype),
+                    slot_ids, lengths, blk.window)
+            if blk.keeps[0] == "state":
+                # a recurrent layer's state and tail land in the cache
+                # before the next layer runs: left to itself the compiler
+                # commits every layer at the program's end and holds
+                # what each commit reads (the layer's whole input, for
+                # its tail) until then: 1.2 GB over 4 x 2048 tokens
+                x, new["state"][i], new["tail"][i] = \
+                    jax.lax.optimization_barrier(
+                        (x, new["state"][i], new["tail"][i]))
+            if chosen is not None:
+                loads.append(self._load(chosen, valid))
         c = dict(cache["counters"])
-        c["moe_expert_load"] = c["moe_expert_load"] + jnp.stack(loads)
+        if self._routed:
+            c["moe_expert_load"] = c["moe_expert_load"] + jnp.stack(loads)
+        if self._recurrent:
+            c["recurrent_chunks_scanned"] = c["recurrent_chunks_scanned"] \
+                + jnp.sum(jnp.where(first, -(-lengths // self.chunk), 0))
         last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
-        return self._logp(params, last[:, 0]), {"k": ks, "v": vs,
-                                                "counters": c}
+        return self._logp(params, last[:, 0]), {**new, "counters": c}
 
     def cache_stats(self, cache):
-        """The counters of `cache` as plain numbers (one fetch):
+        """The counters of `cache` as plain numbers (one fetch), those of
+        the kinds of layer the model has. Routed experts:
         `moe_pairs_routed` token-expert pairs of real tokens;
         `moe_expert_load_max_over_mean` the busiest expert's load over
         the mean, of the layer where that is worst;
         `moe_experts_touched_per_step` distinct experts a decode step
-        read, mean over steps and layers; `window_positions_skipped`
-        cache positions that a one-depth cache would have given the
-        decode steps to read and the ring did not."""
+        read, mean over steps and layers. Window layers:
+        `window_positions_skipped` cache positions that a one-depth
+        cache would have given the decode steps to read and the ring did
+        not. Recurrent layers: `recurrent_state_bytes` what the slots'
+        states and tails hold (a constant of the cache);
+        `recurrent_slot_steps` live slots over all decode steps, each of
+        which had every recurrent layer's state replaced;
+        `recurrent_chunks_scanned` chunks of real tokens the prefills
+        scanned a layer (a bucket's padded chunks and padding rows not
+        counted); `recurrent_state_absmax` the largest |S| of any live
+        slot and layer after the last decode step."""
         c = jax.device_get(cache["counters"])
-        load = np.asarray(c["moe_expert_load"], np.int64)
-        steps = int(c["decode_steps"])
-        mean = load.mean(axis=1)
-        worst = (load.max(axis=1)[mean > 0] / mean[mean > 0])
-        return {
-            "moe_pairs_routed": int(load.sum()),
-            "moe_expert_load_max_over_mean":
-                round(float(worst.max()), 4) if worst.size else None,
-            "moe_experts_touched_per_step":
-                round(float(np.mean(c["moe_experts_touched"])) / steps, 4)
-                if steps else None,
-            "window_positions_skipped":
-                float(c["window_positions_skipped"])}
+        out = {}
+        if self._routed:
+            load = np.asarray(c["moe_expert_load"], np.int64)
+            steps = int(c["decode_steps"])
+            mean = load.mean(axis=1)
+            worst = (load.max(axis=1)[mean > 0] / mean[mean > 0])
+            out.update({
+                "moe_pairs_routed": int(load.sum()),
+                "moe_expert_load_max_over_mean":
+                    round(float(worst.max()), 4) if worst.size else None,
+                "moe_experts_touched_per_step":
+                    round(float(np.mean(c["moe_experts_touched"])) / steps, 4)
+                    if steps else None})
+        if self._windowed:
+            out["window_positions_skipped"] = \
+                float(c["window_positions_skipped"])
+        if self._recurrent:
+            out.update({
+                "recurrent_state_bytes": int(sum(
+                    cache[name][i].nbytes for i in self._recurrent
+                    for name in ("state", "tail"))),
+                "recurrent_slot_steps": int(c["recurrent_slot_steps"]),
+                "recurrent_chunks_scanned":
+                    int(c["recurrent_chunks_scanned"]),
+                "recurrent_state_absmax":
+                    float(c["recurrent_state_absmax"])})
+        return out
+
+
+#: the name the class had while every layer's feed-forward was routed
+SparseDecoderLM = DecoderLM

@@ -18,7 +18,7 @@ from jax import lax
 from bigdl_tpu.nn import kv_cache
 from bigdl_tpu.nn.initialization import Xavier
 from bigdl_tpu.nn.module import ApplyContext, Module
-from bigdl_tpu.nn.normalization import LayerNormalization
+from bigdl_tpu.nn.normalization import LayerNormalization, rms_norm
 from bigdl_tpu.ops.attention_kernel import (blockwise_attention,
                                             causal_grouped_attention,
                                             flash_attention,
@@ -200,7 +200,11 @@ class GroupedQueryAttention(Module):
     j // (n_head // n_kv_head)), no bias; `window` keeps the last
     `window` positions (self included) or, None, all of them;
     `rope_base` is the rotary base or, None, no positional encoding at
-    all. Input [B, T, E] in any float type: it is cast to the weights'
+    all; `qk_norm` puts an RMSNorm (its eps) over the WHOLE q and k
+    projections, before the split into heads and the rotation, with
+    scales `q_norm` and `k_norm`; None, the default, has neither and
+    lowers to the program it always did. Input [B, T, E] in any float
+    type: it is cast to the weights'
     type for the projections, and the result is float32 (the output
     projection's accumulator, unrounded), for a residual stream kept in
     float32. The serving cache of a layer is the pair `init_cache`
@@ -209,7 +213,8 @@ class GroupedQueryAttention(Module):
 
     def __init__(self, embed_dim: int, n_head: int, n_kv_head: int,
                  head_dim: int, window: Optional[int] = None,
-                 rope_base: Optional[float] = None, name=None):
+                 rope_base: Optional[float] = None,
+                 qk_norm: Optional[float] = None, name=None):
         super().__init__(name)
         if n_head % n_kv_head:
             raise ValueError(
@@ -219,14 +224,19 @@ class GroupedQueryAttention(Module):
         self.e, self.h, self.hk, self.hd = embed_dim, n_head, n_kv_head, \
             head_dim
         self.window, self.rope_base = window, rope_base
+        self.qk_norm = qk_norm
 
     def init(self, rng):
         k1, k2, k3, k4 = jax.random.split(rng, 4)
         xav = Xavier()
-        return {"wq": xav(k1, (self.e, self.h * self.hd)),
-                "wk": xav(k2, (self.e, self.hk * self.hd)),
-                "wv": xav(k3, (self.e, self.hk * self.hd)),
-                "wo": xav(k4, (self.h * self.hd, self.e))}
+        p = {"wq": xav(k1, (self.e, self.h * self.hd)),
+             "wk": xav(k2, (self.e, self.hk * self.hd)),
+             "wv": xav(k3, (self.e, self.hk * self.hd)),
+             "wo": xav(k4, (self.h * self.hd, self.e))}
+        if self.qk_norm is not None:
+            p["q_norm"] = jnp.ones((self.h * self.hd,))
+            p["k_norm"] = jnp.ones((self.hk * self.hd,))
+        return p
 
     def project_qkv(self, params, x, positions=None):
         """q [B, H, T, hd] and k, v [B, Hkv, T, hd], rotated at
@@ -235,11 +245,14 @@ class GroupedQueryAttention(Module):
         b, t, _ = x.shape
         x = x.astype(params["wq"].dtype)  # a float32 residual stream
 
-        def heads(z, n):
+        def heads(w, n, norm=None):
+            z = x @ params[w]
+            if norm is not None and self.qk_norm is not None:
+                z = rms_norm(z, params[norm], self.qk_norm)
             return jnp.transpose(z.reshape(b, t, n, self.hd), (0, 2, 1, 3))
-        q = heads(x @ params["wq"], self.h)
-        k = heads(x @ params["wk"], self.hk)
-        v = heads(x @ params["wv"], self.hk)
+        q = heads("wq", self.h, "q_norm")
+        k = heads("wk", self.hk, "k_norm")
+        v = heads("wv", self.hk)
         if self.rope_base is not None:
             q = rope(q, positions, self.rope_base)
             k = rope(k, positions, self.rope_base)
@@ -254,9 +267,11 @@ class GroupedQueryAttention(Module):
         return jax.named_scope("full attention" if self.window is None
                                else "window attention")
 
-    def apply_prefill(self, params, x):
+    def apply_prefill(self, params, x, lengths=None):
         """(out [B, T, E], k, v [B, Hkv, T, hd]) of the whole sequence;
-        k and v are what a serving prefill commits."""
+        k and v are what a serving prefill commits. `lengths` is the
+        recurrent layers' argument and is not read: under the causal
+        mask no real position sees a row's padding."""
         with self._scope():
             q, k, v = self.project_qkv(params, x)
             o = causal_grouped_attention(q, k, v, self.window)

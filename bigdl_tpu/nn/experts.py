@@ -141,3 +141,29 @@ class RoutedExperts(Module):
                 lambda a: self._chunk(params, *a),
                 (x.reshape(n // c, c, -1), logits.reshape(n // c, c, -1)))
             return y.reshape(n, -1), experts.reshape(n, -1)
+
+
+class GatedFFN(Module):
+    """One dense gated feed-forward of width `d_hidden`, the same for
+    every token: y = (silu(x @ wg) * (x @ wu)) @ wd, no bias. x [..., d]
+    is cast to the weights' type for the products; the gate is taken in
+    float32 and the last product keeps its float32 accumulator."""
+
+    def __init__(self, d_model: int, d_hidden: int, name=None):
+        super().__init__(name)
+        self.d, self.hidden = d_model, d_hidden
+
+    def init(self, rng):
+        k1, k2, k3 = jax.random.split(rng, 3)
+        xav = Xavier()
+        return {"wg": xav(k1, (self.d, self.hidden)),
+                "wu": xav(k2, (self.d, self.hidden)),
+                "wd": xav(k3, (self.hidden, self.d))}
+
+    def apply(self, params, input, ctx):
+        with jax.named_scope("dense ffn"):
+            x = input.astype(params["wg"].dtype)
+            gate = jax.nn.silu((x @ params["wg"]).astype(jnp.float32))
+            hidden = gate * (x @ params["wu"]).astype(jnp.float32)
+            return jnp.dot(hidden.astype(x.dtype), params["wd"],
+                           preferred_element_type=jnp.float32)

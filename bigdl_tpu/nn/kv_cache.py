@@ -1,9 +1,10 @@
-"""What an attention layer keeps per serving slot, and how a decode step
-reads it. The one owner of the K/V cache's format (ROADMAP D3): the
-models build their caches from `init`, prefill lands through `commit`,
-a decode step writes through `write` and masks through `step_mask`.
+"""What a layer keeps per serving slot, and how a decode step reads it.
+The one owner of the per-slot state's format (ROADMAP D3): the models
+build their caches from `init` and `init_recurrent`, prefill lands
+through `commit`, a decode step writes K/V through `write` and masks
+through `step_mask`.
 
-Two kinds of layer, told apart by `window`:
+Three kinds of layer. Two keep keys and values, told apart by `window`:
 
 * full (`window` None): `[slots, kv_heads, max_len, head_dim]`, position
   p at index p.
@@ -18,6 +19,20 @@ A decode step over a full layer reads the cache only as deep as a rung
 of `depth_rungs(max_len)` that covers the deepest slot of the step: the
 ladder is a constant of the format, chosen by `rung_index` from the
 step's positions.
+
+* recurrent (`init_recurrent`; nn/linear_attention.py): a state of fixed
+  size, `[slots, heads, key_dim, value_dim]` float32, and the tail of
+  the layer's short causal convolution, `[slots, taps - 1, channels]`:
+  the last rows that came before it. No depth, no mask, no position
+  index, no ladder. A prefill SETS both whole at its slot ids
+  (`commit`); a decode step REPLACES both, for every slot it runs over.
+  Unlike a K/V write that is destructive: nothing of the state before
+  the step is left. What keeps that harmless (an idle slot riding along,
+  a bucket's padding row, warm-up against the live cache, a step
+  computed for a request that had ended): every request's prefill
+  overwrites its slot's state whole, `commit` is idempotent under
+  repeated slot ids, and the layer's update never grows a state
+  (tests/test_hybrid_decoder.py).
 """
 
 from __future__ import annotations
@@ -45,6 +60,17 @@ def init(slots: int, kv_heads: int, max_len: int, head_dim: int,
         raise ValueError(f"max_len must be >= 1, got {max_len}")
     return jnp.zeros((slots, kv_heads, depth(max_len, window), head_dim),
                      dtype)
+
+
+def init_recurrent(slots: int, heads: int, key_dim: int, value_dim: int,
+                   taps: int, channels: int, tail_dtype=jnp.float32):
+    """One recurrent layer's (state, tail), zeroed: the state float32
+    whatever the cache's type, the tail in the type of the rows it
+    holds."""
+    if slots < 1:
+        raise ValueError(f"slots must be >= 1, got {slots}")
+    return (jnp.zeros((slots, heads, key_dim, value_dim), jnp.float32),
+            jnp.zeros((slots, taps - 1, channels), tail_dtype))
 
 
 def depth_rungs(max_len: int) -> Tuple[int, ...]:
@@ -90,10 +116,12 @@ def write(cache, new, positions, window: Optional[int] = None):
 def commit(cache, new, slot_ids, lengths=None,
            window: Optional[int] = None):
     """Commit per-request prefill K/V `new` [B, H, T, hd] into slots of a
-    fleet-wide cache [S, H, L, hd] at sequence position 0. Rows may
-    repeat (bucket padding replicates the last request's row INCLUDING
-    its slot id): the scan writes in request order, so a padded
-    duplicate rewrites identical values and the last write wins.
+    fleet-wide cache [S, H, L, hd] at sequence position 0, or a
+    recurrent layer's state or tail `new` [B, ...] into `cache`
+    [S, ...] whole. Rows may repeat (bucket padding replicates the last
+    request's row INCLUDING its slot id): the scan writes in request
+    order, so a padded duplicate rewrites identical values and the last
+    write wins.
 
     A window layer whose prompt bucket is longer than its ring commits,
     per row, the last L positions of the row's real `lengths` [B] at the
@@ -107,7 +135,8 @@ def commit(cache, new, slot_ids, lengths=None,
 
     def body(c, inp):
         n, s = inp
-        return lax.dynamic_update_slice(c, n[None], (s, 0, 0, 0)), None
+        return lax.dynamic_update_slice(
+            c, n[None], (s,) + (0,) * (c.ndim - 1)), None
     out, _ = lax.scan(body, cache, (new, slot_ids))
     return out
 

@@ -189,6 +189,14 @@ class LayerNormalization(Module):
         return y * params["weight"] + params["bias"]
 
 
+def rms_norm(x, weight, eps: float):
+    """x / rms(x) * weight over the last axis; the statistics in float32
+    whatever the input's type, the result in the input's type."""
+    y = x.astype(jnp.float32)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+    return (y * weight).astype(x.dtype)
+
+
 class RMSNorm(Module):
     """Root-mean-square norm over the last axis: x / rms(x) * weight, no
     mean and no bias. The statistics are taken in float32 whatever the
@@ -202,10 +210,7 @@ class RMSNorm(Module):
         return {"weight": jnp.ones((self.hidden_size,))}
 
     def apply(self, params, input, ctx):
-        x = input.astype(jnp.float32)
-        y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
-                              + self.eps)
-        return (y * params["weight"]).astype(input.dtype)
+        return rms_norm(input, params["weight"], self.eps)
 
 
 def _gaussian_kernel(size: int, sigma: float = None):

@@ -291,10 +291,19 @@ RECORD_SCHEMAS: Dict[str, Dict] = {
                      # token-expert pairs, the busiest expert's load over
                      # the mean, distinct experts a decode step read,
                      # cache positions the window's ring did not read
+                     # (each kind's only where the model has such layers)
                      "moe_pairs_routed": int,
                      "moe_expert_load_max_over_mean": _OPT_NUM,
                      "moe_experts_touched_per_step": _OPT_NUM,
-                     "window_positions_skipped": _NUM},
+                     "window_positions_skipped": _NUM,
+                     # recurrent layers: bytes of the slots' states and
+                     # tails, live slot-steps whose states a decode step
+                     # replaced, chunks of real tokens prefills scanned,
+                     # the largest |S| of a live slot after the last step
+                     "recurrent_state_bytes": int,
+                     "recurrent_slot_steps": int,
+                     "recurrent_chunks_scanned": int,
+                     "recurrent_state_absmax": _NUM},
     },
     # fleet-level counters/gauges (serving/fleet.py), one per
     # membership change or maintain() tick; PrometheusTextSink renders

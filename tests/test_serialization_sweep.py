@@ -204,6 +204,9 @@ SPECS = {
         lambda: nn.GroupedQueryAttention(8, 4, 2, 4, window=3,
                                          rope_base=1e4),
         np.ones((2, 5, 8), np.float32)),
+    "GatedDeltaRule": (
+        lambda: nn.GatedDeltaRule(8, 2, 4, 4, chunk=4),
+        np.ones((2, 5, 8), np.float32)),
     "ScaledDotProductAttention": (
         lambda: nn.ScaledDotProductAttention(), Table(
             np.ones((2, 2, 5, 4), np.float32), np.ones((2, 2, 5, 4), np.float32),

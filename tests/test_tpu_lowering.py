@@ -389,3 +389,65 @@ class TestSparseDecodeProgramCompiles:
         assert entry.count("moe experts/moe down/") >= n_layer
         assert entry.count("moe experts/moe gate up/") >= n_layer
         assert "tpu_custom_call" not in hlo
+
+
+class TestHybridDecodeProgramCompiles:
+    """`GenerationEngine`'s `jit__decode_fn` over `DecoderLM` at the
+    shapes of `olmo-hybrid-7b.serve-decode` (32 slots x 2048, hidden
+    3840, [gated delta rule x3, full attention] x 4 with 30 heads of
+    key 96 / value 192 and 30 of 128, a dense FFN of 11008, vocabulary
+    100352, bf16 weights, K/V and tails, float32 state and norms),
+    compiled by libtpu for a v5e that is not attached. Nothing runs, so
+    nothing here is a timing."""
+
+    def test_the_state_is_replaced_in_place_by_one_fusion_a_layer(self):
+        import re
+        from bigdl_tpu.models.decoder import DecoderLM, LayerSpec
+        on = _v5e_device()
+        slots, max_len, n_layer = 32, 2048, 16
+        layers = [LayerSpec(mixer="attention" if i % 4 == 3
+                            else "gated_delta", ffn="dense", norm="output")
+                  for i in range(n_layer)]
+        model = DecoderLM(100352, embed_dim=3840, n_head=30, n_kv_head=30,
+                          head_dim=128, layers=layers, max_len=max_len,
+                          cache_dtype=jnp.bfloat16, ffn_dim=11008,
+                          qk_norm=True, linear_heads=30, linear_key_dim=96,
+                          linear_value_dim=192)
+        f32 = {"ln1", "ln2", "norm", "q_norm", "k_norm", "a_log", "dt_bias"}
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, a: struct(
+                a.shape, jnp.float32 if f32
+                & {getattr(k, "key", None) for k in path} else jnp.bfloat16,
+                on),
+            jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+        cache = jax.tree_util.tree_map(
+            lambda a: struct(a.shape, a.dtype, on),
+            jax.eval_shape(lambda: model.init_cache(slots, max_len)))
+        state = "f32[32,30,96,192]"
+        assert sum(1 for s in cache["state"] if s is not None) == 12
+        compiled = _compile_decode_program(model, params, cache, slots, on)
+        hlo = compiled.as_text()
+        entry = hlo[hlo.index("ENTRY"):]
+        assert "jit__decode_fn" in hlo and "tpu_custom_call" not in hlo
+        # every slot's state is updated where it lies: 12 x 70.8 MB of
+        # states, 4 x 2 x 503 MB of K/V and the tails are donated and
+        # aliased, and nothing of a state's size is planned beside them
+        m = compiled.memory_analysis()
+        assert m.alias_size_in_bytes >= 4_900_000_000
+        assert m.temp_size_in_bytes < 150_000_000
+        moved = [line for line in entry.splitlines()
+                 if re.search(r" (copy|copy-start|transpose)\(", line)
+                 and line.split(" = ", 1)[-1].lstrip().startswith(state)]
+        assert not moved, moved
+        # XLA's form of the update (PERF.md, PR 36): a fusion that reads
+        # a layer's state for S^T k and S^T q, and one that reads it
+        # again and writes the new one with the largest |S| reduced in
+        # the same pass: two reads and a write where a kernel would make
+        # one of each. Each state is written by ONE instruction
+        writers = re.findall(
+            rf"^\s*%[\w.\-]+ = \(f32\[32\]\S*, {re.escape(state)}\S*\) "
+            r"fusion\(", entry, re.M)
+        assert len(writers) == 12, len(writers)
+        assert entry.count("linear attention/gdn step/") >= 12
+        assert entry.count("linear attention/gdn conv/") >= 12
+        assert "full attention/" in entry and "dense ffn/" in entry
