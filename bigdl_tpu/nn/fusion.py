@@ -1,8 +1,9 @@
 """BatchNorm+ReLU fusion: pattern matching over the module graph.
 
-The fused elementwise tail (ops/bn_relu_kernel.py) only pays off if
-existing models get it WITHOUT edits, so the containers pattern-match the
-`nn/normalization.py` -> `nn/activation.py` adjacency at apply time:
+Existing models get the fused elementwise tail
+(`ops/bn_relu_kernel.py::bn_relu`) WITHOUT edits: the containers
+pattern-match the `nn/normalization.py` -> `nn/activation.py` adjacency
+at apply time:
 
 - `Sequential`: a `BatchNormalization` child immediately followed by a
   `ReLU` child collapses into one `apply_with_activation` call (ResNet's
@@ -19,12 +20,15 @@ semantics. The match runs at trace time (inside jit it costs nothing per
 step) and is re-evaluated every apply, so toggling fusion never requires
 rebuilding a model.
 
-The toggle is process-global, default ON (`BIGDL_TPU_FUSE_BN_RELU=0`
-disarms from the environment); `bench_cli --fusion` drives the A/B
-through `fusion_scope`. Off-TPU the fused tail lowers to the reference
-jnp expressions, bit-identical to the unfused graph (the CPU CI parity
-gate in scripts/run_ci.sh pins this), so the default-on fusion changes
-no CPU numerics.
+What the match collapses into lowers as `bn_relu` says: since PR 37 the
+reference jnp expressions on every backend, the TPU included (the Mosaic
+kernel pair lost to the compiler's own fusion at every ResNet-50 shape on
+the v5e: PERF.md section 6), bit-identical to the unfused graph (the CPU
+CI parity gate in scripts/run_ci.sh pins this). So the toggle changes no
+numerics and, today, no speed: it is process-global, default ON
+(`BIGDL_TPU_FUSE_BN_RELU=0` disarms from the environment), `bench_cli
+--fusion` drives the A/B through `fusion_scope`, and ROADMAP D4 decides
+whether it stays.
 """
 
 from __future__ import annotations

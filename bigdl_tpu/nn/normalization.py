@@ -99,13 +99,14 @@ class BatchNormalization(Module):
 
     def apply_with_activation(self, params, input, ctx: ApplyContext,
                               relu: bool = True):
-        """BN + activation as ONE fused elementwise tail
-        (ops/bn_relu_kernel.py): a single VMEM-resident read-modify-write
-        on TPU instead of separate normalize and ReLU HBM passes;
-        off-TPU it lowers to the exact unfused expressions (bit-identical
-        — the containers' pattern matcher relies on this). Statistics,
-        state updates, and the folded coefficients are shared with the
-        plain `apply`."""
+        """BN + activation as ONE elementwise tail
+        (ops/bn_relu_kernel.py::bn_relu): the exact unfused expressions
+        on every backend (bit-identical — the containers' pattern matcher
+        relies on this), which XLA fuses into the neighbouring
+        convolutions; the Mosaic kernel pair behind it lost to that on
+        the v5e at every ResNet-50 shape (PR 37). Statistics, state
+        updates, and the folded coefficients are shared with the plain
+        `apply`."""
         if getattr(self, "data_format", "NHWC") != "NHWC":
             # NCHW transposes around the tail; keep a correct fallback
             # (the pattern matcher never fuses NCHW — belt and braces)

@@ -12,28 +12,30 @@ with `scale`/`shift` the per-channel folded BN coefficients the module
 already computes (nn/normalization.py folds weight/rsqrt(var) into one
 multiply-add). The backward fuses the same way (`custom_vjp`): one kernel
 produces dx and per-tile partial reductions for dscale/dshift, so training
-never materializes the mask or the pre-activation in HBM. Whether this
-beats what XLA does with the unfused graph on the chip is ROADMAP S1's
-pair-run; the kernels first compiled under Mosaic and ran on a v5e in
-PR 22 (PERF.md).
+never materializes the mask or the pre-activation in HBM. The kernels
+first compiled under Mosaic and ran on a v5e in PR 22 (PERF.md).
 
-Routing: on TPU `bn_relu` dispatches the Pallas custom_vjp pair
-(`bn_relu_pallas`), compiled by Mosaic; off-TPU it INLINES the exact
-unfused op sequence with no custom-derivative boundary, so the CPU fused
-graph is structurally the unfused graph minus the module dispatch —
-autodiff and trajectories stay bit-identical (the CI parity gate pins
-this; a custom_vjp boundary on CPU measurably perturbs XLA's fusion/FMA
-grouping at the ~1e-7 level). Interpreter mode exists only behind the
-test hooks: `INTERPRET`, an explicit `interpret=True` (the raw-kernel
-parity tests and the `_pick_tile_n` boundary suite), and `FORCE_PALLAS`,
-which routes the public op through the interpreter-mode custom_vjp
-off-TPU for end-to-end kernel drills — forward bit-identical, backward
-within 1e-6 of the unfused autodiff (the tiled partial reductions regroup
-sums).
+Routing: `bn_relu` INLINES the exact unfused op sequence on every
+backend, the TPU included, with no custom-derivative boundary: XLA fuses
+the chain into its neighbours, and autodiff and trajectories stay
+bit-identical to the unfused graph (the CI parity gate pins this; a
+custom_vjp boundary measurably perturbs XLA's fusion/FMA grouping at the
+~1e-7 level). The chip decided it (ROADMAP S1's pair-run, PR 37; the
+table is in `bn_relu`'s docstring and PERF.md section 6): a custom call
+is a wall to XLA, so the float32 copy of every activation has to exist in
+memory, be relaid for the kernel, and be kept as the backward's residual,
+and at no ResNet-50 shape did the kernel pair beat the compiler's own
+fusion. The Pallas pair stays reachable as `bn_relu_pallas`, and behind
+the test hooks: `INTERPRET`, an explicit `interpret=True` (the
+raw-kernel parity tests and the `_pick_tile_n` boundary suite), and
+`FORCE_PALLAS`, which routes the public op through the custom_vjp for
+end-to-end kernel drills (interpreter mode off-TPU) — forward
+bit-identical, backward within 1e-6 of the unfused autodiff (the tiled
+partial reductions regroup sums). ROADMAP D4 decides what of the pair
+stays.
 
 No reference counterpart: the reference's CPU BN calls MKL's fused
-batchnorm primitive; this exists because on TPU the fusion has to be
-expressed, not linked.
+batchnorm primitive.
 """
 
 from __future__ import annotations
@@ -199,7 +201,7 @@ def bn_relu_backward(x2, scale, shift, g2, relu: bool = True,
 
 
 # ---------------------------------------------------------------------- #
-# reference (unfused-equivalent) expressions — the off-TPU lowering
+# reference (unfused-equivalent) expressions the kernels are held to
 # ---------------------------------------------------------------------- #
 
 def _reference_forward(x, scale, shift, relu: bool, out_dtype):
@@ -269,7 +271,7 @@ def _backward_nd(x, scale, shift, g, relu):
 
 
 # ---------------------------------------------------------------------- #
-# public op: backend-routed dispatcher over the custom_vjp kernel pair
+# public op: the inline expressions; the custom_vjp kernel pair by name
 # ---------------------------------------------------------------------- #
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -277,7 +279,8 @@ def bn_relu_pallas(x, scale, shift, relu: bool = True, out_dtype=None):
     """The fused tail as a custom_vjp over the Pallas kernels (forward
     AND backward fuse), compiled by Mosaic; interpreter mode only under
     the `INTERPRET`/`FORCE_PALLAS` test hooks. `relu`/`out_dtype` are
-    static. Use `bn_relu` for backend-routed production dispatch."""
+    static. No model reaches it through `bn_relu` (PR 37): chip_smoke.py
+    and the parity tests call it by name."""
     return _forward_nd(x, scale, shift, relu,
                        jnp.dtype(out_dtype or x.dtype))
 
@@ -299,16 +302,19 @@ def bn_relu(x, scale, shift, relu: bool = True, out_dtype=None):
     """Fused `activation(x * scale + shift)` over the trailing channel
     axis of x (any leading rank).
 
-    On TPU (or under `FORCE_PALLAS`) this is the Pallas custom_vjp pair —
-    one VMEM-resident pass each direction. Off-TPU it inlines the EXACT
-    unfused op sequence (multiply-add, cast, `jax.nn.relu`) with no
-    custom-derivative boundary, so the CPU fused graph autodiffs
-    bit-identically to the unfused one — XLA fuses the chain itself and
-    the CI trajectory parity gate stays exact. With scale=1 this is the
-    bias+activation tail; nn/normalization.py feeds it the folded BN
-    coefficients."""
+    On every backend this inlines the EXACT unfused op sequence
+    (multiply-add, cast, `jax.nn.relu`) with no custom-derivative
+    boundary, so the fused graph autodiffs bit-identically to the unfused
+    one and XLA fuses the chain into its neighbours. The chip's table
+    (v5e, ResNet-50 b128 bf16 step, device ms with the Mosaic pair at one
+    class of tails alone, PR 37): nowhere 50.39; stem [1605632, 64] 59.91;
+    C=64 65.90; C=128 57.92; C=256 55.62; C=512 51.79; everywhere 89.76.
+    The rule: a shape keeps the pair only where it beat "nowhere"; none
+    did, so no backend takes it. `FORCE_PALLAS` (a test hook) routes
+    through `bn_relu_pallas`. With scale=1 this is the bias+activation
+    tail; nn/normalization.py feeds it the folded BN coefficients."""
     out_dtype = jnp.dtype(out_dtype or x.dtype)
-    if FORCE_PALLAS or jax.default_backend() == "tpu":
+    if FORCE_PALLAS:
         return bn_relu_pallas(x, scale, shift, relu, out_dtype)
     y = (x * scale + shift).astype(out_dtype)
     # jax.nn.relu, not jnp.maximum: its custom_jvp zeroes the gradient at

@@ -808,7 +808,7 @@ def bench_fusion_ab(segments: int = 10, seg_iters: int = 6,
     Gates the PARITY contract first (same pattern as the generation
     smoke), two legs per model (LeNet — no BN, fusion must be a no-op —
     and ResNet-8/CIFAR):
-    (1) production CPU routing: fused loss trajectories BIT-identical to
+    (1) production routing: fused loss trajectories BIT-identical to
         the unfused graph (the inline tail is structurally the unfused
         ops);
     (2) kernel routing (Pallas custom_vjp FORCED, interpreter mode):
@@ -821,10 +821,10 @@ def bench_fusion_ab(segments: int = 10, seg_iters: int = 6,
     fused vs unfused step executables (the PR 8 attribution stream —
     compile records off the CompiledFunction wrapper), and wall-clock
     step time via the alternated pair-ratio estimator from docs/PERF.md.
-    CPU guard: off-TPU the fused tail lowers to the same XLA-fused
-    elementwise expressions, so the CPU ratio measures only the pattern
-    rewrite (~1.0x expected); the kernel's HBM win needs the TPU capture
-    (docs/PERF.md "Fusion and overlap"). Prints ONE json line."""
+    The fused tail lowers to the same XLA-fused elementwise expressions
+    on every backend (PR 37: on the v5e the kernel pair lost to them,
+    docs/PERF.md "Fusion and overlap"), so the ratio measures only the
+    pattern rewrite (~1.0x expected). Prints ONE json line."""
     import jax
 
     import bigdl_tpu.nn as nn_

@@ -9,11 +9,13 @@ weights made from a seed:
   device   JAX found a TPU whose kind the peak table knows
   train    ResNet-50 224x224, 128 per chip, bf16 compute over f32
            masters, through DistriOptimizer over build_mesh(), BN+ReLU
-           fusion at its default; the Mosaic kernels are in the step
-           that ran, the loss is finite and falls; on several chips the
-           step all-reduces gradients and gathers no batch, and a second
-           run splits the mesh data=2 x model=2
-  kernels  the fused BN+ReLU pair against its reference expressions;
+           pattern fusion at its default; the step that ran holds the
+           Mosaic kernels `bn_relu`'s routing implies (none since PR 37),
+           the loss is finite and falls; on several chips the step
+           all-reduces gradients and gathers no batch, and a second run
+           splits the mesh data=2 x model=2
+  kernels  the BN+ReLU Mosaic pair, called by name, against its
+           reference expressions;
            flash attention fwd+bwd against naive attention; a
            TransformerLM train step through optim.Optimizer with the
            flash kernels in it; on several chips ring and zigzag
@@ -55,10 +57,12 @@ BF16_TOL = 2e-2
 PER_CHIP_BATCH = 128
 TRAIN_STEPS = 30
 LM_VOCAB, LM_SEQ, LM_BATCH, LM_STEPS = 1024, 2048, 8, 4
-#: ResNet-50 has 33 BatchNorm+ReLU pairs the containers fuse (the stem
-#: and two per bottleneck block); each is one forward and one backward
-#: Mosaic kernel in the train step.
-RESNET50_FUSED_PAIRS = 33
+#: ResNet-50's 33 BatchNorm+ReLU pairs that the containers collapse into
+#: `bn_relu` (the stem and two per bottleneck block), as (rows an image,
+#: channels, how many)
+RESNET50_TAILS = ((112 * 112, 64, 1), (56 * 56, 64, 6), (56 * 56, 128, 1),
+                  (28 * 28, 128, 7), (28 * 28, 256, 1), (14 * 14, 256, 11),
+                  (14 * 14, 512, 1), (7 * 7, 512, 5))
 
 
 def say(msg=""):
@@ -150,7 +154,7 @@ def train_resnet50(mesh, steps):
     return opt, losses, sink, x
 
 
-def report_run(tag, opt, losses, sink, steps, n_kernels):
+def report_run(tag, opt, losses, sink, steps):
     compiles = [r for r in sink.records if r["type"] == "compile"]
     steady = [r["step_time_s"] for r in sink.steps()[2:]]
     say(f"  {tag}: lower {sum(r['lower_s'] for r in compiles):.1f} s, "
@@ -164,11 +168,7 @@ def report_run(tag, opt, losses, sink, steps, n_kernels):
     check(len(compiles) == 1 and opt._step_fn.last_info is not None,
           f"{tag}: one compile for the whole run, and the last step ran "
           f"that executable (no plain-jit fallback)")
-    mosaic, ops, gathered = hlo_ops(opt._step_fn.executables()[0])
-    check(mosaic >= n_kernels,
-          f"{tag}: {mosaic} Mosaic kernels in the compiled step "
-          f"(>= {n_kernels})")
-    return ops, gathered
+    return hlo_ops(opt._step_fn.executables()[0])
 
 
 def device_peaks():
@@ -179,11 +179,33 @@ def device_peaks():
     return [s["peak_bytes_in_use"] + s["peak_bytes_reserved"] for s in stats]
 
 
+def resnet50_fused_pairs(batch):
+    """How many of ResNet-50's 33 BN+ReLU tails `bn_relu` sends to the
+    Mosaic pair at `batch` images: asked of the router itself, shape by
+    shape. Each is one forward and one backward kernel in the step."""
+    from bigdl_tpu.ops import bn_relu_kernel as bk
+    pairs = 0
+    for rows, c, n in RESNET50_TAILS:
+        coef = jax.ShapeDtypeStruct((c,), jnp.float32)
+        jaxpr = jax.make_jaxpr(
+            lambda x, s, b: bk.bn_relu(x, s, b, True, jnp.bfloat16))(
+                jax.ShapeDtypeStruct((batch * rows, c), jnp.float32),
+                coef, coef)
+        pairs += n * bk.count_fused_calls(jaxpr)
+    return pairs
+
+
 def phase_train(n_dev):
     from bigdl_tpu.nn import fusion
     from bigdl_tpu.parallel.mesh import build_mesh
     say("== train: ResNet-50 224x224 bf16 through DistriOptimizer")
-    check(fusion.fusion_enabled(), "BN+ReLU fusion is at its default (on)")
+    check(fusion.fusion_enabled(),
+          "BN+ReLU pattern fusion is at its default (on): the 33 tails "
+          "collapse into bn_relu")
+    pairs = resnet50_fused_pairs(PER_CHIP_BATCH)
+    say(f"  bn_relu sends {pairs} of the {sum(t[2] for t in RESNET50_TAILS)}"
+        f" tails to the Mosaic pair at {PER_CHIP_BATCH} images a chip, "
+        f"and inlines the rest for XLA to fuse")
     layouts = [build_mesh()]
     if n_dev >= 4 and n_dev % 2 == 0:
         layouts.append(build_mesh(data=n_dev // 2, model=2))
@@ -191,8 +213,11 @@ def phase_train(n_dev):
         tag = "x".join(f"{k}={v}" for k, v in mesh.shape.items())
         steps = TRAIN_STEPS if mesh is layouts[0] else TRAIN_STEPS // 3
         opt, losses, sink, x = train_resnet50(mesh, steps)
-        ops, gathered = report_run(tag, opt, losses, sink, steps,
-                                   2 * RESNET50_FUSED_PAIRS)
+        mosaic, ops, gathered = report_run(tag, opt, losses, sink, steps)
+        check(mosaic == 2 * pairs,
+              f"{tag}: {mosaic} Mosaic kernels in the compiled step (a "
+              f"forward and a backward one for each of the {pairs} tails "
+              f"bn_relu keeps)")
         peaks = device_peaks()
         say(f"  {tag}: peak device memory "
             f"{[round(p / 2 ** 30, 2) for p in peaks]} GiB")
@@ -234,18 +259,21 @@ def kernel_parity():
     shift = jax.random.normal(ks[2], (64,), jnp.float32)
     g = jax.random.normal(ks[3], x.shape, jnp.bfloat16)
     y, vjp = jax.vjp(
-        lambda *a: bk.bn_relu(*a, True, jnp.bfloat16), x, scale, shift)
+        lambda *a: bk.bn_relu_pallas(*a, True, jnp.dtype(jnp.bfloat16)),
+        x, scale, shift)
     want_y = jax.jit(bk._reference_forward, static_argnums=(3, 4))(
         x, scale, shift, True, jnp.bfloat16)
     check(y.dtype == jnp.bfloat16 and y.shape == x.shape
           and bool(jnp.array_equal(y, want_y)),
-          "bn_relu forward equals the unfused expressions bit for bit")
+          "bn_relu_pallas forward equals the unfused expressions bit for "
+          "bit")
     want = jax.jit(bk._reference_backward, static_argnums=(4, 5))(
         x, scale, shift, g, True, jnp.bfloat16)
     for name, got, ref in zip(("dx", "dscale", "dshift"), vjp(g), want):
         err = rel_err(got, ref)
-        check(err < 1e-5, f"bn_relu backward {name} within 1e-5 of the "
-                          f"unfused autodiff (f32 sums regrouped): {err:.1e}")
+        check(err < 1e-5, f"bn_relu_pallas backward {name} within 1e-5 of "
+                          f"the unfused autodiff (f32 sums regrouped): "
+                          f"{err:.1e}")
 
     # flash attention fwd+bwd against naive attention in f32
     q, k, v = (jax.random.normal(kk, (2, 8, 2048, 64), jnp.bfloat16)
@@ -306,8 +334,10 @@ def lm_step():
     opt.set_iteration_hook(lambda state: losses.append(state["loss"]))
     opt.optimize()
     # per layer: one forward kernel, and dq and dk/dv backward kernels
-    report_run("transformer-LM", opt, losses, sink, LM_STEPS,
-               3 * model.n_layer)
+    mosaic, _, _ = report_run("transformer-LM", opt, losses, sink, LM_STEPS)
+    check(mosaic >= 3 * model.n_layer,
+          f"transformer-LM: {mosaic} Mosaic kernels in the compiled step "
+          f"(>= {3 * model.n_layer})")
     return model
 
 

@@ -3,7 +3,7 @@ training-step hot-path contracts (ops/bn_relu_kernel.py, nn/fusion.py).
 
 Mirrors the stem kernel's test discipline: interpret-mode parity at
 boundary tile shapes, jaxpr-level structural asserts, and bit-identity
-of the CPU production routing against the unfused graph."""
+of the production routing against the unfused graph."""
 
 import numpy as np
 import pytest
@@ -102,10 +102,14 @@ class TestKernelParity:
         for a, bb in zip(gu, gf):
             np.testing.assert_allclose(a, bb, rtol=1e-6, atol=1e-6)
 
-    def test_cpu_routing_is_bit_identical_including_grads(self):
-        # the production off-TPU route inlines the unfused ops: autodiff
-        # must agree BITWISE (this is what keeps the CI trajectory
-        # parity gate exact)
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    def test_routing_is_bit_identical_including_grads(self, monkeypatch,
+                                                      backend):
+        # the production route inlines the unfused ops whatever backend
+        # JAX names (PR 37: the TPU no longer takes the kernel pair):
+        # autodiff must agree BITWISE (this is what keeps the CI
+        # trajectory parity gate exact)
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
         rs = np.random.RandomState(3)
         x, s, b = _rand(rs, 40, 12), _rand(rs, 12), _rand(rs, 12)
 

@@ -170,14 +170,51 @@ def resnet50_step(net, data: int, model: int):
 class TestResNet50StepLowers:
     @pytest.mark.parametrize("data,model", [(1, 1), (4, 1)])
     def test_fused_train_step(self, tpu_routing, resnet50, data, model):
-        # (4, 1): a Mosaic call under a multi-device jit lowers only
-        # inside a shard_map (ops/partitioning.py)
+        # no ResNet-50 tail crosses into the Mosaic pair (PR 37: the
+        # chip's pair-run, `bn_relu`'s docstring), on one chip as on
+        # one of four; what the matcher collapses lowers to the unfused
+        # step's jaxpr, equation for equation
         assert fusion.fusion_enabled()
         step, args = resnet50_step(resnet50, data, model)
         traced = step.trace(*args)
-        assert bk.count_fused_calls(traced.jaxpr) == 33
+        assert bk.count_fused_calls(traced.jaxpr) == 0
         lowered = traced.lower(lowering_platforms=("tpu",))
-        assert n_mosaic(lowered) == 66  # one forward + one backward each
+        assert n_mosaic(lowered) == 0
+        with fusion.fusion_scope(False):
+            step, args = resnet50_step(resnet50, data, model)
+            unfused = step.trace(*args)
+        assert str(traced.jaxpr) == str(unfused.jaxpr)
+
+
+#: ResNet-50's 33 BN+ReLU tails at 128 images fall into five classes of
+#: [rows, channels]: the stem; the other 64-channel tails; 128; 256; 512
+RESNET50_TAIL_CLASSES = [
+    [(1605632, 64)], [(401408, 64)], [(401408, 128), (100352, 128)],
+    [(100352, 256), (25088, 256)], [(25088, 512), (6272, 512)]]
+
+
+class TestBnReluRouter:
+    @pytest.mark.parametrize("shapes", RESNET50_TAIL_CLASSES,
+                             ids=["stem", "c64", "c128", "c256", "c512"])
+    @pytest.mark.parametrize("fuse_env", [None, "0", "1"])
+    def test_decision_follows_shape_and_dtypes_alone(
+            self, tpu_routing, monkeypatch, shapes, fuse_env):
+        # the chip's table is empty (no class keeps the kernel), whatever
+        # the environment says and on a second call as on the first
+        if fuse_env is None:
+            monkeypatch.delenv("BIGDL_TPU_FUSE_BN_RELU", raising=False)
+        else:
+            monkeypatch.setenv("BIGDL_TPU_FUSE_BN_RELU", fuse_env)
+        for n, c in shapes:
+            args = (struct((n, c), jnp.float32), struct((c,), jnp.float32),
+                    struct((c,), jnp.float32))
+            for out_dtype in (jnp.bfloat16, jnp.float32):
+                def tail(x, s, b):
+                    return bk.bn_relu(x, s, b, True, out_dtype)
+                for _ in range(2):
+                    jaxpr = jax.make_jaxpr(tail)(*args)
+                    assert bk.count_fused_calls(jaxpr) == 0
+                assert n_mosaic(lower_for_tpu(tail, *args)) == 0
 
 
 # ---------------------------------------------------------------------- #
