@@ -2,28 +2,33 @@
 
 Each layer is a `LayerSpec`: its token mixer (grouped-query attention,
 `nn.GroupedQueryAttention`, over a sliding `window` or everything, with
-a rotary base or no positional encoding at all; or the gated delta
+a rotary base or no positional encoding at all; the gated delta
 rule's linear attention, `nn.GatedDeltaRule`, which keeps a fixed-size
-state instead of keys and values), its feed-forward (dropless routed
-experts, `nn.experts.RoutedExperts`, or one dense gated one,
+state instead of keys and values; or latent attention,
+`nn.LatentAttention`, which keeps one compressed latent a position for
+all heads), its feed-forward (dropless routed
+experts, `nn.experts.RoutedExperts`, with or without an always-on
+`shared` expert added to their sum, or one dense gated one,
 `nn.experts.GatedFFN`) and where its RMSNorms sit: on each sub-layer's
 input,
 
     h = norm1(x);  r = h @ router;  x = x + attn(h)
     u = norm2(x);  x = x + ffn(u)        experts routed by r
 
-(a router reads the ATTENTION block's normed input, before attention
-runs), or on each sub-layer's output,
+(`router_reads="attention"`: the router reads the ATTENTION block's
+normed input, before attention runs; "ffn": r = u @ router, the
+feed-forward's own), or on each sub-layer's output,
 
     x = x + norm1(attn(x));  x = x + norm2(ffn(x)).
 
 The serving side is what `GenerationEngine` calls (`init_cache`,
 `apply_prefill`, `apply_step`, `cache_stats`): the cache gives each
 layer what its kind keeps a slot (nn/kv_cache.py: K and V as deep as
-the kind needs, or a recurrent state and its convolution's tail),
-prefill takes the head over each prompt's last real position only, and
-the cache pytree carries device-side counters of the routing, of the
-window and of the recurrent state.
+the kind needs, a recurrent state and its convolution's tail, or the
+latent and the shared rotary key), prefill takes the head over each
+prompt's last real position only, and the cache pytree carries
+device-side counters of the routing, of the window, of the recurrent
+state and of the latent's reads.
 """
 
 from __future__ import annotations
@@ -39,32 +44,65 @@ from bigdl_tpu.nn import kv_cache
 from bigdl_tpu.nn.attention import GroupedQueryAttention
 from bigdl_tpu.nn.experts import GatedFFN, RoutedExperts
 from bigdl_tpu.nn.initialization import Xavier
+from bigdl_tpu.nn.latent_attention import LatentAttention
 from bigdl_tpu.nn.linear_attention import GatedDeltaRule
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.nn.normalization import RMSNorm
 
-#: what a layer may keep a serving slot, by the cache's names: an
-#: attention layer "k" and "v", a recurrent one "state" and "tail"
-_KEPT = ("k", "v", "state", "tail")
+#: what a layer keeps a serving slot, by its mixer and by the cache's
+#: names: an attention layer "k" and "v", a recurrent one "state" and
+#: "tail", a latent one "latent" and "k_pe"
+_KEEPS = {"attention": ("k", "v"), "gated_delta": ("state", "tail"),
+          "latent": ("latent", "k_pe")}
+_KEPT = tuple(name for pair in _KEEPS.values() for name in pair)
+
+
+@dataclass(frozen=True)
+class LatentDims:
+    """A latent-attention layer's widths: each head's query and key
+    without position (`nope`) and rotary (`rope`), its value (`value`),
+    and the latent's `rank`."""
+    nope: int
+    rope: int
+    value: int
+    rank: int
+
+
+@dataclass(frozen=True)
+class ExpertsKind:
+    """How the routed experts gate ("relu", "silu") and how their router
+    scores (`nn.experts.route`: "softmax"; or "sigmoid", chosen by score
+    plus the block's `router_bias`, weighed by the score, times
+    `scale`)."""
+    gate: str = "relu"
+    scoring: str = "softmax"
+    scale: float = 1.0
 
 
 @dataclass(frozen=True)
 class LayerSpec:
     """One layer of the pattern. `mixer`: "attention" (over the last
     `window` positions, None: all; `rope_base` of its rotary encoding,
-    None: no positional encoding) or "gated_delta" (linear attention;
-    neither applies). `ffn`: "experts" (routed) or "dense". `norm`: the
-    RMSNorms on each sub-layer's "input" or "output"."""
+    None: no positional encoding), "gated_delta" (linear attention;
+    neither applies) or "latent" (latent attention over everything; its
+    `rope_base` is a number). `ffn`: "experts" (routed; beside them an
+    always-on gated expert of width `shared`, 0: none; their router
+    reads what `router_reads` names, the "attention" block's normed
+    input or the "ffn"'s own) or "dense". `norm`: the RMSNorms on each
+    sub-layer's "input" or "output"."""
     window: Optional[int] = None
     rope_base: Optional[float] = None
     mixer: str = "attention"
     ffn: str = "experts"
     norm: str = "input"
+    shared: int = 0
+    router_reads: str = "attention"
 
     def __post_init__(self):
-        for field, known in (("mixer", ("attention", "gated_delta")),
+        for field, known in (("mixer", tuple(_KEEPS)),
                              ("ffn", ("experts", "dense")),
-                             ("norm", ("input", "output"))):
+                             ("norm", ("input", "output")),
+                             ("router_reads", ("attention", "ffn"))):
             if getattr(self, field) not in known:
                 raise ValueError(f"LayerSpec.{field} is one of {known}, "
                                  f"got {getattr(self, field)!r}")
@@ -73,22 +111,35 @@ class LayerSpec:
             raise ValueError("LayerSpec.window and rope_base belong to "
                              "mixer='attention'; a gated_delta layer keeps "
                              "a state, not positions")
+        if self.mixer == "latent" and (self.window is not None
+                                       or self.rope_base is None):
+            raise ValueError("a latent layer attends to everything and "
+                             "rotates its rotary part: window None, "
+                             "rope_base a number")
+        if self.ffn == "dense" and (self.shared
+                                    or self.router_reads != "attention"):
+            raise ValueError("LayerSpec.shared and router_reads belong to "
+                             "ffn='experts'")
 
 
 class DecoderBlock(Module):
     """`attn` is the layer's token mixer and `keeps` the names of the two
-    things it keeps a serving slot ("k", "v" or "state", "tail"); the
-    feed-forward is `experts` (with the block's `router`) or `ffn`."""
+    things it keeps a serving slot (`_KEEPS`); the feed-forward is
+    `experts` (with the block's `router`, a sigmoid router's
+    `router_bias`, and the `shared` expert where the layer has one) or
+    `ffn`."""
 
     def __init__(self, embed_dim: int, spec: LayerSpec, attn: Module,
                  ffn: Module, eps: float = 1e-6, name=None):
         super().__init__(name)
         self.e, self.window = embed_dim, spec.window
         self.attn = attn
-        self.keeps = ("k", "v") if spec.mixer == "attention" \
-            else ("state", "tail")
+        self.keeps = _KEEPS[spec.mixer]
         self.experts = ffn if spec.ffn == "experts" else None
         self.ffn = ffn if spec.ffn == "dense" else None
+        self.shared = GatedFFN(embed_dim, spec.shared) if spec.shared \
+            else None
+        self.route_early = spec.router_reads == "attention"
         self.norm_output = spec.norm == "output"
         self.ln1, self.ln2 = RMSNorm(embed_dim, eps), RMSNorm(embed_dim, eps)
 
@@ -99,13 +150,17 @@ class DecoderBlock(Module):
         if self.experts is not None:
             p["router"] = Xavier()(k2, (self.e, self.experts.n_experts))
             p["experts"] = self.experts.init(k3)
+            if self.experts.scoring == "sigmoid":
+                p["router_bias"] = jnp.zeros((self.experts.n_experts,))
+            if self.shared is not None:
+                p["shared"] = self.shared.init(jax.random.fold_in(rng, 3))
         else:
             p["ffn"] = self.ffn.init(k3)
         return p
 
     def _route(self, params, h):
-        """Router logits in float32 from the attention block's normed
-        input, itself float32 and unrounded (a float32 product at full
+        """Router logits in float32 from a sub-layer's normed input,
+        itself float32 and unrounded (a float32 product at full
         precision: the top-k must not turn on a matmul's rounding)."""
         with jax.named_scope("moe route"):
             return jnp.dot(h.astype(jnp.float32),
@@ -114,10 +169,12 @@ class DecoderBlock(Module):
 
     def _mix(self, params, x, mixer):
         """x + the mixer's sub-layer, what the mixer keeps, and the
-        router's logits (None with no router); `mixer(params, h)` is the
-        layer's prefill or step."""
+        router's logits (None with no router, or one that reads the
+        feed-forward's input); `mixer(params, h)` is the layer's prefill
+        or step."""
         h = x if self.norm_output else self.ln1.apply(params["ln1"], x, None)
-        logits = None if self.experts is None else self._route(params, h)
+        logits = self._route(params, h) \
+            if self.experts is not None and self.route_early else None
         a, kept_a, kept_b = mixer(params["attn"], h)
         if self.norm_output:
             a = self.ln1.apply(params["ln1"], a, None)
@@ -130,9 +187,15 @@ class DecoderBlock(Module):
         u = x if self.norm_output else self.ln2.apply(params["ln2"], x, None)
         u = u.reshape(-1, self.e)
         if self.experts is not None:
+            if logits is None:
+                logits = self._route(params, u)
             y, chosen = self.experts.apply_routed(
                 params["experts"], u,
-                logits.reshape(-1, self.experts.n_experts))
+                logits.reshape(-1, self.experts.n_experts),
+                params.get("router_bias"))
+            if self.shared is not None:
+                with jax.named_scope("shared expert"):
+                    y = y + self.shared.apply(params["shared"], u, None)
         else:
             y, chosen = self.ffn.apply(params["ffn"], u, None), None
         y = y.reshape(shape)
@@ -172,9 +235,11 @@ class DecoderLM(Module):
     `n_head` query heads of `head_dim` over `n_kv_head` K/V heads
     (`qk_norm`: an RMSNorm over the whole q and k projections);
     "experts" layers `n_experts` of `expert_dim` with `top_k` active,
-    "dense" ones `ffn_dim`; "gated_delta" layers `linear_heads` heads of
+    gated and routed as `experts` says, "dense" ones `ffn_dim`;
+    "gated_delta" layers `linear_heads` heads of
     `linear_key_dim` and `linear_value_dim` behind a convolution of
-    `conv_taps`, prefilled in chunks of `chunk`. The residual stream,
+    `conv_taps`, prefilled in chunks of `chunk`; "latent" layers
+    `n_head` heads of the widths `latent` gives. The residual stream,
     the norms, the router, the recurrent state and the log-probs are
     float32 whatever the weights' type; the matmuls take their operands
     in the weights' type and add their float32 accumulators to the
@@ -191,20 +256,31 @@ class DecoderLM(Module):
                  cache_dtype=jnp.float32, name=None, *, ffn_dim: int = 0,
                  qk_norm: bool = False, linear_heads: int = 0,
                  linear_key_dim: int = 0, linear_value_dim: int = 0,
-                 conv_taps: int = 4, chunk: int = 64):
+                 conv_taps: int = 4, chunk: int = 64,
+                 latent: Optional[LatentDims] = None,
+                 experts: ExpertsKind = ExpertsKind()):
         super().__init__(name)
         self.vocab, self.e, self.max_len = vocab_size, embed_dim, max_len
         self.cache_dtype = cache_dtype
         self.n_experts, self.chunk = n_experts, chunk
 
         def block(spec):
-            attn = GroupedQueryAttention(
-                embed_dim, n_head, n_kv_head, head_dim, window=spec.window,
-                rope_base=spec.rope_base, qk_norm=eps if qk_norm else None) \
-                if spec.mixer == "attention" else GatedDeltaRule(
+            if spec.mixer == "attention":
+                attn = GroupedQueryAttention(
+                    embed_dim, n_head, n_kv_head, head_dim,
+                    window=spec.window, rope_base=spec.rope_base,
+                    qk_norm=eps if qk_norm else None)
+            elif spec.mixer == "gated_delta":
+                attn = GatedDeltaRule(
                     embed_dim, linear_heads, linear_key_dim,
                     linear_value_dim, conv_taps, chunk, eps)
-            ffn = RoutedExperts(embed_dim, expert_dim, n_experts, top_k) \
+            else:
+                attn = LatentAttention(
+                    embed_dim, n_head, latent.nope, latent.rope,
+                    latent.value, latent.rank, spec.rope_base, eps)
+            ffn = RoutedExperts(
+                embed_dim, expert_dim, n_experts, top_k, gate=experts.gate,
+                scoring=experts.scoring, scale=experts.scale) \
                 if spec.ffn == "experts" else GatedFFN(embed_dim, ffn_dim)
             return DecoderBlock(embed_dim, spec, attn, ffn, eps)
         self.blocks = [block(spec) for spec in layers]
@@ -213,7 +289,9 @@ class DecoderLM(Module):
         self._routed = [i for i, b in enumerate(self.blocks)
                         if b.experts is not None]
         self._recurrent = [i for i, b in enumerate(self.blocks)
-                           if b.keeps == ("state", "tail")]
+                           if b.keeps == _KEEPS["gated_delta"]]
+        self._latent = [i for i, b in enumerate(self.blocks)
+                        if b.keeps == _KEEPS["latent"]]
         self._windowed = any(b.window is not None for b in self.blocks)
 
     def init(self, rng):
@@ -252,8 +330,10 @@ class DecoderLM(Module):
         names): "k" and "v" `[slots, n_kv_head, depth, head_dim]`, depth
         `window` on window layers and `max_len` on full ones; "state"
         `[slots, heads, key_dim, value_dim]` float32 and "tail"
-        `[slots, taps - 1, channels]` on recurrent ones; and the
-        counters a step and a prefill add to on the device."""
+        `[slots, taps - 1, channels]` on recurrent ones; "latent"
+        `[slots, max_len, rank]` and "k_pe" `[slots, max_len, rope]` on
+        latent ones; and the counters a step and a prefill add to on
+        the device."""
         cache = {name: [None] * len(self.blocks) for name in _KEPT}
         for i, blk in enumerate(self.blocks):
             cache[blk.keeps[0]][i], cache[blk.keeps[1]][i] = \
@@ -269,6 +349,10 @@ class DecoderLM(Module):
             c["recurrent_slot_steps"] = jnp.zeros((), jnp.int32)
             c["recurrent_chunks_scanned"] = jnp.zeros((), jnp.int32)
             c["recurrent_state_absmax"] = jnp.zeros((), jnp.float32)
+        if self._latent:
+            # float32: slots x max_len a step passes int32 in hours
+            c["latent_positions_live"] = jnp.zeros((), jnp.float32)
+            c["latent_positions_read"] = jnp.zeros((), jnp.float32)
         cache["counters"] = c
         return cache
 
@@ -323,6 +407,13 @@ class DecoderLM(Module):
             c["recurrent_slot_steps"] = c["recurrent_slot_steps"] \
                 + jnp.sum(live).astype(jnp.int32)
             c["recurrent_state_absmax"] = absmax
+        if self._latent:
+            depth = cache["latent"][self._latent[0]].shape[1]
+            c["latent_positions_live"] = c["latent_positions_live"] \
+                + jnp.sum(jnp.where(live, positions + 1, 0)).astype(
+                    jnp.float32)
+            c["latent_positions_read"] = c["latent_positions_read"] \
+                + float(positions.shape[0] * depth)
         return self._logp(params, x[:, 0]), {**new, "counters": c}
 
     def apply_prefill(self, params, tokens, cache, slot_ids, lengths):
@@ -384,7 +475,13 @@ class DecoderLM(Module):
         `recurrent_chunks_scanned` chunks of real tokens the prefills
         scanned a layer (a bucket's padded chunks and padding rows not
         counted); `recurrent_state_absmax` the largest |S| of any live
-        slot and layer after the last decode step."""
+        slot and layer after the last decode step. Latent layers:
+        `latent_cache_bytes` what the slots' latents and rotary keys
+        hold (a constant of the cache); over the decode steps, a layer,
+        `latent_positions_live` the live slots' positions + 1 (what a
+        read that follows the lengths would touch) and
+        `latent_positions_read` slots x the depth a step read (the whole
+        of `max_len`: the step has no ladder)."""
         c = jax.device_get(cache["counters"])
         out = {}
         if self._routed:
@@ -412,6 +509,13 @@ class DecoderLM(Module):
                     int(c["recurrent_chunks_scanned"]),
                 "recurrent_state_absmax":
                     float(c["recurrent_state_absmax"])})
+        if self._latent:
+            out.update({
+                "latent_cache_bytes": int(sum(
+                    cache[name][i].nbytes for i in self._latent
+                    for name in _KEEPS["latent"])),
+                "latent_positions_live": float(c["latent_positions_live"]),
+                "latent_positions_read": float(c["latent_positions_read"])})
         return out
 
 

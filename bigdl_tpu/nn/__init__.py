@@ -106,6 +106,7 @@ from bigdl_tpu.nn.attention import (GroupedQueryAttention,
                                     MultiHeadAttention,
                                     ScaledDotProductAttention,
                                     TransformerBlock, rope)
+from bigdl_tpu.nn.latent_attention import LatentAttention
 from bigdl_tpu.nn.linear_attention import GatedDeltaRule
 from bigdl_tpu.nn import initialization
 from bigdl_tpu.nn.initialization import (BilinearFiller, ConstInitMethod,

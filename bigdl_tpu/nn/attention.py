@@ -47,6 +47,15 @@ def rope(x, positions=None, base: float = 10000.0):
     return out.reshape(b, h, t, d).astype(x.dtype)
 
 
+def project_heads(o, wo):
+    """[B, H, T, d] -> [B, T, E]: the heads side by side through the
+    output projection `wo` [H d, E], its float32 accumulator unrounded
+    (for a residual stream kept in float32)."""
+    b, h, t, d = o.shape
+    return jnp.dot(jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, h * d), wo,
+                   preferred_element_type=jnp.float32)
+
+
 # the K/V cache's format has one owner, nn/kv_cache.py; these are its
 # write and commit under the names this module has always exported
 cache_write = kv_cache.write
@@ -259,9 +268,7 @@ class GroupedQueryAttention(Module):
         return q, k, v
 
     def _finish(self, params, o):  # [B, H, T, hd] -> [B, T, E] float32
-        b, h, t, hd = o.shape
-        return jnp.dot(jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, h * hd),
-                       params["wo"], preferred_element_type=jnp.float32)
+        return project_heads(o, params["wo"])
 
     def _scope(self):
         return jax.named_scope("full attention" if self.window is None

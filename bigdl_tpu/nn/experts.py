@@ -1,5 +1,5 @@
 """Dropless routed experts: every token's top-k experts are computed,
-whatever the imbalance; no capacity, no dropped token, no bias.
+whatever the imbalance; no capacity, no dropped token.
 
 `RoutedExperts` is the feed-forward half of a sparse decoder block. The
 router is not in it: the block hands in the router's logits, which it
@@ -30,22 +30,41 @@ from bigdl_tpu.nn.initialization import Xavier
 from bigdl_tpu.nn.module import Module
 
 
-def route(logits, top_k: int):
+def route(logits, top_k: int, scoring: str = "softmax", bias=None,
+          scale: float = 1.0):
     """Top-k of the router's logits [N, E] and their weights, in
-    float32: softmax over all E, top-k, renormalised to sum 1, which is
-    the softmax over the k chosen logits. Returns (experts [N, k] int32,
-    weights [N, k] float32)."""
-    vals, idx = lax.top_k(logits.astype(jnp.float32), top_k)
-    return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+    float32. Returns (experts [N, k] int32, weights [N, k] float32).
+
+    "softmax": softmax over all E, top-k, renormalised to sum 1, which
+    is the softmax over the k chosen logits. "sigmoid": each expert's
+    score p = sigmoid(logit) stands alone; the k experts are CHOSEN by
+    p + `bias` [E] (a buffer that balances the load and weighs nothing)
+    and WEIGHED by p, normalised over the chosen and times `scale`:
+    w_e = scale * p_e / (sum of the chosen p + 1e-20)."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        vals, idx = lax.top_k(logits, top_k)
+        return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+    if scoring != "sigmoid":
+        raise ValueError(f"scoring is 'softmax' or 'sigmoid', got "
+                         f"{scoring!r}")
+    p = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(p if bias is None else p + bias, top_k)
+    w = jnp.take_along_axis(p, idx, axis=-1)
+    w = scale * w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w
 
 
 class RoutedExperts(Module):
     """`n_experts` gated experts of width `d_hidden`:
-    E_e(u) = (relu(u @ wg_e) * (u @ wu_e)) @ wd_e, and the layer's result
-    sum over a token's `top_k` experts of weight x E_e(u).
+    E_e(u) = (act(u @ wg_e) * (u @ wu_e)) @ wd_e, and the layer's result
+    sum over a token's `top_k` experts of weight x E_e(u). `gate` names
+    act: "relu", or "silu" (taken in float32, as `GatedFFN`'s);
+    `scoring` and `scale` are `route`'s.
 
-    `apply_routed(params, x [N, d], logits [N, E])` returns (y [N, d]
-    float32, experts [N, top_k] int32, each token's chosen experts); x
+    `apply_routed(params, x [N, d], logits [N, E], bias=None)` returns
+    (y [N, d] float32, experts [N, top_k] int32, each token's chosen
+    experts); `bias` [E] is the sigmoid router's; x
     is cast to the weights' type for the products, whose last keeps its
     float32 accumulator. More than
     `token_chunk` rows are taken `token_chunk` at a time, which bounds
@@ -53,13 +72,26 @@ class RoutedExperts(Module):
     changes no number."""
 
     def __init__(self, d_model: int, d_hidden: int, n_experts: int,
-                 top_k: int, token_chunk: int = 8192, name=None):
+                 top_k: int, token_chunk: int = 8192, name=None, *,
+                 gate: str = "relu", scoring: str = "softmax",
+                 scale: float = 1.0):
         super().__init__(name)
         if not 1 <= top_k <= n_experts:
             raise ValueError(f"top_k {top_k} of {n_experts} experts")
+        if gate not in ("relu", "silu"):
+            raise ValueError(f"gate is 'relu' or 'silu', got {gate!r}")
         self.d, self.hidden, self.n_experts, self.top_k = \
             d_model, d_hidden, n_experts, top_k
         self.token_chunk = int(token_chunk)
+        self.gate, self.scoring, self.scale = gate, scoring, float(scale)
+
+    def _gated(self, gate, up):
+        """act(gate) * up, the down projection's left operand before its
+        cast."""
+        if self.gate == "relu":
+            return jax.nn.relu(gate) * up
+        return jax.nn.silu(gate.astype(jnp.float32)) \
+            * up.astype(jnp.float32)
 
     def init(self, rng):
         k1, k2, k3 = jax.random.split(rng, 3)
@@ -68,9 +100,9 @@ class RoutedExperts(Module):
         return {"wg": xav(k1, (e, d, h)), "wu": xav(k2, (e, d, h)),
                 "wd": xav(k3, (e, h, d))}
 
-    def _chunk(self, params, x, logits):
+    def _chunk(self, params, x, logits, bias=None):
         n, k = x.shape[0], self.top_k
-        experts, weights = route(logits, k)
+        experts, weights = route(logits, k, self.scoring, bias, self.scale)
         x = x.astype(params["wg"].dtype)
         if self.n_experts <= n * k and n <= self.n_experts:
             return self._few_rows(params, x, experts, weights), experts
@@ -80,7 +112,8 @@ class RoutedExperts(Module):
         rows = x[order // k]                               # [N k, d]
         gate = lax.ragged_dot(rows, params["wg"], sizes)
         up = lax.ragged_dot(rows, params["wu"], sizes)
-        out = lax.ragged_dot(jax.nn.relu(gate) * up, params["wd"], sizes,
+        out = lax.ragged_dot(self._gated(gate, up).astype(x.dtype),
+                             params["wd"], sizes,
                              preferred_element_type=jnp.float32)
         # back to token order; the k weighted results summed in float32
         out = out[jnp.argsort(order)].reshape(n, k, self.d)
@@ -121,7 +154,7 @@ class RoutedExperts(Module):
             chosen = experts[..., None] == jnp.arange(
                 self.n_experts, dtype=experts.dtype)       # [N, k, E]
             mix = jnp.sum(jnp.where(chosen, weights[..., None], 0.0), axis=1)
-            hidden = (jax.nn.relu(gate) * up).astype(jnp.float32) \
+            hidden = self._gated(gate, up).astype(jnp.float32) \
                 * mix[..., None]
             return jnp.einsum("neh,ehd->nd", hidden.astype(x.dtype),
                               params["wd"],
@@ -132,13 +165,13 @@ class RoutedExperts(Module):
         x, logits = list(input)
         return self.apply_routed(params, x, logits)[0]
 
-    def apply_routed(self, params, x, logits):
+    def apply_routed(self, params, x, logits, bias=None):
         with jax.named_scope("moe experts"):
             n, c = x.shape[0], self.token_chunk
             if n <= c or n % c:
-                return self._chunk(params, x, logits)
+                return self._chunk(params, x, logits, bias)
             y, experts = lax.map(
-                lambda a: self._chunk(params, *a),
+                lambda a: self._chunk(params, *a, bias),
                 (x.reshape(n // c, c, -1), logits.reshape(n // c, c, -1)))
             return y.reshape(n, -1), experts.reshape(n, -1)
 

@@ -1,10 +1,10 @@
 """What a layer keeps per serving slot, and how a decode step reads it.
 The one owner of the per-slot state's format (ROADMAP D3): the models
-build their caches from `init` and `init_recurrent`, prefill lands
-through `commit`, a decode step writes K/V through `write` and masks
-through `step_mask`.
+build their caches from `init`, `init_recurrent` and `init_latent`,
+prefill lands through `commit`, a decode step writes what it keeps by
+position through `write` and masks through `step_mask`.
 
-Three kinds of layer. Two keep keys and values, told apart by `window`:
+Four kinds of layer. Two keep keys and values, told apart by `window`:
 
 * full (`window` None): `[slots, kv_heads, max_len, head_dim]`, position
   p at index p.
@@ -33,6 +33,18 @@ step's positions.
   overwrites its slot's state whole, `commit` is idempotent under
   repeated slot ids, and the layer's update never grows a state
   (tests/test_hybrid_decoder.py).
+
+* latent (`init_latent`; nn/latent_attention.py): ONE compressed vector
+  a position for all heads, `[slots, max_len, rank]`, and the one rotary
+  key a position that every head shares, `[slots, max_len, rope_dim]`,
+  both in the cache's type; no axis of heads, and no K or V at all (a
+  decode step reads the latent as it lies). Position p at index p,
+  written by `write`, landed by `commit`, masked by `step_mask` as a
+  full layer's K/V are; a decode step reads the whole depth (no ladder).
+  On the v5e the compiler lays the 64-wide rotary keys out with the
+  positions minor, so their lanes are not padded to 128: the device
+  holds the 576 values a position and layer that the leaves count
+  (PERF.md PR 39: 3.62 GB at 32 slots x 16384 x 6 layers).
 """
 
 from __future__ import annotations
@@ -51,13 +63,16 @@ def depth(max_len: int, window: Optional[int]) -> int:
     return max_len if window is None else min(int(window), max_len)
 
 
+def _at_least_one(**sizes):
+    for name, n in sizes.items():
+        if n < 1:
+            raise ValueError(f"{name} must be >= 1, got {n}")
+
+
 def init(slots: int, kv_heads: int, max_len: int, head_dim: int,
          window: Optional[int] = None, dtype=jnp.float32):
     """One layer's K (or V) buffer, zeroed."""
-    if slots < 1:
-        raise ValueError(f"slots must be >= 1, got {slots}")
-    if max_len < 1:
-        raise ValueError(f"max_len must be >= 1, got {max_len}")
+    _at_least_one(slots=slots, max_len=max_len)
     return jnp.zeros((slots, kv_heads, depth(max_len, window), head_dim),
                      dtype)
 
@@ -67,10 +82,17 @@ def init_recurrent(slots: int, heads: int, key_dim: int, value_dim: int,
     """One recurrent layer's (state, tail), zeroed: the state float32
     whatever the cache's type, the tail in the type of the rows it
     holds."""
-    if slots < 1:
-        raise ValueError(f"slots must be >= 1, got {slots}")
+    _at_least_one(slots=slots)
     return (jnp.zeros((slots, heads, key_dim, value_dim), jnp.float32),
             jnp.zeros((slots, taps - 1, channels), tail_dtype))
+
+
+def init_latent(slots: int, max_len: int, rank: int, rope_dim: int,
+                dtype=jnp.float32):
+    """One latent layer's (latent, rotary keys), zeroed."""
+    _at_least_one(slots=slots, max_len=max_len)
+    return (jnp.zeros((slots, max_len, rank), dtype),
+            jnp.zeros((slots, max_len, rope_dim), dtype))
 
 
 def depth_rungs(max_len: int) -> Tuple[int, ...]:
@@ -99,26 +121,29 @@ def rung_index(rungs: Sequence[int], positions):
 
 
 def write(cache, new, positions, window: Optional[int] = None):
-    """Write `new` [B, H, T, hd] into `cache` [B, H, L, hd] starting at
-    per-row sequence position `positions` [B] — a per-row
-    `lax.dynamic_update_slice`, so under donation the decode step updates
-    its preallocated KV buffers in place (O(1) memory and step cost per
-    token; never a per-token concat/retrace). A window layer's ring
-    takes one position at a time (T = 1), at `position % L`."""
+    """Write `new` [B, H, T, hd] into `cache` [B, H, L, hd] (or a
+    latent layer's `new` [B, T, w] into `cache` [B, L, w]: positions are
+    the axis before the last) starting at per-row sequence position
+    `positions` [B] — a per-row `lax.dynamic_update_slice`, so under
+    donation the decode step updates its preallocated buffers in place
+    (O(1) memory and step cost per token; never a per-token
+    concat/retrace). A window layer's ring takes one position at a time
+    (T = 1), at `position % L`."""
     if window is not None:
         positions = positions % cache.shape[2]
+    lead = (0,) * (cache.ndim - 3)
 
     def one(c, n, p):
-        return lax.dynamic_update_slice(c, n, (0, p, 0))
+        return lax.dynamic_update_slice(c, n, lead + (p, 0))
     return jax.vmap(one)(cache, new, positions)
 
 
 def commit(cache, new, slot_ids, lengths=None,
            window: Optional[int] = None):
     """Commit per-request prefill K/V `new` [B, H, T, hd] into slots of a
-    fleet-wide cache [S, H, L, hd] at sequence position 0, or a
-    recurrent layer's state or tail `new` [B, ...] into `cache`
-    [S, ...] whole. Rows may repeat (bucket padding replicates the last
+    fleet-wide cache [S, H, L, hd] at sequence position 0 (a latent
+    layer's `new` [B, T, w] into [S, L, w] likewise), or a recurrent
+    layer's state or tail `new` [B, ...] into `cache` [S, ...] whole. Rows may repeat (bucket padding replicates the last
     request's row INCLUDING its slot id): the scan writes in request
     order, so a padded duplicate rewrites identical values and the last
     write wins.

@@ -303,7 +303,13 @@ RECORD_SCHEMAS: Dict[str, Dict] = {
                      "recurrent_state_bytes": int,
                      "recurrent_slot_steps": int,
                      "recurrent_chunks_scanned": int,
-                     "recurrent_state_absmax": _NUM},
+                     "recurrent_state_absmax": _NUM,
+                     # latent layers: bytes of the slots' latents and
+                     # rotary keys; over the decode steps, the live
+                     # slots' positions + 1 and slots x the depth read
+                     "latent_cache_bytes": int,
+                     "latent_positions_live": _NUM,
+                     "latent_positions_read": _NUM},
     },
     # fleet-level counters/gauges (serving/fleet.py), one per
     # membership change or maintain() tick; PrometheusTextSink renders

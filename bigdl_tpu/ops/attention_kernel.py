@@ -239,15 +239,19 @@ def flash_attention_forward(q, k, v, causal: bool = False,
     `return_lse=True` also returns the [B,H,T] logsumexp (backward input).
 
     `k`, `v` may hold fewer heads than `q` ([B, Hkv, T, D], H a multiple
-    of Hkv: query head j reads K/V head j // (H // Hkv)), and `window`
-    keeps, for the query at position p, the keys at p - window + 1 .. p.
-    Either one takes the grouped kernel below (causal, no logsumexp: the
-    serving prefill's); with neither this is the call it always was."""
+    of Hkv: query head j reads K/V head j // (H // Hkv)), `v` may have a
+    width of its own ([B, Hkv, T, Dv]: latent attention's values are
+    narrower than its keys; the result is then Dv wide and the scale
+    still the query's width's), and `window` keeps, for the query at
+    position p, the keys at p - window + 1 .. p. Any of the three takes
+    the grouped kernel below (causal, no logsumexp: the serving
+    prefill's); with none this is the call it always was."""
     from jax.experimental import pallas as pl
 
     if interpret is None:
         interpret = INTERPRET
-    if window is not None or k.shape[1] != q.shape[1]:
+    if window is not None or k.shape[1] != q.shape[1] \
+            or v.shape[-1] != q.shape[-1]:
         if not causal or return_lse:
             raise ValueError("the windowed / grouped-query forward is "
                              "causal and returns no logsumexp")
@@ -593,7 +597,8 @@ def _flash_fwd_grouped_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
                               sm_scale: float, window: Optional[int]):
     """One program = one (batch * K/V head, q block, K/V step). The q
     block holds the `group` query heads that share the K/V head, folded
-    into its rows, so a K/V block is read once for all of them; K/V
+    into its rows, so a K/V block is read once for all of them; `v`, the
+    accumulator and the result are `v`'s own width; K/V
     blocks come through the grid (never a whole head in VMEM) and the
     online-softmax state lives in scratch across the K/V steps. A step
     past the q block's last needed K/V block computes nothing, and its
@@ -601,6 +606,7 @@ def _flash_fwd_grouped_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
     from jax.experimental import pallas as pl
 
     group, _, d = q_ref.shape[1:]
+    d_v = v_ref.shape[-1]
     rows = group * block_q
     j, step = pl.program_id(1), pl.program_id(2)
     first, last = _grouped_kv_range(j, block_q, block_k, window)
@@ -641,7 +647,7 @@ def _flash_fwd_grouped_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
     @pl.when(step == pl.num_programs(2) - 1)
     def _():
         o_ref[0] = (acc_ref[...] / l_ref[...]).reshape(
-            group, block_q, d).astype(o_ref.dtype)
+            group, block_q, d_v).astype(o_ref.dtype)
 
 
 def _flash_forward_grouped(q, k, v, sm_scale, block_q, block_k, interpret,
@@ -650,8 +656,8 @@ def _flash_forward_grouped(q, k, v, sm_scale, block_q, block_k, interpret,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, t, d = q.shape
-    hk = k.shape[1]
-    if h % hk or k.shape != v.shape or k.shape[2] != t:
+    hk, d_v = k.shape[1], v.shape[-1]
+    if h % hk or k.shape != (b, hk, t, d) or v.shape != (b, hk, t, d_v):
         raise ValueError(f"q {q.shape} against k {k.shape}, v {v.shape}")
     group = h // hk
     sm_scale = sm_scale or d ** -0.5
@@ -679,12 +685,12 @@ def _flash_forward_grouped(q, k, v, sm_scale, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((1, group, block_q, d), lambda i, j, s: (i, 0, j, 0)),
             pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d_v), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, group, block_q, d),
+        out_specs=pl.BlockSpec((1, group, block_q, d_v),
                                lambda i, j, s: (i, 0, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * hk, group, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b * hk, group, t, d_v), q.dtype),
+        scratch_shapes=[pltpu.VMEM((rows, d_v), jnp.float32),
                         pltpu.VMEM((rows, 1), jnp.float32),
                         pltpu.VMEM((rows, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -692,15 +698,16 @@ def _flash_forward_grouped(q, k, v, sm_scale, block_q, block_k, interpret,
         interpret=interpret,
         name="flash_fwd_gqa" if window is None else "flash_fwd_window",
     )(q.reshape(b * hk, group, t, d), k.reshape(b * hk, t, d),
-      v.reshape(b * hk, t, d))
-    return out.reshape(b, h, t, d)
+      v.reshape(b * hk, t, d_v))
+    return out.reshape(b, h, t, d_v)
 
 
 def grouped_attention(q, k, v, keep):
-    """Plain-XLA attention of q [B, H, Tq, D] over k, v [B, Hkv, Tk, D]
-    (query head j reads K/V head j // (H // Hkv), so K/V are read once a
-    K/V head) under the mask `keep`, broadcastable to
-    [B, Hkv, H // Hkv, Tq, Tk]; scores and softmax in float32."""
+    """Plain-XLA attention of q [B, H, Tq, D] over k [B, Hkv, Tk, D] and
+    v [B, Hkv, Tk, Dv] (query head j reads K/V head j // (H // Hkv), so
+    K/V are read once a K/V head) under the mask `keep`, broadcastable
+    to [B, Hkv, H // Hkv, Tq, Tk]; scores and softmax in float32; the
+    result [B, H, Tq, Dv]."""
     b, h, tq, d = q.shape
     hk = k.shape[1]
     s = jnp.einsum("bkgqd,bktd->bkgqt", q.reshape(b, hk, h // hk, tq, d), k,
@@ -708,12 +715,13 @@ def grouped_attention(q, k, v, keep):
     p = jax.nn.softmax(jnp.where(keep, s, NEG_INF), axis=-1)
     o = jnp.einsum("bkgqt,bktd->bkgqd", p.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)
-    return o.astype(q.dtype).reshape(b, h, tq, d)
+    return o.astype(q.dtype).reshape(b, h, tq, v.shape[-1])
 
 
 def causal_grouped_attention(q, k, v, window: Optional[int] = None):
-    """Inference-only causal self-attention, q [B, H, T, D] over k, v
-    [B, Hkv, T, D], the last `window` keys a query (all with None): the
+    """Inference-only causal self-attention, q [B, H, T, D] over k
+    [B, Hkv, T, D] and v [B, Hkv, T, Dv], the last `window` keys a query
+    (all with None): the
     grouped flash kernel on a TPU where T fills its 128-row blocks,
     else plain XLA with the mask written out (tests, tiny shapes)."""
     use_pallas = jax.default_backend() == "tpu" or INTERPRET

@@ -207,6 +207,9 @@ SPECS = {
     "GatedDeltaRule": (
         lambda: nn.GatedDeltaRule(8, 2, 4, 4, chunk=4),
         np.ones((2, 5, 8), np.float32)),
+    "LatentAttention": (
+        lambda: nn.LatentAttention(8, 2, 4, 2, 4, 6, rope_base=1e4),
+        np.ones((2, 5, 8), np.float32)),
     "ScaledDotProductAttention": (
         lambda: nn.ScaledDotProductAttention(), Table(
             np.ones((2, 2, 5, 4), np.float32), np.ones((2, 2, 5, 4), np.float32),
