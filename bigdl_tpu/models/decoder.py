@@ -48,6 +48,7 @@ from bigdl_tpu.nn.latent_attention import LatentAttention
 from bigdl_tpu.nn.linear_attention import GatedDeltaRule
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.nn.normalization import RMSNorm
+from bigdl_tpu.ops import latent_decode_kernel
 
 #: what a layer keeps a serving slot, by its mixer and by the cache's
 #: names: an attention layer "k" and "v", a recurrent one "state" and
@@ -413,7 +414,7 @@ class DecoderLM(Module):
                 + jnp.sum(jnp.where(live, positions + 1, 0)).astype(
                     jnp.float32)
             c["latent_positions_read"] = c["latent_positions_read"] \
-                + float(positions.shape[0] * depth)
+                + latent_decode_kernel.positions_read(positions, depth)
         return self._logp(params, x[:, 0]), {**new, "counters": c}
 
     def apply_prefill(self, params, tokens, cache, slot_ids, lengths):
@@ -480,8 +481,9 @@ class DecoderLM(Module):
         hold (a constant of the cache); over the decode steps, a layer,
         `latent_positions_live` the live slots' positions + 1 (what a
         read that follows the lengths would touch) and
-        `latent_positions_read` slots x the depth a step read (the whole
-        of `max_len`: the step has no ladder)."""
+        `latent_positions_read` the positions a step read (each slot's
+        live blocks, whole, on the `mla_decode` kernel's path; slots x
+        `max_len` on the plain-XLA one)."""
         c = jax.device_get(cache["counters"])
         out = {}
         if self._routed:

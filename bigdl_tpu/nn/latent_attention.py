@@ -25,10 +25,14 @@ step ABSORBS `wuk` into the query and `wuv` into the result,
     s_j[t'] = (q_lat_j . c[t'] + q_pe_j . k_pe[t']) / sqrt(...)
     o_j = (softmax(s_j) @ c) @ wuv[:, j]
 
-so that it reads the cached latent once for all heads and never
+so that it reads the cached latent for all heads at once and never
 rebuilds a key or a value (at 32 slots x 16384 positions that would be
 terabytes of products a layer); the absorbed form costs 2 x (2 rank +
-rope) a pair and head, which is why a prefill does not take it.
+rope) a pair and head, which is why a prefill does not take it. On a
+TPU the step's read is the `mla_decode` kernel (ops/
+latent_decode_kernel.py): each position's latent and rotary key once
+for the scores and the values, and only each slot's live blocks;
+elsewhere it is `attend_absorbed`, plain XLA over the whole depth.
 
 Input [B, T, E] in any float type: cast to the weights' type for the
 projections; the latent's RMSNorm, the rotary angles, the scores, the
@@ -40,6 +44,8 @@ cache of a layer is the pair `init_cache` gives (nn/kv_cache.py:
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -48,16 +54,18 @@ from bigdl_tpu.nn.attention import project_heads, rope
 from bigdl_tpu.nn.initialization import Xavier
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.nn.normalization import rms_norm
+from bigdl_tpu.ops import latent_decode_kernel
 from bigdl_tpu.ops.attention_kernel import NEG_INF, causal_grouped_attention
 
 
 class LatentAttention(Module):
-    """Causal self-attention over a shared latent of `rank` (see the
-    module's text). Weights: `wq` [E, H (nope + rope)], `wkva`
-    [E, rank + rope], `kv_norm` [rank] float32, `wuk` [rank, H nope],
-    `wuv` [rank, H value] (the published `kv_b_proj`, its key and value
-    columns apart so that a decode step slices nothing), `wo`
-    [H value, E]; no bias."""
+    """Causal self-attention over a shared latent of `rank`: prefill
+    expanded, decode absorbed (the `mla_decode` kernel on a TPU, plain
+    XLA elsewhere; see the module's text). Weights: `wq` [E, H (nope +
+    rope)], `wkva` [E, rank + rope], `kv_norm` [rank] float32, `wuk`
+    [rank, H nope], `wuv` [rank, H value] (the published `kv_b_proj`,
+    its key and value columns apart so that a decode step slices
+    nothing), `wo` [H value, E]; no bias."""
 
     def __init__(self, embed_dim: int, n_head: int, nope_dim: int,
                  rope_dim: int, value_dim: int, rank: int,
@@ -129,12 +137,31 @@ class LatentAttention(Module):
         return kv_cache.init_latent(slots, max_len, self.rank, self.rope,
                                     dtype)
 
+    def attend_absorbed(self, q_lat, q_pe, c_cache, pe_cache, positions):
+        """The plain-XLA read of the absorbed form: `q_lat` [B, H, rank]
+        and `q_pe` [B, H, rope] in the weights' type score the whole
+        depth of `c_cache` [B, L, rank] and `pe_cache` [B, L, rope], and
+        the probabilities, cast to the weights' type, read `c_cache`
+        again. Returns o_lat [B, H, rank] float32. What `mla_decode`
+        computes in one pass over each slot's live blocks."""
+        s = jnp.einsum("bhr,blr->bhl", q_lat, c_cache,
+                       preferred_element_type=jnp.float32) \
+            + jnp.einsum("bhp,blp->bhl", q_pe, pe_cache,
+                         preferred_element_type=jnp.float32)
+        keep = kv_cache.step_mask(c_cache.shape[1], positions)[:, 0]
+        p = jax.nn.softmax(jnp.where(keep, s * self.sm_scale, NEG_INF),
+                           axis=-1)
+        return jnp.einsum("bhl,blr->bhr", p.astype(q_lat.dtype), c_cache,
+                          preferred_element_type=jnp.float32)
+
     def apply_step(self, params, x, c_cache, pe_cache, positions):
         """One new token a row in the absorbed form: `x` [B, 1, E] at
         `positions` [B] against the layer's latent cache [B, L, rank]
         and rotary keys [B, L, rope]. Writes the token's c and k_pe,
-        then all heads read the latent once for the scores and once for
-        the values. Returns (out [B, 1, E], c_cache, pe_cache)."""
+        then all heads read the latent: through `mla_decode`, once and
+        only to each slot's length, where `latent_decode_kernel.block_for`
+        gives a block; else `attend_absorbed`. Returns (out [B, 1, E],
+        c_cache, pe_cache)."""
         with jax.named_scope("latent attention"):
             q_nope, q_pe, c, k_pe = self._project(
                 params, x, positions=positions[:, None])
@@ -148,15 +175,12 @@ class LatentAttention(Module):
                 q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, :, 0], wuk,
                                    preferred_element_type=jnp.float32)
             with jax.named_scope("mla attend"):
-                s = jnp.einsum("bhr,blr->bhl", q_lat.astype(dtype), c_cache,
-                               preferred_element_type=jnp.float32) \
-                    + jnp.einsum("bhp,blp->bhl", q_pe[:, :, 0], pe_cache,
-                                 preferred_element_type=jnp.float32)
-                keep = kv_cache.step_mask(c_cache.shape[1], positions)[:, 0]
-                p = jax.nn.softmax(
-                    jnp.where(keep, s * self.sm_scale, NEG_INF), axis=-1)
-                o_lat = jnp.einsum("bhl,blr->bhr", p.astype(dtype), c_cache,
-                                   preferred_element_type=jnp.float32)
+                block = latent_decode_kernel.block_for(c_cache.shape[1])
+                attend = self.attend_absorbed if block is None else \
+                    functools.partial(latent_decode_kernel.mla_decode,
+                                      sm_scale=self.sm_scale, block=block)
+                o_lat = attend(q_lat.astype(dtype), q_pe[:, :, 0], c_cache,
+                               pe_cache, positions)
             with jax.named_scope("mla absorb"):
                 wuv = params["wuv"].reshape(self.rank, self.h, self.dv)
                 o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(dtype), wuv,

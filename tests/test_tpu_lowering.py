@@ -283,6 +283,24 @@ class TestMosaicCompiles:
                     argnums=(0, 1, 2)), q, q, q).compile()
 
 
+    def test_latent_decode_at_the_cells_shapes(self):
+        # 32 slots x 16384 of a 512-wide latent and 64 rotary keys, 32
+        # heads, blocks of 512: two blocks of each in VMEM (at the
+        # serving cells' precision: under "highest" Mosaic refuses the
+        # bf16 operands of a multi-pass dot, "Bad lhs type")
+        from bigdl_tpu.ops.latent_decode_kernel import mla_decode
+        on = _v5e_device()
+        with jax.default_matmul_precision("bfloat16"):
+            lower_for_tpu(
+                lambda q, qpe, c, pe, pos: mla_decode(
+                    q, qpe, c, pe, pos, 192 ** -0.5, 512, interpret=False),
+                struct((32, 32, 512), jnp.bfloat16, on),
+                struct((32, 32, 64), jnp.bfloat16, on),
+                struct((32, 16384, 512), jnp.bfloat16, on),
+                struct((32, 16384, 64), jnp.bfloat16, on),
+                struct((32,), jnp.int32, on)).compile()
+
+
 # ---------------------------------------------------------------------- #
 # the serving engine's decode program at the serving cell's shapes
 # ---------------------------------------------------------------------- #
@@ -488,3 +506,112 @@ class TestHybridDecodeProgramCompiles:
         assert entry.count("linear attention/gdn step/") >= 12
         assert entry.count("linear attention/gdn conv/") >= 12
         assert "full attention/" in entry and "dense ffn/" in entry
+
+
+# ---------------------------------------------------------------------- #
+# latent attention's decode step: one `mla_decode` kernel a layer
+# ---------------------------------------------------------------------- #
+
+def _tiny_decode_step(model, slots, max_len):
+    """`model.apply_step` lowered for a TPU from shapes alone."""
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(slots, max_len))
+    ids = struct((slots,), jnp.int32)
+    return lower_for_tpu(model.apply_step, params, ids, cache, ids)
+
+
+class TestLatentDecodeLowers:
+    """The tiny decoders of the three kinds that take `DecoderLM`'s decode
+    step (tiny-latent's widths for the latent one) under the TPU's
+    routing: a latent layer's step is ONE Mosaic call, `mla_decode`; the
+    sparse and hybrid steps hold none, as before the kernel."""
+
+    def test_one_kernel_a_latent_layer(self, tpu_routing):
+        from bigdl_tpu.models.decoder import (DecoderLM, ExpertsKind,
+                                              LatentDims, LayerSpec)
+        layers = [LayerSpec(mixer="latent", rope_base=1e6, ffn="dense")] \
+            + [LayerSpec(mixer="latent", rope_base=1e6, shared=64,
+                         router_reads="ffn")] * 5
+        model = DecoderLM(128, 64, 4, 4, 24, layers, n_experts=16,
+                          expert_dim=32, top_k=3, ffn_dim=128,
+                          latent=LatentDims(16, 8, 16, 32),
+                          experts=ExpertsKind("silu", "sigmoid", 2.448))
+        lowered = _tiny_decode_step(model, 4, 256)
+        assert n_mosaic(lowered) == 6
+        assert lowered.as_text(debug_info=True).count('"mla_decode"') >= 1
+        # a cache the blocks do not divide keeps the plain-XLA form
+        assert n_mosaic(_tiny_decode_step(model, 4, 200)) == 0
+
+    @pytest.mark.parametrize("kind", ["sparse", "hybrid"])
+    def test_no_kernel_in_the_other_decode_steps(self, tpu_routing, kind):
+        from bigdl_tpu.models.decoder import DecoderLM, LayerSpec
+        if kind == "sparse":
+            layers = [LayerSpec(window=64 if i % 4 else None,
+                                rope_base=1e6 if i % 4 else None)
+                      for i in range(4)]
+            model = DecoderLM(128, 64, 4, 2, 16, layers, n_experts=8,
+                              expert_dim=32, top_k=2)
+        else:
+            layers = [LayerSpec(mixer="attention" if i % 4 == 3
+                                else "gated_delta", ffn="dense",
+                                norm="output") for i in range(4)]
+            model = DecoderLM(128, 64, 4, 4, 16, layers, ffn_dim=128,
+                              qk_norm=True, linear_heads=4,
+                              linear_key_dim=8, linear_value_dim=16)
+        assert n_mosaic(_tiny_decode_step(model, 4, 256)) == 0
+
+
+class TestLatentDecodeProgramCompiles:
+    """`GenerationEngine`'s `jit__decode_fn` over `DecoderLM` at the
+    shapes of `kanana-2-30b-a3b.serve-docs` (32 slots x 16384, hidden
+    2048, six latent layers of 32 heads over a 512-wide latent and a
+    64-wide rotary key, 128 experts of 768 with 6 active beside a shared
+    one, vocabulary 128256, bf16 weights and latent cache, float32
+    norms and router), compiled by libtpu for a v5e that is not attached
+    under the TPU's routing. Nothing runs, so nothing here is a
+    timing."""
+
+    def test_one_mla_decode_a_layer_and_no_copy_of_a_cache(self,
+                                                           tpu_routing):
+        import re
+        from bigdl_tpu.models.decoder import (DecoderLM, ExpertsKind,
+                                              LatentDims, LayerSpec)
+        on = _v5e_device()
+        slots, max_len, n_layer = 32, 16384, 6
+        layers = [LayerSpec(mixer="latent", rope_base=1e6, ffn="dense")] \
+            + [LayerSpec(mixer="latent", rope_base=1e6, shared=1536,
+                         router_reads="ffn")] * (n_layer - 1)
+        model = DecoderLM(128256, 2048, 32, 32, 192, layers,
+                          n_experts=128, expert_dim=768, top_k=6,
+                          cache_dtype=jnp.bfloat16, ffn_dim=6144,
+                          latent=LatentDims(128, 64, 128, 512),
+                          experts=ExpertsKind("silu", "sigmoid", 2.448))
+        f32 = {"router", "router_bias", "ln1", "ln2", "norm", "kv_norm"}
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, a: struct(
+                a.shape, jnp.float32 if f32
+                & {getattr(k, "key", None) for k in path} else jnp.bfloat16,
+                on),
+            jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+        cache = jax.tree_util.tree_map(
+            lambda a: struct(a.shape, a.dtype, on),
+            jax.eval_shape(lambda: model.init_cache(slots, max_len)))
+        compiled = _compile_decode_program(model, params, cache, slots, on)
+        hlo = compiled.as_text()
+        entry = hlo[hlo.index("ENTRY"):]
+        calls = re.findall(r"^\s*%mla_decode[\w.]* = \S+ custom-call\(",
+                           entry, re.M)
+        assert len(calls) == n_layer == hlo.count("tpu_custom_call")
+        # the absorbed XLA form's float32 scores [slots, heads, depth]
+        # are gone, and no whole latent or rotary-key buffer is copied
+        # or relaid out around the calls: the keys stay positions-minor,
+        # which the kernel's [slots, rope, depth] view takes as a bitcast
+        assert "f32[32,32,16384]" not in entry
+        moved = [line for line in entry.splitlines()
+                 if re.search(r" (copy|copy-start|transpose)\(", line)
+                 and re.match(r"bf16\[32,(16384|64),(512|64|16384)\]",
+                              line.split(" = ", 1)[-1].lstrip())]
+        assert not moved, moved
+        m = compiled.memory_analysis()
+        assert m.alias_size_in_bytes >= 3_600_000_000     # the donated cache
+        assert m.temp_size_in_bytes < 100_000_000
