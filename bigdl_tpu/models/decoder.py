@@ -173,19 +173,24 @@ class DecoderBlock(Module):
         router's logits (None with no router, or one that reads the
         feed-forward's input); `mixer(params, h)` is the layer's prefill
         or step."""
-        h = x if self.norm_output else self.ln1.apply(params["ln1"], x, None)
+        h = x if self.norm_output else self._norm("ln1", params, x)
         logits = self._route(params, h) \
             if self.experts is not None and self.route_early else None
         a, kept_a, kept_b = mixer(params["attn"], h)
         if self.norm_output:
-            a = self.ln1.apply(params["ln1"], a, None)
+            a = self._norm("ln1", params, a)
         return x + a, kept_a, kept_b, logits
+
+    def _norm(self, which, params, x):
+        """The RMSNorm `which` ("ln1" or "ln2") of `x`."""
+        with jax.named_scope("norm"):
+            return getattr(self, which).apply(params[which], x, None)
 
     def _feed(self, params, x, logits):
         """x + the feed-forward's sub-layer, and each token's experts
         [..., top_k] (None for a dense one)."""
         shape = x.shape
-        u = x if self.norm_output else self.ln2.apply(params["ln2"], x, None)
+        u = x if self.norm_output else self._norm("ln2", params, x)
         u = u.reshape(-1, self.e)
         if self.experts is not None:
             if logits is None:
@@ -201,7 +206,7 @@ class DecoderBlock(Module):
             y, chosen = self.ffn.apply(params["ffn"], u, None), None
         y = y.reshape(shape)
         if self.norm_output:
-            y = self.ln2.apply(params["ln2"], y, None)
+            y = self._norm("ln2", params, y)
         x = x + y
         if chosen is not None:
             chosen = chosen.reshape(*shape[:-1], -1)
@@ -307,14 +312,17 @@ class DecoderLM(Module):
     @staticmethod
     def _embed(params, tokens):
         """1-based ids -> the float32 residual stream's first value."""
-        return params["embed"][tokens.astype(jnp.int32) - 1].astype(
-            jnp.float32)
+        with jax.named_scope("embed"):
+            return params["embed"][tokens.astype(jnp.int32) - 1].astype(
+                jnp.float32)
 
     def _logp(self, params, x):
-        h = self.norm.apply(params["norm"], x, None)
-        logits = jnp.dot(h.astype(params["head"].dtype), params["head"],
-                         preferred_element_type=jnp.float32)
-        return jax.nn.log_softmax(logits, axis=-1)
+        """The final norm, the projection to the vocabulary, log-probs."""
+        with jax.named_scope("head"):
+            h = self.norm.apply(params["norm"], x, None)
+            logits = jnp.dot(h.astype(params["head"].dtype), params["head"],
+                             preferred_element_type=jnp.float32)
+            return jax.nn.log_softmax(logits, axis=-1)
 
     def apply(self, params, input, ctx):
         if self.max_len is not None and input.shape[1] > self.max_len:
@@ -384,37 +392,43 @@ class DecoderLM(Module):
             a, b = blk.keeps
             x, new[a][i], new[b][i], chosen = blk.apply_step(
                 params[f"block{i}"], x, cache[a][i], cache[b][i], positions)
-            if chosen is not None:
-                loads.append(self._load(chosen[:, 0], live))
-                touched.append(jnp.sum(self._load(chosen[:, 0], everyone)
-                                       > 0))
-            if blk.window is not None:
-                skipped += kv_cache.positions_skipped(
-                    jnp.where(live, positions, 0),
-                    blk.window).astype(jnp.float32)
+            with jax.named_scope("counters"):
+                if chosen is not None:
+                    loads.append(self._load(chosen[:, 0], live))
+                    touched.append(jnp.sum(
+                        self._load(chosen[:, 0], everyone) > 0))
+                if blk.window is not None:
+                    skipped += kv_cache.positions_skipped(
+                        jnp.where(live, positions, 0),
+                        blk.window).astype(jnp.float32)
             if a == "state":
-                absmax = jnp.maximum(absmax, jnp.max(jnp.where(
-                    live, jnp.max(jnp.abs(new[a][i]), axis=(1, 2, 3)), 0.0)))
-        c = dict(cache["counters"])
-        if self._routed:
-            c["moe_expert_load"] = c["moe_expert_load"] + jnp.stack(loads)
-            c["moe_experts_touched"] = c["moe_experts_touched"] \
-                + jnp.stack(touched).astype(jnp.int32)
-        c["decode_steps"] = c["decode_steps"] + 1
-        if self._windowed:
-            c["window_positions_skipped"] = c["window_positions_skipped"] \
-                + skipped
-        if self._recurrent:
-            c["recurrent_slot_steps"] = c["recurrent_slot_steps"] \
-                + jnp.sum(live).astype(jnp.int32)
-            c["recurrent_state_absmax"] = absmax
-        if self._latent:
-            depth = cache["latent"][self._latent[0]].shape[1]
-            c["latent_positions_live"] = c["latent_positions_live"] \
-                + jnp.sum(jnp.where(live, positions + 1, 0)).astype(
-                    jnp.float32)
-            c["latent_positions_read"] = c["latent_positions_read"] \
-                + latent_decode_kernel.positions_read(positions, depth)
+                # reduced in the fusion that writes the new state, which
+                # takes this path: named for the layer whose state it is
+                with jax.named_scope("linear attention"):
+                    absmax = jnp.maximum(absmax, jnp.max(jnp.where(
+                        live, jnp.max(jnp.abs(new[a][i]), axis=(1, 2, 3)),
+                        0.0)))
+        with jax.named_scope("counters"):
+            c = dict(cache["counters"])
+            if self._routed:
+                c["moe_expert_load"] = c["moe_expert_load"] + jnp.stack(loads)
+                c["moe_experts_touched"] = c["moe_experts_touched"] \
+                    + jnp.stack(touched).astype(jnp.int32)
+            c["decode_steps"] = c["decode_steps"] + 1
+            if self._windowed:
+                c["window_positions_skipped"] = \
+                    c["window_positions_skipped"] + skipped
+            if self._recurrent:
+                c["recurrent_slot_steps"] = c["recurrent_slot_steps"] \
+                    + jnp.sum(live).astype(jnp.int32)
+                c["recurrent_state_absmax"] = absmax
+            if self._latent:
+                depth = cache["latent"][self._latent[0]].shape[1]
+                c["latent_positions_live"] = c["latent_positions_live"] \
+                    + jnp.sum(jnp.where(live, positions + 1, 0)).astype(
+                        jnp.float32)
+                c["latent_positions_read"] = c["latent_positions_read"] \
+                    + latent_decode_kernel.positions_read(positions, depth)
         return self._logp(params, x[:, 0]), {**new, "counters": c}
 
     def apply_prefill(self, params, tokens, cache, slot_ids, lengths):
@@ -449,14 +463,19 @@ class DecoderLM(Module):
                     jax.lax.optimization_barrier(
                         (x, new["state"][i], new["tail"][i]))
             if chosen is not None:
-                loads.append(self._load(chosen, valid))
-        c = dict(cache["counters"])
-        if self._routed:
-            c["moe_expert_load"] = c["moe_expert_load"] + jnp.stack(loads)
-        if self._recurrent:
-            c["recurrent_chunks_scanned"] = c["recurrent_chunks_scanned"] \
-                + jnp.sum(jnp.where(first, -(-lengths // self.chunk), 0))
-        last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
+                with jax.named_scope("counters"):
+                    loads.append(self._load(chosen, valid))
+        with jax.named_scope("counters"):
+            c = dict(cache["counters"])
+            if self._routed:
+                c["moe_expert_load"] = c["moe_expert_load"] + jnp.stack(loads)
+            if self._recurrent:
+                c["recurrent_chunks_scanned"] = \
+                    c["recurrent_chunks_scanned"] + jnp.sum(
+                        jnp.where(first, -(-lengths // self.chunk), 0))
+        with jax.named_scope("head"):
+            last = jnp.take_along_axis(x, (lengths - 1)[:, None, None],
+                                       axis=1)
         return self._logp(params, last[:, 0]), {**new, "counters": c}
 
     def cache_stats(self, cache):

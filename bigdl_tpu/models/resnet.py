@@ -126,12 +126,14 @@ def ResNet(class_num: int = 1000, depth: int = 50, shortcut_type: str = "B",
                                            name="conv1")
             if s2d_stem else _conv(3, 64, 7, 2, 3, name="conv1"))
     model = (nn.Sequential(name=f"ResNet{depth}")
+             .scope("stem")
              .add(stem)
              .add(_bn(64))
              .add(nn.ReLU())
              .add(nn.SpatialMaxPooling(3, 3, 2, 2, pad_w=1, pad_h=1)))
     n_in = 64
     for stage, (w, r) in enumerate(zip(widths, reps)):
+        model.scope(f"stage {stage + 1}")
         for i in range(r):
             stride = 2 if (stage > 0 and i == 0) else 1
             if kind == "bottleneck":
@@ -143,6 +145,7 @@ def ResNet(class_num: int = 1000, depth: int = 50, shortcut_type: str = "B",
                                     zero_gamma)
                 n_in = w
             model.add(nn.Remat(block) if remat else block)
+    model.scope("classifier")
     model.add(nn.Pooler())  # global average pool -> [B, C]
     model.add(nn.Linear(n_in, class_num, name="fc"))
     model.add(nn.LogSoftMax())

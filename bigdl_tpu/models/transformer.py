@@ -58,11 +58,20 @@ class TransformerLM(Module):
                 f"sequence length {input.shape[1]} exceeds max_len "
                 f"{self.max_len}")
         # 1-based token ids (reference label convention)
-        x = params["embed"][input.astype(jnp.int32) - 1]
+        x = self._embed(params, input)
         for i, blk in enumerate(self.blocks):
             x = blk.apply(params[f"block{i}"], x, ctx)
-        logits = x @ params["head"]
-        return jax.nn.log_softmax(logits, axis=-1)
+        return self._logp(params, x)
+
+    @staticmethod
+    def _embed(params, tokens):
+        with jax.named_scope("embed"):
+            return params["embed"][tokens.astype(jnp.int32) - 1]
+
+    @staticmethod
+    def _logp(params, x):
+        with jax.named_scope("head"):
+            return jax.nn.log_softmax(x @ params["head"], axis=-1)
 
     # ------------------------------------------------- incremental decoding
     # The O(1) autoregressive serving path (serving/generation.py): a
@@ -98,7 +107,7 @@ class TransformerLM(Module):
         the causal mask follows each slot's own position). Writes each
         token's K/V at its position and returns ([S, vocab] next-token
         log-probs, updated cache)."""
-        x = params["embed"][tokens.astype(jnp.int32) - 1][:, None, :]
+        x = self._embed(params, tokens)[:, None, :]
         ks, vs = [], []
         for i, blk in enumerate(self.blocks):
             x, k_c, v_c = blk.apply_step(params[f"block{i}"], x,
@@ -106,8 +115,7 @@ class TransformerLM(Module):
                                          positions)
             ks.append(k_c)
             vs.append(v_c)
-        logits = x[:, 0] @ params["head"]
-        return jax.nn.log_softmax(logits, axis=-1), {"k": ks, "v": vs}
+        return self._logp(params, x[:, 0]), {"k": ks, "v": vs}
 
     def apply_prefill(self, params, tokens, cache, slot_ids, lengths):
         """Prefill a batch of prompts into cache slots: `tokens` [B, T]
@@ -119,14 +127,14 @@ class TransformerLM(Module):
         ([B, vocab] log-probs at each prompt's LAST real token — the
         first generated token's distribution — and the updated cache)."""
         from bigdl_tpu.nn.attention import cache_commit
-        x = params["embed"][tokens.astype(jnp.int32) - 1]
+        x = self._embed(params, tokens)
         ks, vs = [], []
         for i, blk in enumerate(self.blocks):
             x, k, v = blk.apply_prefill(params[f"block{i}"], x)
             ks.append(cache_commit(cache["k"][i], k, slot_ids))
             vs.append(cache_commit(cache["v"][i], v, slot_ids))
-        logits = x @ params["head"]
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        last = jnp.take_along_axis(
-            logp, (lengths.astype(jnp.int32) - 1)[:, None, None], axis=1)
+        logp = self._logp(params, x)
+        with jax.named_scope("head"):
+            last = jnp.take_along_axis(
+                logp, (lengths.astype(jnp.int32) - 1)[:, None, None], axis=1)
         return last[:, 0], {"k": ks, "v": vs}

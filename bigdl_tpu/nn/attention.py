@@ -161,8 +161,17 @@ class MultiHeadAttention(Module):
             xq, xkv = list(input)  # Table is 1-based; iterate
         else:
             xq = xkv = input
-        q, k, v = self.project_qkv(params, xq, xkv)
-        return self._finish(params, self._attend(q, k, v))
+        with jax.named_scope("full attention"):
+            q, k, v = self.project_qkv(params, xq, xkv)
+            return self._finish(params, self._attend(q, k, v))
+
+    def apply_prefill(self, params, x):
+        """(out [B, T, E], k, v [B, H, T, hd]) of the whole sequence:
+        `apply`'s self-attention, with the post-RoPE K/V a serving
+        prefill commits."""
+        with jax.named_scope("full attention"):
+            q, k, v = self.project_qkv(params, x)
+            return self._finish(params, self._attend(q, k, v)), k, v
 
     def apply_step(self, params, x, k_cache, v_cache, positions):
         """Position-indexed single-step attention — the O(1)-per-token
@@ -184,22 +193,24 @@ class MultiHeadAttention(Module):
         rung, inside the one program. The positions dropped are masked
         for every row, so they weigh exp(-1e30 - m) = 0: the result is
         the whole depth's but for the order of a float sum."""
-        q, k, v = self.project_qkv(params, x, positions=positions[:, None])
-        k_cache = cache_write(k_cache, k, positions)
-        v_cache = cache_write(v_cache, v, positions)
+        with jax.named_scope("full attention"):
+            q, k, v = self.project_qkv(params, x,
+                                       positions=positions[:, None])
+            k_cache = cache_write(k_cache, k, positions)
+            v_cache = cache_write(v_cache, v, positions)
 
-        def read(d, q, k_cache, v_cache, positions):
-            mask = kv_cache.step_mask(d, positions)
-            return naive_attention(q, k_cache[:, :, :d], v_cache[:, :, :d],
-                                   mask=mask)
-        rungs = kv_cache.depth_rungs(k_cache.shape[2])
-        if len(rungs) == 1:  # no switch, and no index to compute
-            o = read(rungs[0], q, k_cache, v_cache, positions)
-        else:
-            o = lax.switch(kv_cache.rung_index(rungs, positions),
-                           [partial(read, d) for d in rungs],
-                           q, k_cache, v_cache, positions)
-        return self._finish(params, o), k_cache, v_cache
+            def read(d, q, k_cache, v_cache, positions):
+                mask = kv_cache.step_mask(d, positions)
+                return naive_attention(q, k_cache[:, :, :d],
+                                       v_cache[:, :, :d], mask=mask)
+            rungs = kv_cache.depth_rungs(k_cache.shape[2])
+            if len(rungs) == 1:  # no switch, and no index to compute
+                o = read(rungs[0], q, k_cache, v_cache, positions)
+            else:
+                o = lax.switch(kv_cache.rung_index(rungs, positions),
+                               [partial(read, d) for d in rungs],
+                               q, k_cache, v_cache, positions)
+            return self._finish(params, o), k_cache, v_cache
 
 
 class GroupedQueryAttention(Module):
@@ -333,29 +344,37 @@ class TransformerBlock(Module):
                 "w2": xav(k5, (self.hidden, self.e)),
                 "b2": jnp.zeros((self.e,))}
 
+    def _norm(self, which, params, x, ctx=None):
+        """The LayerNorm `which` ("ln1" or "ln2") of `x`."""
+        with jax.named_scope("norm"):
+            return getattr(self, which).apply(params[which], x, ctx)
+
     def apply(self, params, input, ctx):
         x = input
-        h = self.ln1.apply(params["ln1"], x, ctx)
+        h = self._norm("ln1", params, x, ctx)
         x = x + self.attn.apply(params["attn"], h, ctx)
-        h = self.ln2.apply(params["ln2"], x, ctx)
-        h = jax.nn.gelu(h @ params["w1"] + params["b1"])
-        if self.dropout and ctx.training:
-            keep = 1.0 - self.dropout
-            h = h * jax.random.bernoulli(ctx.make_rng(), keep, h.shape) / keep
-        return x + (h @ params["w2"] + params["b2"])
+        h = self._norm("ln2", params, x, ctx)
+        with jax.named_scope("dense ffn"):
+            h = jax.nn.gelu(h @ params["w1"] + params["b1"])
+            if self.dropout and ctx.training:
+                keep = 1.0 - self.dropout
+                h = h * jax.random.bernoulli(ctx.make_rng(), keep,
+                                             h.shape) / keep
+            return x + (h @ params["w2"] + params["b2"])
 
     def _mlp(self, params, x):
         # inference-form MLP tail (no dropout) shared by the incremental
         # step and prefill applies; matches `apply`'s eval-mode math
-        h = self.ln2.apply(params["ln2"], x, None)
-        h = jax.nn.gelu(h @ params["w1"] + params["b1"])
-        return h @ params["w2"] + params["b2"]
+        h = self._norm("ln2", params, x)
+        with jax.named_scope("dense ffn"):
+            h = jax.nn.gelu(h @ params["w1"] + params["b1"])
+            return h @ params["w2"] + params["b2"]
 
     def apply_step(self, params, x, k_cache, v_cache, positions):
         """One-token incremental block apply (inference): x [B, 1, E] at
         per-row `positions` [B] against this layer's KV cache. Returns
         (out [B, 1, E], k_cache, v_cache)."""
-        h = self.ln1.apply(params["ln1"], x, None)
+        h = self._norm("ln1", params, x)
         a, k_cache, v_cache = self.attn.apply_step(
             params["attn"], h, k_cache, v_cache, positions)
         x = x + a
@@ -365,8 +384,7 @@ class TransformerBlock(Module):
         """Full-sequence inference apply that ALSO returns this layer's
         post-RoPE K/V [B, H, T, hd], so a serving prefill can commit them
         into a decode cache. Same math as eval-mode `apply`."""
-        h = self.ln1.apply(params["ln1"], x, None)
-        q, k, v = self.attn.project_qkv(params["attn"], h)
-        x = x + self.attn._finish(params["attn"],
-                                  self.attn._attend(q, k, v))
+        h = self._norm("ln1", params, x)
+        a, k, v = self.attn.apply_prefill(params["attn"], h)
+        x = x + a
         return x + self._mlp(params, x), k, v

@@ -9,6 +9,7 @@ pre-computed topological sort traced once under jit (no per-step scheduling).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -85,12 +86,32 @@ class Sequential(Container):
         True
     """
 
+    #: {index of a child: the `jax.named_scope` that child and those after
+    #: it run under, up to the next entry}; set by `scope`
+    _scopes: Dict[int, str] = {}
+
+    def scope(self, name: str) -> "Sequential":
+        """Run the children added from here on, up to the next call, under
+        `jax.named_scope(name)`: the path a device trace's operations of
+        those children carry. A BN+ReLU pair split by a scope's edge is
+        not fused."""
+        self._scopes = {**self._scopes, len(self.children): name}
+        return self
+
     def apply(self, params, input, ctx):
+        x = input
+        edges = sorted({0, len(self.children), *self._scopes})
+        for lo, hi in zip(edges, edges[1:]):
+            name = self._scopes.get(lo)
+            with jax.named_scope(name) if name else contextlib.nullcontext():
+                x = self._apply_children(lo, hi, params, x, ctx)
+        return x
+
+    def _apply_children(self, i, n, params, x, ctx):
+        """Children i to n - 1 in turn on `x`."""
         from bigdl_tpu.nn.fusion import (fusible_activation, fusible_bn,
                                          fusion_enabled)
-        x = input
         fuse = fusion_enabled()
-        i, n = 0, len(self.children)
         while i < n:
             child = self.children[i]
             if fuse and i + 1 < n and fusible_bn(child) \

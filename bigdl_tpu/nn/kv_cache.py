@@ -128,14 +128,16 @@ def write(cache, new, positions, window: Optional[int] = None):
     donation the decode step updates its preallocated buffers in place
     (O(1) memory and step cost per token; never a per-token
     concat/retrace). A window layer's ring takes one position at a time
-    (T = 1), at `position % L`."""
-    if window is not None:
-        positions = positions % cache.shape[2]
-    lead = (0,) * (cache.ndim - 3)
+    (T = 1), at `position % L`. Under the scope `kv write`, wherever it
+    is called from."""
+    with jax.named_scope("kv write"):
+        if window is not None:
+            positions = positions % cache.shape[2]
+        lead = (0,) * (cache.ndim - 3)
 
-    def one(c, n, p):
-        return lax.dynamic_update_slice(c, n, lead + (p, 0))
-    return jax.vmap(one)(cache, new, positions)
+        def one(c, n, p):
+            return lax.dynamic_update_slice(c, n, lead + (p, 0))
+        return jax.vmap(one)(cache, new, positions)
 
 
 def commit(cache, new, slot_ids, lengths=None,
@@ -150,20 +152,21 @@ def commit(cache, new, slot_ids, lengths=None,
 
     A window layer whose prompt bucket is longer than its ring commits,
     per row, the last L positions of the row's real `lengths` [B] at the
-    ring's indices (position p at p % L)."""
-    ring = cache.shape[2]
-    if window is not None and new.shape[2] > ring:
-        last = lengths.astype(jnp.int32)[:, None] - 1           # [B, 1]
-        held = last - (last - jnp.arange(ring)[None, :]) % ring  # [B, L]
-        held = jnp.clip(held, 0, new.shape[2] - 1)  # < 0: masked anyway
-        new = jnp.take_along_axis(new, held[:, None, :, None], axis=2)
+    ring's indices (position p at p % L). Under the scope `kv commit`."""
+    with jax.named_scope("kv commit"):
+        ring = cache.shape[2]
+        if window is not None and new.shape[2] > ring:
+            last = lengths.astype(jnp.int32)[:, None] - 1           # [B, 1]
+            held = last - (last - jnp.arange(ring)[None, :]) % ring  # [B, L]
+            held = jnp.clip(held, 0, new.shape[2] - 1)  # < 0: masked anyway
+            new = jnp.take_along_axis(new, held[:, None, :, None], axis=2)
 
-    def body(c, inp):
-        n, s = inp
-        return lax.dynamic_update_slice(
-            c, n[None], (s,) + (0,) * (c.ndim - 1)), None
-    out, _ = lax.scan(body, cache, (new, slot_ids))
-    return out
+        def body(c, inp):
+            n, s = inp
+            return lax.dynamic_update_slice(
+                c, n[None], (s,) + (0,) * (c.ndim - 1)), None
+        out, _ = lax.scan(body, cache, (new, slot_ids))
+        return out
 
 
 def step_mask(length: int, positions, window: Optional[int] = None):

@@ -402,8 +402,9 @@ class DistriOptimizer(BaseOptimizer):
 
         def update(params, opt_state, lr, grads, losses, states):
             g = jax.tree_util.tree_map(lambda *ls: total(*ls) / R0, *grads)
-            new_params, new_opt = optim.update_with_masters(
-                clip(g), opt_state, params, lr)
+            with jax.named_scope("optimizer update"):
+                new_params, new_opt = optim.update_with_masters(
+                    clip(g), opt_state, params, lr)
             ms = states[0] if R0 == 1 \
                 else jax.tree_util.tree_map(avg, *states)
             return new_params, new_opt, ms, total(*losses) / R0

@@ -951,7 +951,8 @@ class BaseOptimizer:
                                                    training=True, rng=rng)
                     if mixed:
                         out = cast(out, jnp.float32)
-                    return criterion.apply(out, y), new_ms
+                    with jax.named_scope("loss"):
+                        return criterion.apply(out, y), new_ms
             return jax.value_and_grad(loss_fn, has_aux=True)(params)
 
         return loss_and_grads
@@ -1000,18 +1001,20 @@ class BaseOptimizer:
             else:
                 (loss, new_ms), grads = loss_and_grads(params, model_state,
                                                        x, y, step_rng)
-            grads = clip(grads)
             # return the FULL merged state, not the partial update:
             # model_state is donated, so untouched old leaves must flow
             # through the step (aliased by XLA) rather than be re-read
             # from dead host references
             new_ms = merge_state(model_state, new_ms)
-            new_params, new_opt = optim.update_with_masters(
-                grads, opt_state, params, lr)
-            (new_params, new_opt, new_ms), aux = guards(
-                guard, need_norms, loss, grads,
-                (params, opt_state, model_state),
-                (new_params, new_opt, new_ms))
+            with jax.named_scope("optimizer update"):
+                grads = clip(grads)
+                new_params, new_opt = optim.update_with_masters(
+                    grads, opt_state, params, lr)
+            with jax.named_scope("step guards"):
+                (new_params, new_opt, new_ms), aux = guards(
+                    guard, need_norms, loss, grads,
+                    (params, opt_state, model_state),
+                    (new_params, new_opt, new_ms))
             if constrain_state is not None:
                 new_ms = constrain_state(new_ms)
             return new_params, new_opt, new_ms, loss, rng, aux

@@ -104,6 +104,15 @@ def default_seq_buckets(max_len: int, floor: int = 8) -> List[int]:
     return out
 
 
+def _next_token(logp):
+    """Greedy 1-based ids [B] from log-probs [B, vocab], traced inside the
+    decode and prefill programs under the model's `head` scope name."""
+    import jax
+    import jax.numpy as jnp
+    with jax.named_scope("head"):
+        return jnp.argmax(logp, axis=-1).astype(jnp.int32) + 1
+
+
 class TokenStream:
     """Streaming token future for ONE generation request.
 
@@ -427,12 +436,12 @@ class GenerationEngine(InferenceEngine):
             tokens = jnp.where(fresh, tokens, prev)
             logp, cache = model_ref.apply_step(params, tokens, cache,
                                                positions)
-            return jnp.argmax(logp, axis=-1).astype(jnp.int32) + 1, cache
+            return _next_token(logp), cache
 
         def _prefill_fn(params, cache, tokens, slot_ids, lengths):
             logp, cache = model_ref.apply_prefill(params, tokens, cache,
                                                   slot_ids, lengths)
-            return jnp.argmax(logp, axis=-1).astype(jnp.int32) + 1, cache
+            return _next_token(logp), cache
 
         # the cache is DONATED: the per-token cost of the decode step is
         # one in-place slice update, never a buffer copy; signatures are
