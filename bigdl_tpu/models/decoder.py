@@ -48,7 +48,7 @@ from bigdl_tpu.nn.latent_attention import LatentAttention
 from bigdl_tpu.nn.linear_attention import GatedDeltaRule
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.nn.normalization import RMSNorm
-from bigdl_tpu.ops import latent_decode_kernel
+from bigdl_tpu.ops import gqa_decode_kernel, latent_decode_kernel
 
 #: what a layer keeps a serving slot, by its mixer and by the cache's
 #: names: an attention layer "k" and "v", a recurrent one "state" and
@@ -298,6 +298,8 @@ class DecoderLM(Module):
                            if b.keeps == _KEEPS["gated_delta"]]
         self._latent = [i for i, b in enumerate(self.blocks)
                         if b.keeps == _KEEPS["latent"]]
+        self._grouped = [i for i, b in enumerate(self.blocks)
+                         if b.keeps == _KEEPS["attention"]]
         self._windowed = any(b.window is not None for b in self.blocks)
 
     def init(self, rng):
@@ -362,6 +364,9 @@ class DecoderLM(Module):
             # float32: slots x max_len a step passes int32 in hours
             c["latent_positions_live"] = jnp.zeros((), jnp.float32)
             c["latent_positions_read"] = jnp.zeros((), jnp.float32)
+        if self._grouped:
+            c["kv_positions_live"] = jnp.zeros((), jnp.float32)
+            c["kv_positions_read"] = jnp.zeros((), jnp.float32)
         cache["counters"] = c
         return cache
 
@@ -429,6 +434,15 @@ class DecoderLM(Module):
                         jnp.float32)
                 c["latent_positions_read"] = c["latent_positions_read"] \
                     + latent_decode_kernel.positions_read(positions, depth)
+            if self._grouped:
+                ks = [cache["k"][i] for i in self._grouped]
+                c["kv_positions_live"] = c["kv_positions_live"] + sum(
+                    jnp.sum(jnp.where(live, jnp.minimum(
+                        positions + 1, k.shape[2]), 0)) for k in ks).astype(
+                            jnp.float32)
+                c["kv_positions_read"] = c["kv_positions_read"] + sum(
+                    gqa_decode_kernel.positions_read(positions, k)
+                    for k in ks)
         return self._logp(params, x[:, 0]), {**new, "counters": c}
 
     def apply_prefill(self, params, tokens, cache, slot_ids, lengths):
@@ -502,7 +516,12 @@ class DecoderLM(Module):
         read that follows the lengths would touch) and
         `latent_positions_read` the positions a step read (each slot's
         live blocks, whole, on the `mla_decode` kernel's path; slots x
-        `max_len` on the plain-XLA one)."""
+        `max_len` on the plain-XLA one). Grouped-query attention layers,
+        over the decode steps and those layers: `kv_positions_live` the
+        live slots' `min(position + 1, depth)` (a ring holds `window`)
+        and `kv_positions_read` the positions a step read (each slot's
+        live blocks, whole, on the `gqa_decode` kernel's path; slots x
+        depth on the plain-XLA one)."""
         c = jax.device_get(cache["counters"])
         out = {}
         if self._routed:
@@ -537,6 +556,10 @@ class DecoderLM(Module):
                     for name in _KEEPS["latent"])),
                 "latent_positions_live": float(c["latent_positions_live"]),
                 "latent_positions_read": float(c["latent_positions_read"])})
+        if self._grouped:
+            out.update({
+                "kv_positions_live": float(c["kv_positions_live"]),
+                "kv_positions_read": float(c["kv_positions_read"])})
         return out
 
 

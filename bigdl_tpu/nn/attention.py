@@ -19,6 +19,7 @@ from bigdl_tpu.nn import kv_cache
 from bigdl_tpu.nn.initialization import Xavier
 from bigdl_tpu.nn.module import ApplyContext, Module
 from bigdl_tpu.nn.normalization import LayerNormalization, rms_norm
+from bigdl_tpu.ops import gqa_decode_kernel
 from bigdl_tpu.ops.attention_kernel import (blockwise_attention,
                                             causal_grouped_attention,
                                             flash_attention,
@@ -305,8 +306,10 @@ class GroupedQueryAttention(Module):
     def apply_step(self, params, x, k_cache, v_cache, positions):
         """One new token a row: `x` [B, 1, E] at `positions` [B] against
         the layer's cache. Writes the token's K/V, then the `group` query
-        heads of each K/V head read its cache once, under the mask its
-        kind of cache gives. Returns (out [B, 1, E], k_cache, v_cache)."""
+        heads of each K/V head read its cache once: through `gqa_decode`,
+        only to each slot's length, where `gqa_decode_kernel.cache_block`
+        gives a block; else whole, under the mask its kind of cache gives.
+        Returns (out [B, 1, E], k_cache, v_cache)."""
         with self._scope():
             q, k, v = self.project_qkv(params, x,
                                        positions=positions[:, None])
@@ -314,9 +317,14 @@ class GroupedQueryAttention(Module):
                                      positions, self.window)
             v_cache = kv_cache.write(v_cache, v.astype(v_cache.dtype),
                                      positions, self.window)
-            mask = kv_cache.step_mask(k_cache.shape[2], positions,
-                                      self.window)
-            o = grouped_attention(q, k_cache, v_cache, mask[:, :, None])
+            block = gqa_decode_kernel.cache_block(k_cache)
+            if block is None:
+                mask = kv_cache.step_mask(k_cache.shape[2], positions,
+                                          self.window)
+                o = grouped_attention(q, k_cache, v_cache, mask[:, :, None])
+            else:
+                o = gqa_decode_kernel.gqa_decode(q, k_cache, v_cache,
+                                                 positions, block)
             return self._finish(params, o), k_cache, v_cache
 
 

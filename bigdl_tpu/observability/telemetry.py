@@ -309,7 +309,12 @@ RECORD_SCHEMAS: Dict[str, Dict] = {
                      # slots' positions + 1 and slots x the depth read
                      "latent_cache_bytes": int,
                      "latent_positions_live": _NUM,
-                     "latent_positions_read": _NUM},
+                     "latent_positions_read": _NUM,
+                     # grouped-query attention layers: over the decode
+                     # steps and those layers, the live slots'
+                     # min(position + 1, depth) and the positions read
+                     "kv_positions_live": _NUM,
+                     "kv_positions_read": _NUM},
     },
     # fleet-level counters/gauges (serving/fleet.py), one per
     # membership change or maintain() tick; PrometheusTextSink renders

@@ -9,6 +9,7 @@ from bigdl_tpu.ops.attention_kernel import (attention_state_finish,
                                             naive_attention)
 from bigdl_tpu.ops.bn_relu_kernel import (bn_relu, bn_relu_backward,
                                           bn_relu_forward, bn_relu_pallas)
+from bigdl_tpu.ops.gqa_decode_kernel import gqa_decode
 from bigdl_tpu.ops.latent_decode_kernel import mla_decode
 from bigdl_tpu.ops import operation
 from bigdl_tpu.ops import feature_col

@@ -116,7 +116,7 @@ def test_init_cache_gives_each_layer_what_its_kind_keeps(model):
             assert cache["k"][i] is None and cache["v"][i] is None
     assert set(cache["counters"]) == {
         "decode_steps", "recurrent_slot_steps", "recurrent_chunks_scanned",
-        "recurrent_state_absmax"}
+        "recurrent_state_absmax", "kv_positions_live", "kv_positions_read"}
     # the state is float32 whatever the cache's type; K, V and tail follow
     half = build(cache_dtype=jnp.bfloat16).init_cache(2, 64)
     assert half["state"][0].dtype == jnp.float32

@@ -207,3 +207,43 @@ def test_resnet_stages_are_scoped_where_the_model_is_built():
     x = jnp.ones((1, 32, 32, 3), jnp.float32)
     out = model.forward(x)
     assert out.shape == (1, 10) and np.isfinite(np.asarray(out)).all()
+
+
+#: a tiny model of each kind whose decode step takes a kernel on a TPU
+#: (caches of 256, rings of 128: whole blocks), and the scope each of its
+#: kernel calls must read under, in the order of the layers
+_KERNEL_CASES = {
+    "sparse": (lambda: DecoderLM(
+        128, 64, 4, 2, 16,
+        [LayerSpec(window=128 if i % 4 else None,
+                   rope_base=1e6 if i % 4 else None) for i in range(4)],
+        8, 32, 2), ["full attention"] + ["window attention"] * 3),
+    "hybrid": (_hybrid, ["full attention"] * 2),
+    "latent": (_latent, ["mla attend"] * 6),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_KERNEL_CASES))
+def test_the_decode_kernels_read_under_their_layers_scope(kind, monkeypatch):
+    """Lowered for a TPU under its routing, each `gqa_decode` and
+    `mla_decode` call of a decode step carries the path of the layer it
+    reads for: a device trace reads it under `full attention`,
+    `window attention` or `latent attention` > `mla attend`."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    build, scopes = _KERNEL_CASES[kind]
+    model = build()
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(SLOTS, 256))
+    ids = jax.ShapeDtypeStruct((SLOTS,), jnp.int32)
+    text = jax.jit(model.apply_step).trace(params, ids, cache, ids).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    calls = re.findall(r"custom_call @tpu_custom_call\(.*loc\((#loc\d+)\)$",
+                       text, re.M)
+    paths = [SCOPES.components(locs[c]) for c in calls]
+    assert len(paths) == len(scopes)
+    kernel = "mla_decode" if kind == "latent" else "gqa_decode"
+    for comps, scope in zip(paths, scopes):
+        assert scope in comps and kernel in comps, comps
+        if kind == "latent":
+            assert "latent attention" in comps

@@ -300,10 +300,50 @@ class TestMosaicCompiles:
                 struct((32, 16384, 64), jnp.bfloat16, on),
                 struct((32,), jnp.int32, on)).compile()
 
+    @pytest.mark.parametrize("heads,kv_heads,depth", [
+        (28, 4, 16384), (28, 4, 4096), (30, 30, 2048)],
+        ids=["sparse-full", "sparse-window", "hybrid-full"])
+    def test_grouped_decode_at_the_cells_shapes(self, heads, kv_heads,
+                                                depth, monkeypatch):
+        # 32 slots, heads of 128, bf16, in the block `block_for` gives
+        # the shapes on a TPU
+        from bigdl_tpu.ops import gqa_decode_kernel as gdk
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        block = gdk.block_for(depth, kv_heads, 128, 2)
+        on = _v5e_device()
+        kv = struct((32, kv_heads, depth, 128), jnp.bfloat16, on)
+        with jax.default_matmul_precision("bfloat16"):
+            lower_for_tpu(
+                lambda q, k, v, pos: gdk.gqa_decode(q, k, v, pos, block,
+                                                    interpret=False),
+                struct((32, heads, 1, 128), jnp.bfloat16, on), kv, kv,
+                struct((32,), jnp.int32, on)).compile()
+
 
 # ---------------------------------------------------------------------- #
 # the serving engine's decode program at the serving cell's shapes
 # ---------------------------------------------------------------------- #
+
+def _assert_one_call_a_layer_and_no_cache_moved(compiled, kernel, n_layer,
+                                                buffers, donated):
+    """The compiled decode program holds `n_layer` Mosaic calls, all
+    named `kernel`; no cache buffer of the shapes `buffers` is copied or
+    relaid out; the donated cache (at least `donated` bytes) is updated
+    in place with little beside it."""
+    import re
+    hlo = compiled.as_text()
+    entry = hlo[hlo.index("ENTRY"):]
+    calls = re.findall(rf"^\s*%{kernel}[\w.]* = \S+ custom-call\(",
+                       entry, re.M)
+    assert len(calls) == n_layer == hlo.count("tpu_custom_call")
+    moved = [line for line in entry.splitlines()
+             if re.search(r" (copy|copy-start|transpose)\(", line)
+             and line.split(" = ", 1)[-1].lstrip().startswith(buffers)]
+    assert not moved, moved
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= donated
+    assert m.temp_size_in_bytes < 100_000_000
+
 
 def _compile_decode_program(model, params, cache, slots, on):
     """`GenerationEngine`'s own `jit__decode_fn` for `model`, compiled by
@@ -395,14 +435,13 @@ class TestSparseDecodeProgramCompiles:
     layer must read `wg`, `wu` and `wd` where they lie. Nothing runs, so
     nothing here is a timing."""
 
-    def test_experts_are_read_in_place_and_named(self):
-        import re
+    @staticmethod
+    def _program(on):
         from bigdl_tpu.models.decoder import LayerSpec, SparseDecoderLM
-        on = _v5e_device()
-        slots, max_len, n_layer = 32, 16384, 8
+        slots, max_len = 32, 16384
         layers = [LayerSpec(window=4096 if i % 4 else None,
                             rope_base=1.5e6 if i % 4 else None)
-                  for i in range(n_layer)]
+                  for i in range(8)]
         model = SparseDecoderLM(151936, embed_dim=2560, n_head=28,
                                 n_kv_head=4, head_dim=128, layers=layers,
                                 n_experts=64, expert_dim=768, top_k=6,
@@ -416,7 +455,13 @@ class TestSparseDecodeProgramCompiles:
         cache = jax.tree_util.tree_map(
             lambda a: struct(a.shape, a.dtype, on),
             jax.eval_shape(lambda: model.init_cache(slots, max_len)))
-        compiled = _compile_decode_program(model, params, cache, slots, on)
+        return _compile_decode_program(model, params, cache, slots, on)
+
+    def test_experts_are_read_in_place_and_named(self):
+        import re
+        on = _v5e_device()
+        n_layer = 8
+        compiled = self._program(on)
         hlo = compiled.as_text()
         entry = hlo[hlo.index("ENTRY"):]
         assert "jit__decode_fn" in hlo and "ragged-dot" not in hlo
@@ -445,6 +490,17 @@ class TestSparseDecodeProgramCompiles:
         assert entry.count("moe experts/moe gate up/") >= n_layer
         assert "tpu_custom_call" not in hlo
 
+    def test_one_gqa_decode_a_layer_and_no_copy_of_a_cache(self,
+                                                           tpu_routing):
+        """Under the TPU's routing each of the eight layers reads its
+        cache through ONE `gqa_decode` call, the two full layers' 16384
+        deep and the six rings of 4096, and no K/V buffer is copied or
+        relaid out around the calls."""
+        compiled = self._program(_v5e_device())
+        _assert_one_call_a_layer_and_no_cache_moved(
+            compiled, "gqa_decode", 8,
+            ("bf16[32,4,16384,128]", "bf16[32,4,4096,128]"), 3_700_000_000)
+
 
 class TestHybridDecodeProgramCompiles:
     """`GenerationEngine`'s `jit__decode_fn` over `DecoderLM` at the
@@ -455,10 +511,9 @@ class TestHybridDecodeProgramCompiles:
     compiled by libtpu for a v5e that is not attached. Nothing runs, so
     nothing here is a timing."""
 
-    def test_the_state_is_replaced_in_place_by_one_fusion_a_layer(self):
-        import re
+    @staticmethod
+    def _program(on):
         from bigdl_tpu.models.decoder import DecoderLM, LayerSpec
-        on = _v5e_device()
         slots, max_len, n_layer = 32, 2048, 16
         layers = [LayerSpec(mixer="attention" if i % 4 == 3
                             else "gated_delta", ffn="dense", norm="output")
@@ -478,9 +533,13 @@ class TestHybridDecodeProgramCompiles:
         cache = jax.tree_util.tree_map(
             lambda a: struct(a.shape, a.dtype, on),
             jax.eval_shape(lambda: model.init_cache(slots, max_len)))
-        state = "f32[32,30,96,192]"
         assert sum(1 for s in cache["state"] if s is not None) == 12
-        compiled = _compile_decode_program(model, params, cache, slots, on)
+        return _compile_decode_program(model, params, cache, slots, on)
+
+    def test_the_state_is_replaced_in_place_by_one_fusion_a_layer(self):
+        import re
+        state = "f32[32,30,96,192]"
+        compiled = self._program(_v5e_device())
         hlo = compiled.as_text()
         entry = hlo[hlo.index("ENTRY"):]
         assert "jit__decode_fn" in hlo and "tpu_custom_call" not in hlo
@@ -507,6 +566,16 @@ class TestHybridDecodeProgramCompiles:
         assert entry.count("linear attention/gdn conv/") >= 12
         assert "full attention/" in entry and "dense ffn/" in entry
 
+    def test_one_gqa_decode_a_layer_and_no_copy_of_a_cache(self,
+                                                           tpu_routing):
+        """Under the TPU's routing each of the four full layers reads its
+        cache through ONE `gqa_decode` call (30 K/V heads of 128, 2048
+        deep), and no K/V buffer is copied or relaid out around them."""
+        compiled = self._program(_v5e_device())
+        _assert_one_call_a_layer_and_no_cache_moved(
+            compiled, "gqa_decode", 4, ("bf16[32,30,2048,128]",),
+            4_900_000_000)
+
 
 # ---------------------------------------------------------------------- #
 # latent attention's decode step: one `mla_decode` kernel a layer
@@ -523,8 +592,9 @@ def _tiny_decode_step(model, slots, max_len):
 class TestLatentDecodeLowers:
     """The tiny decoders of the three kinds that take `DecoderLM`'s decode
     step (tiny-latent's widths for the latent one) under the TPU's
-    routing: a latent layer's step is ONE Mosaic call, `mla_decode`; the
-    sparse and hybrid steps hold none, as before the kernel."""
+    routing: a latent layer's step is ONE Mosaic call, `mla_decode`; a
+    grouped-query layer's is ONE `gqa_decode` where its cache is a whole
+    number of blocks deep, and plain XLA where it is not."""
 
     def test_one_kernel_a_latent_layer(self, tpu_routing):
         from bigdl_tpu.models.decoder import (DecoderLM, ExpertsKind,
@@ -558,7 +628,13 @@ class TestLatentDecodeLowers:
             model = DecoderLM(128, 64, 4, 4, 16, layers, ffn_dim=128,
                               qk_norm=True, linear_heads=4,
                               linear_key_dim=8, linear_value_dim=16)
-        assert n_mosaic(_tiny_decode_step(model, 4, 256)) == 0
+        # the one full layer's 256 positions are two blocks; the sparse
+        # model's rings of 64 are no whole block and stay plain XLA, as
+        # does every layer of a cache 200 deep
+        lowered = _tiny_decode_step(model, 4, 256)
+        assert n_mosaic(lowered) == 1
+        assert lowered.as_text(debug_info=True).count('"gqa_decode"') >= 1
+        assert n_mosaic(_tiny_decode_step(model, 4, 200)) == 0
 
 
 class TestLatentDecodeProgramCompiles:
