@@ -4,14 +4,18 @@ through its first steps and then through the measured window.
 The program's training loop is one call, `optimize()`, so set-up and
 window are one run of it. Weights and the one resident batch are made on
 the device from the seed. Steps 1 to 3 are the steps the reference
-follows: their losses come from the loop's own iteration hook, the first
-gradient from the optimizer's state after step 1 and the parameters'
-change after step 3, both read where the compiled step hands its new
-state back to the loop. Step 4 drains those readings; the window opens
-at the end of it and closes at the first step boundary past
-`--seconds`. The loop syncs the loss at the interval the program
-defaults to, so every step boundary is a barrier and the rate is all
-items of all whole steps over the whole window.
+follows: their losses, the first gradient from the optimizer's state
+after step 1 and the parameters' change after step 3 are all read where
+the compiled step hands its outputs back to the loop. Step 4 drains
+those readings; the window opens once it has ended on the device.
+
+The loop syncs the loss at the mix's `sync_interval` (null: the
+program's default, every step). Between syncs the host dispatches ahead
+of the device, as far as the runtime's queue lets it, so the host's own
+time a step is off the device's path. When `--seconds` have passed,
+nothing more is dispatched: the window closes once every step sent has
+ended on the device, and the rate is all items of all those steps over
+that whole time.
 """
 
 from __future__ import annotations
@@ -38,6 +42,8 @@ class StepProbe:
         self.calls = 0
         self.first_grad = None
         self.change = None
+        self.losses = []  # steps 1 to 3, as the step returns them
+        self.last_loss = None  # the newest step's, on the device
 
     def wrap(self, step):
         import jax
@@ -46,6 +52,9 @@ class StepProbe:
         def probed(*args):
             out = step(*args)
             self.calls += 1
+            self.last_loss = out[3]
+            if self.calls <= 3:
+                self.losses.append(out[3])
             if self.calls == 1:
                 g = self.adapter.first_gradient(out[1])
                 self.first_grad = (nx.leaf_norms(g), nx.sketches(g))
@@ -99,6 +108,7 @@ def build_optimizer(ctx, adapter, x, y):
 
 
 def run(ctx) -> Dict[str, Any]:
+    import jax
     from benchmarks.reference import numerics as nx
     adapter = ctx.adapter
     rows = ctx.mix["per_chip_batch"] * ctx.chips
@@ -109,6 +119,8 @@ def run(ctx) -> Dict[str, Any]:
     adapter.model.set_params(adapter.to_program(weights))
     adapter.model._state = adapter.model.state_init()
     del weights
+    jax.block_until_ready((adapter.model.ensure_params(), x, y))
+    marks = {"weights": time.perf_counter()}
     opt = build_optimizer(ctx, adapter, x, y)
     del x, y
     tracer = None
@@ -119,32 +131,41 @@ def run(ctx) -> Dict[str, Any]:
     probe = StepProbe(ctx, adapter)
     probe.install(opt)
 
-    w = {"t0": None, "deadline": None, "ends": [], "losses": [],
-         "lowerings0": 0, "trace": None, "prog": None}
+    every = max(1, int(ctx.mix.get("sync_interval") or 1))
+    w = {"t0": None, "deadline": None, "t_end": None, "last": None,
+         "syncs": [], "lowerings0": 0, "prog": None}
 
     def hook(state):
         now = time.perf_counter()
         n = state["neval"]
-        if n <= 3:
-            w["losses"].append(float(state["loss"]))
-        if n == 3:
-            w["prog"] = {"losses": list(w["losses"]),
+        if n == 1:
+            jax.block_until_ready(probe.last_loss)
+            marks["step1"] = time.perf_counter()
+        elif n == 3:
+            w["prog"] = {"losses": [float(l) for l in probe.losses],
                          "gnorm": nx.to_floats(probe.first_grad[0]),
                          "gsketch": nx.to_floats(probe.first_grad[1]),
                          "dnorm": nx.to_floats(probe.change[0]),
                          "dsketch": nx.to_floats(probe.change[1])}
         elif n == SETUP_STEPS:
             w["lowerings0"] = ctx.lowerings()
+            jax.block_until_ready(probe.last_loss)
             w["t0"] = time.perf_counter()
             w["deadline"] = w["t0"] + ctx.seconds
         elif n > SETUP_STEPS:
-            w["ends"].append(now)
+            if n % every == 0:  # the loop has just synced this step
+                w["syncs"].append((n, now))
             if ctx.trace:
                 ctx.trace_from(now - w["t0"], ctx.seconds - TRACE_SECONDS)
 
     def done(state):
-        return w["deadline"] is not None \
-            and time.perf_counter() >= w["deadline"]
+        if w["deadline"] is None or time.perf_counter() < w["deadline"]:
+            return False
+        # send nothing more; the window ends with the last step sent
+        jax.block_until_ready(probe.last_loss)
+        w["t_end"] = time.perf_counter()
+        w["last"] = state["neval"]
+        return True
 
     opt.set_iteration_hook(hook)
     opt.set_end_when(done)
@@ -152,13 +173,22 @@ def run(ctx) -> Dict[str, Any]:
     ctx.trace_stop()
     lowerings = ctx.lowerings() - w["lowerings0"]
 
-    ends = np.asarray(w["ends"])
-    steps = len(ends)
+    steps = w["last"] - SETUP_STEPS
     if steps < 1:
         raise RuntimeError("no whole step fit the window")
-    elapsed = float(ends[-1] - w["t0"])
+    elapsed = float(w["t_end"] - w["t0"])
     rate = steps * items_per_step / elapsed / ctx.chips
-    step_ms = np.diff(np.concatenate([[w["t0"]], ends])) * 1e3
+    # the time per step from one sync to the next (the last interval ends
+    # where the window does)
+    syncs = [(SETUP_STEPS, w["t0"])] + w["syncs"]
+    if syncs[-1][0] < w["last"]:
+        syncs.append((w["last"], w["t_end"]))
+    step_ms = np.asarray([(t1 - t0) / (n1 - n0) * 1e3 for (n0, t0), (n1, t1)
+                          in zip(syncs, syncs[1:])])
+    phases = (f"set-up: the chip at {ctx.t_chip - ctx.t_start:.1f} s, "
+              f"weights and batch {marks['weights'] - ctx.t_chip:.1f}, "
+              f"step 1 {marks['step1'] - marks['weights']:.1f}, steps 2 to "
+              f"{SETUP_STEPS} {w['t0'] - marks['step1']:.1f}")
 
     # free the program's state before the reference runs
     adapter.model.set_params(None)
@@ -168,9 +198,11 @@ def run(ctx) -> Dict[str, Any]:
     slow = int((step_ms > 1.5 * np.median(step_ms)).sum())
     return {
         "t_window": w["t0"], "window_s": elapsed,
-        "note": f"{steps} steps, median {np.median(step_ms):.2f} ms, longest "
-                f"{step_ms.max():.1f} ms, {slow} over 1.5 x the median, "
-                f"{lowerings} programs lowered in the window",
+        "note": f"{steps} steps, loss synced every {every}; a step from "
+                f"sync to sync: median {np.median(step_ms):.2f} ms, longest "
+                f"{step_ms.max():.1f} ms, {slow} of {len(step_ms)} over 1.5 x "
+                f"the median; {lowerings} programs lowered in the window; "
+                + phases,
         "attempted": steps, "failed": 0,
         "end_to_end": {"train_items_per_s_per_chip": rate},
         "counters": {"steps": steps, "items_per_step": items_per_step,

@@ -129,6 +129,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     cell = manifest.cell(workload)
     peaks_doc = load_json(os.path.join(HERE, "peaks.json"))
     devices, peaks = find_devices(int(cell["chips"]), peaks_doc, require_chip)
+    t_chip = time.perf_counter()
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": len(devices)}
     if require_chip:
@@ -140,6 +141,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     trace_dir = os.path.join(scratch, f"trace-{os.getpid()}")
     ctx = Context(manifest, cell, seed, seconds, trace, devices, peaks,
                   t_start, trace_dir)
+    ctx.t_chip = t_chip  # set-up's phases are read from here on
     driver = load_py("drivers", ctx.mix["driver"])
     _say(device, f"{workload} seed={seed} seconds={seconds} trace={int(trace)}")
 

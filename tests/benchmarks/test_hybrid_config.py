@@ -251,15 +251,15 @@ HYBRID_TWINS = {"prefill_ms_p50.hybrid": "prefill_ms_p50.chat",
                 "engine_step_ms_p50.hybrid": "engine_step_ms_p50"}
 
 
-def test_the_cell_reports_the_median_gap_and_its_layers_metrics():
-    m = Manifest()
+def cell_pins(m):
+    """What the cell has, in `m`; nothing about entries appended after it."""
     names = {x["name"] for x in m.doc["end_to_end"]}
     assert {x["name"] for x in m.metrics("end_to_end", CELL, names)} == \
         {"itl_p50_ms", "setup_s"}
     assert {x["name"] for x in m.metrics("per_layer", CELL,
-                                         {"itl_p50_ms", "setup_s"})} == \
-        {"decode_step_ms", "decode_hbm_share.hybrid", "serve_mfu.hybrid"} \
-        | set(HYBRID_TWINS)
+                                         {"itl_p50_ms", "setup_s"})} >= \
+        {"decode_step_ms", "decode_hbm_share.hybrid", "serve_mfu.hybrid",
+         "decode_kv_write_ms", "decode_attention_ms"} | set(HYBRID_TWINS)
     for x in m.doc["per_layer"]:
         if x["name"].endswith(".hybrid"):
             assert x["workloads"] == [CELL] and x["moves"] == "itl_p50_ms"
@@ -272,12 +272,15 @@ def test_the_cell_reports_the_median_gap_and_its_layers_metrics():
                                            "gated_delta_rule.py"))
 
 
+def test_the_cell_reports_the_median_gap_and_its_layers_metrics():
+    cell_pins(Manifest())
+
+
 @pytest.mark.parametrize("twin", sorted(HYBRID_TWINS))
 def test_a_twin_reads_what_the_accepted_metric_reads(twin):
     """The cell's tail, first-token, prefill, compile, sender and engine
     step metrics are the accepted readers with the accepted arguments
-    under a name of the cell's own (the accepted lists are pinned by
-    `test_tail.py` and `test_span_metrics.py`)."""
+    under a name of the cell's own."""
     m = Manifest()
     mine, theirs = m.metric_file(twin), m.metric_file(HYBRID_TWINS[twin])
     for key in ("reader", "args", "unit", "source", "layer"):

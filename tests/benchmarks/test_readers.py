@@ -62,7 +62,6 @@ def test_kernel_roofline_and_its_silence(ctx):
     reduced = {"kernels": [(text, 2 * least), (text, 2 * least)]}
     assert _read("flash_roofline.train", ctx, None, reduced) == \
         pytest.approx(50.0)
-    assert _read("bn_relu_roofline", ctx, None, reduced) is None
     assert _read("flash_roofline.train", ctx, None, {"kernels": []}) is None
 
 

@@ -28,6 +28,9 @@ def _read(name, ctx, out, reduced):
 #: metric -> (span, percentile, cells that list it)
 _SERVE = ["neox-3.6b.serve-chat"]
 _TRAIN = ["resnet50.train-b128", "neox-3.6b.train-t2048"]
+#: the LM syncs its loss every step; ResNet-50 every 100 steps, so its
+#: traced slice holds a sync only where the window's end meets one
+_SYNCED_EVERY_STEP = ["neox-3.6b.train-t2048"]
 SPAN_METRICS = {
     "engine_step_ms_p50": ("generate step", 50, _SERVE),
     "engine_step_ms_p95": ("generate step", 95, _SERVE),
@@ -35,21 +38,28 @@ SPAN_METRICS = {
     "decode_fetch_ms_p50": ("decode fetch", 50, _SERVE),
     "decode_deliver_ms_p50": ("decode deliver", 50, _SERVE),
     "step_dispatch_ms_p50": ("step dispatch", 50, _TRAIN),
-    "loss_sync_ms_p50": ("loss sync", 50, _TRAIN),
+    "loss_sync_ms_p50": ("loss sync", 50, _SYNCED_EVERY_STEP),
     "step_bookkeeping_ms_p50": ("step bookkeeping", 50, _TRAIN),
 }
 
 
-@pytest.mark.parametrize("name", sorted(SPAN_METRICS))
-def test_span_metrics_read_their_span_and_nothing_else(ctx, name):
+def span_metric_pins(m, name):
+    """`name` reads its span, in `m`, and lists at least its cells: a cell
+    appended after them may list it too."""
     span, q, cells = SPAN_METRICS[name]
-    spec = Manifest().metric_file(name)
+    spec = m.metric_file(name)
     assert spec["reader"] == "span_percentile"
     assert spec["args"] == {"span": span, "q": q}
     assert (spec["unit"], spec["source"]) == ("ms", "program_span")
-    entry = [m for m in Manifest().doc["per_layer"] if m["name"] == name]
-    assert len(entry) == 1 and entry[0]["workloads"] == cells
+    entry = [e for e in m.doc["per_layer"] if e["name"] == name]
+    assert len(entry) == 1 and set(cells) <= set(entry[0]["workloads"])
     assert entry[0]["better"] == "lower"
+
+
+@pytest.mark.parametrize("name", sorted(SPAN_METRICS))
+def test_span_metrics_read_their_span_and_nothing_else(ctx, name):
+    span_metric_pins(Manifest(), name)
+    span, q, _ = SPAN_METRICS[name]
     # durations in seconds, 1 to 20 ms: the median is 10.5 ms, the 95th
     # percentile 19.05 ms
     durations = [k * 1e-3 for k in range(1, 21)]
