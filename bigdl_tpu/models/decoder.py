@@ -4,9 +4,10 @@ Each layer is a `LayerSpec`: its token mixer (grouped-query attention,
 `nn.GroupedQueryAttention`, over a sliding `window` or everything, with
 a rotary base or no positional encoding at all; the gated delta
 rule's linear attention, `nn.GatedDeltaRule`, which keeps a fixed-size
-state instead of keys and values; or latent attention,
+state instead of keys and values; latent attention,
 `nn.LatentAttention`, which keeps one compressed latent a position for
-all heads), its feed-forward (dropless routed
+all heads; or the gated short convolution, `nn.ShortConv`, which keeps
+the last rows it convolved and nothing else), its feed-forward (dropless routed
 experts, `nn.experts.RoutedExperts`, with or without an always-on
 `shared` expert added to their sum, or one dense gated one,
 `nn.experts.GatedFFN`) and where its RMSNorms sit: on each sub-layer's
@@ -24,11 +25,13 @@ feed-forward's own), or on each sub-layer's output,
 The serving side is what `GenerationEngine` calls (`init_cache`,
 `apply_prefill`, `apply_step`, `cache_stats`): the cache gives each
 layer what its kind keeps a slot (nn/kv_cache.py: K and V as deep as
-the kind needs, a recurrent state and its convolution's tail, or the
-latent and the shared rotary key), prefill takes the head over each
+the kind needs, a recurrent state and its convolution's tail, the
+latent and the shared rotary key, or a convolution's tail alone),
+prefill takes the head over each
 prompt's last real position only, and the cache pytree carries
 device-side counters of the routing, of the window, of the recurrent
-state and of the latent's reads.
+state, of the latent's reads and of the convolutions' tails. The head
+is its own matrix, or the embedding's transpose (`tie_head`).
 """
 
 from __future__ import annotations
@@ -48,14 +51,16 @@ from bigdl_tpu.nn.latent_attention import LatentAttention
 from bigdl_tpu.nn.linear_attention import GatedDeltaRule
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.nn.normalization import RMSNorm
+from bigdl_tpu.nn.short_conv import ShortConv
 from bigdl_tpu.ops import gqa_decode_kernel, latent_decode_kernel
 
 #: what a layer keeps a serving slot, by its mixer and by the cache's
 #: names: an attention layer "k" and "v", a recurrent one "state" and
-#: "tail", a latent one "latent" and "k_pe"
+#: "tail", a latent one "latent" and "k_pe", a short convolution
+#: "conv_tail" alone
 _KEEPS = {"attention": ("k", "v"), "gated_delta": ("state", "tail"),
-          "latent": ("latent", "k_pe")}
-_KEPT = tuple(name for pair in _KEEPS.values() for name in pair)
+          "latent": ("latent", "k_pe"), "short_conv": ("conv_tail",)}
+_KEPT = tuple(name for names in _KEEPS.values() for name in names)
 
 
 @dataclass(frozen=True)
@@ -85,8 +90,9 @@ class LayerSpec:
     """One layer of the pattern. `mixer`: "attention" (over the last
     `window` positions, None: all; `rope_base` of its rotary encoding,
     None: no positional encoding), "gated_delta" (linear attention;
-    neither applies) or "latent" (latent attention over everything; its
-    `rope_base` is a number). `ffn`: "experts" (routed; beside them an
+    neither applies), "latent" (latent attention over everything; its
+    `rope_base` is a number) or "short_conv" (the gated short
+    convolution; neither applies). `ffn`: "experts" (routed; beside them an
     always-on gated expert of width `shared`, 0: none; their router
     reads what `router_reads` names, the "attention" block's normed
     input or the "ffn"'s own) or "dense". `norm`: the RMSNorms on each
@@ -107,11 +113,11 @@ class LayerSpec:
             if getattr(self, field) not in known:
                 raise ValueError(f"LayerSpec.{field} is one of {known}, "
                                  f"got {getattr(self, field)!r}")
-        if self.mixer == "gated_delta" and (self.window is not None
-                                            or self.rope_base is not None):
-            raise ValueError("LayerSpec.window and rope_base belong to "
-                             "mixer='attention'; a gated_delta layer keeps "
-                             "a state, not positions")
+        if self.mixer in ("gated_delta", "short_conv") and (
+                self.window is not None or self.rope_base is not None):
+            raise ValueError(f"LayerSpec.window and rope_base belong to "
+                             f"mixer='attention'; a {self.mixer} layer "
+                             f"keeps no positions")
         if self.mixer == "latent" and (self.window is not None
                                        or self.rope_base is None):
             raise ValueError("a latent layer attends to everything and "
@@ -124,8 +130,10 @@ class LayerSpec:
 
 
 class DecoderBlock(Module):
-    """`attn` is the layer's token mixer and `keeps` the names of the two
-    things it keeps a serving slot (`_KEEPS`); the feed-forward is
+    """`attn` is the layer's token mixer and `keeps` the names of the one
+    or two things it keeps a serving slot (`_KEEPS`; where it keeps one,
+    the second of the pair this block's entry points pass is None); the
+    feed-forward is
     `experts` (with the block's `router`, a sigmoid router's
     `router_bias`, and the `shared` expert where the layer has one) or
     `ffn`."""
@@ -176,7 +184,8 @@ class DecoderBlock(Module):
         h = x if self.norm_output else self._norm("ln1", params, x)
         logits = self._route(params, h) \
             if self.experts is not None and self.route_early else None
-        a, kept_a, kept_b = mixer(params["attn"], h)
+        a, *kept = mixer(params["attn"], h)
+        kept_a, kept_b = (*kept, None)[:2]
         if self.norm_output:
             a = self._norm("ln1", params, a)
         return x + a, kept_a, kept_b, logits
@@ -228,9 +237,10 @@ class DecoderBlock(Module):
     def apply_step(self, params, x, kept_a, kept_b, positions):
         """One token a row against what the layer keeps: (out [B, 1, E],
         the two kept things updated, experts [B, 1, top_k] or None)."""
+        kept = (kept_a, kept_b)[:len(self.keeps)]
         x, kept_a, kept_b, logits = self._mix(
             params, x, lambda p, h: self.attn.apply_step(
-                p, h, kept_a, kept_b, positions))
+                p, h, *kept, positions))
         x, chosen = self._feed(params, x, logits)
         return x, kept_a, kept_b, chosen
 
@@ -239,13 +249,17 @@ class DecoderLM(Module):
     """[B, T] int tokens (1-based) -> [B, T, vocab] log-probs; `layers`
     is the pattern, one `LayerSpec` a layer. Attention layers have
     `n_head` query heads of `head_dim` over `n_kv_head` K/V heads
-    (`qk_norm`: an RMSNorm over the whole q and k projections);
+    (`qk_norm`: an RMSNorm over the whole q and k projections, or with
+    `qk_norm_per_head` over each head);
     "experts" layers `n_experts` of `expert_dim` with `top_k` active,
     gated and routed as `experts` says, "dense" ones `ffn_dim`;
     "gated_delta" layers `linear_heads` heads of
     `linear_key_dim` and `linear_value_dim` behind a convolution of
     `conv_taps`, prefilled in chunks of `chunk`; "latent" layers
-    `n_head` heads of the widths `latent` gives. The residual stream,
+    `n_head` heads of the widths `latent` gives; "short_conv" layers a
+    convolution of `conv_taps` over `embed_dim` channels. `tie_head`:
+    the log-probs are taken through the embedding's transpose, and the
+    model holds no head of its own. The residual stream,
     the norms, the router, the recurrent state and the log-probs are
     float32 whatever the weights' type; the matmuls take their operands
     in the weights' type and add their float32 accumulators to the
@@ -264,9 +278,11 @@ class DecoderLM(Module):
                  linear_key_dim: int = 0, linear_value_dim: int = 0,
                  conv_taps: int = 4, chunk: int = 64,
                  latent: Optional[LatentDims] = None,
-                 experts: ExpertsKind = ExpertsKind()):
+                 experts: ExpertsKind = ExpertsKind(),
+                 qk_norm_per_head: bool = False, tie_head: bool = False):
         super().__init__(name)
         self.vocab, self.e, self.max_len = vocab_size, embed_dim, max_len
+        self.tie_head = tie_head
         self.cache_dtype = cache_dtype
         self.n_experts, self.chunk = n_experts, chunk
 
@@ -275,7 +291,10 @@ class DecoderLM(Module):
                 attn = GroupedQueryAttention(
                     embed_dim, n_head, n_kv_head, head_dim,
                     window=spec.window, rope_base=spec.rope_base,
-                    qk_norm=eps if qk_norm else None)
+                    qk_norm=eps if qk_norm else None,
+                    qk_norm_per_head=qk_norm_per_head)
+            elif spec.mixer == "short_conv":
+                attn = ShortConv(embed_dim, conv_taps)
             elif spec.mixer == "gated_delta":
                 attn = GatedDeltaRule(
                     embed_dim, linear_heads, linear_key_dim,
@@ -300,13 +319,16 @@ class DecoderLM(Module):
                         if b.keeps == _KEEPS["latent"]]
         self._grouped = [i for i, b in enumerate(self.blocks)
                          if b.keeps == _KEEPS["attention"]]
+        self._conv = [i for i, b in enumerate(self.blocks)
+                      if b.keeps == _KEEPS["short_conv"]]
         self._windowed = any(b.window is not None for b in self.blocks)
 
     def init(self, rng):
         keys = jax.random.split(rng, len(self.blocks) + 2)
         p = {"embed": jax.random.normal(keys[0], (self.vocab, self.e)) * 0.02,
-             "head": Xavier()(keys[1], (self.e, self.vocab)),
              "norm": self.norm.init(None)}
+        if not self.tie_head:
+            p["head"] = Xavier()(keys[1], (self.e, self.vocab))
         for i, blk in enumerate(self.blocks):
             p[f"block{i}"] = blk.init(keys[i + 2])
         return p
@@ -322,8 +344,15 @@ class DecoderLM(Module):
         """The final norm, the projection to the vocabulary, log-probs."""
         with jax.named_scope("head"):
             h = self.norm.apply(params["norm"], x, None)
-            logits = jnp.dot(h.astype(params["head"].dtype), params["head"],
-                             preferred_element_type=jnp.float32)
+            if self.tie_head:  # h @ embed^T, the embedding as it lies
+                w = params["embed"]
+                logits = jax.lax.dot_general(
+                    h.astype(w.dtype), w, (((h.ndim - 1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            else:
+                logits = jnp.dot(h.astype(params["head"].dtype),
+                                 params["head"],
+                                 preferred_element_type=jnp.float32)
             return jax.nn.log_softmax(logits, axis=-1)
 
     def apply(self, params, input, ctx):
@@ -337,18 +366,20 @@ class DecoderLM(Module):
 
     # ------------------------------------------------------------- serving
     def init_cache(self, slots: int, max_len: int, dtype=None):
-        """Per layer what its kind keeps (`None` under the other kind's
+        """Per layer what its kind keeps (`None` under the other kinds'
         names): "k" and "v" `[slots, n_kv_head, depth, head_dim]`, depth
         `window` on window layers and `max_len` on full ones; "state"
         `[slots, heads, key_dim, value_dim]` float32 and "tail"
         `[slots, taps - 1, channels]` on recurrent ones; "latent"
         `[slots, max_len, rank]` and "k_pe" `[slots, max_len, rope]` on
-        latent ones; and the counters a step and a prefill add to on
+        latent ones; "conv_tail" `[slots, taps - 1, embed_dim]` on short
+        convolutions; and the counters a step and a prefill add to on
         the device."""
         cache = {name: [None] * len(self.blocks) for name in _KEPT}
         for i, blk in enumerate(self.blocks):
-            cache[blk.keeps[0]][i], cache[blk.keeps[1]][i] = \
-                blk.attn.init_cache(slots, max_len, dtype or self.cache_dtype)
+            for name, buf in zip(blk.keeps, blk.attn.init_cache(
+                    slots, max_len, dtype or self.cache_dtype)):
+                cache[name][i] = buf
         c = {"decode_steps": jnp.zeros((), jnp.int32)}
         if self._routed:
             n = len(self._routed)
@@ -367,6 +398,8 @@ class DecoderLM(Module):
         if self._grouped:
             c["kv_positions_live"] = jnp.zeros((), jnp.float32)
             c["kv_positions_read"] = jnp.zeros((), jnp.float32)
+        if self._conv:
+            c["conv_slot_steps"] = jnp.zeros((), jnp.int32)
         cache["counters"] = c
         return cache
 
@@ -394,9 +427,12 @@ class DecoderLM(Module):
         skipped = jnp.zeros((), jnp.float32)
         absmax = jnp.zeros((), jnp.float32)
         for i, blk in enumerate(self.blocks):
-            a, b = blk.keeps
-            x, new[a][i], new[b][i], chosen = blk.apply_step(
-                params[f"block{i}"], x, cache[a][i], cache[b][i], positions)
+            kept = [cache[name][i] for name in blk.keeps] + [None]
+            x, *kept, chosen = blk.apply_step(
+                params[f"block{i}"], x, kept[0], kept[1], positions)
+            for name, k in zip(blk.keeps, kept):
+                new[name][i] = k
+            a = blk.keeps[0]
             with jax.named_scope("counters"):
                 if chosen is not None:
                     loads.append(self._load(chosen[:, 0], live))
@@ -443,6 +479,9 @@ class DecoderLM(Module):
                 c["kv_positions_read"] = c["kv_positions_read"] + sum(
                     gqa_decode_kernel.positions_read(positions, k)
                     for k in ks)
+            if self._conv:
+                c["conv_slot_steps"] = c["conv_slot_steps"] \
+                    + jnp.sum(live).astype(jnp.int32)
         return self._logp(params, x[:, 0]), {**new, "counters": c}
 
     def apply_prefill(self, params, tokens, cache, slot_ids, lengths):
@@ -521,7 +560,10 @@ class DecoderLM(Module):
         live slots' `min(position + 1, depth)` (a ring holds `window`)
         and `kv_positions_read` the positions a step read (each slot's
         live blocks, whole, on the `gqa_decode` kernel's path; slots x
-        depth on the plain-XLA one)."""
+        depth on the plain-XLA one). Short convolutions:
+        `conv_tail_bytes` what the slots' tails hold (a constant of the
+        cache); `conv_slot_steps` live slots over all decode steps, each
+        of which had every short convolution's tail shifted."""
         c = jax.device_get(cache["counters"])
         out = {}
         if self._routed:
@@ -560,6 +602,11 @@ class DecoderLM(Module):
             out.update({
                 "kv_positions_live": float(c["kv_positions_live"]),
                 "kv_positions_read": float(c["kv_positions_read"])})
+        if self._conv:
+            out.update({
+                "conv_tail_bytes": int(sum(
+                    cache["conv_tail"][i].nbytes for i in self._conv)),
+                "conv_slot_steps": int(c["conv_slot_steps"])})
         return out
 
 

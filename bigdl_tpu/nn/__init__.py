@@ -108,6 +108,7 @@ from bigdl_tpu.nn.attention import (GroupedQueryAttention,
                                     TransformerBlock, rope)
 from bigdl_tpu.nn.latent_attention import LatentAttention
 from bigdl_tpu.nn.linear_attention import GatedDeltaRule
+from bigdl_tpu.nn.short_conv import ShortConv
 from bigdl_tpu.nn import initialization
 from bigdl_tpu.nn.initialization import (BilinearFiller, ConstInitMethod,
                                          MsraFiller, Ones, RandomNormal,

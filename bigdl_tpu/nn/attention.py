@@ -223,19 +223,22 @@ class GroupedQueryAttention(Module):
     `rope_base` is the rotary base or, None, no positional encoding at
     all; `qk_norm` puts an RMSNorm (its eps) over the WHOLE q and k
     projections, before the split into heads and the rotation, with
-    scales `q_norm` and `k_norm`; None, the default, has neither and
+    scales `q_norm` and `k_norm`, or with `qk_norm_per_head` over each
+    head of them apart, one scale of `head_dim` shared by the heads of
+    q and one by those of k; None, the default, has neither and
     lowers to the program it always did. Input [B, T, E] in any float
     type: it is cast to the weights'
     type for the projections, and the result is float32 (the output
     projection's accumulator, unrounded), for a residual stream kept in
     float32. The serving cache of a layer is the pair `init_cache`
-    gives: a ring of `window` positions or `max_len` deep
-    (nn/kv_cache.py)."""
+    gives: a ring of `window` positions or `max_len` deep, heads
+    narrower than 128 folded side by side (nn/kv_cache.py)."""
 
     def __init__(self, embed_dim: int, n_head: int, n_kv_head: int,
                  head_dim: int, window: Optional[int] = None,
                  rope_base: Optional[float] = None,
-                 qk_norm: Optional[float] = None, name=None):
+                 qk_norm: Optional[float] = None, name=None, *,
+                 qk_norm_per_head: bool = False):
         super().__init__(name)
         if n_head % n_kv_head:
             raise ValueError(
@@ -245,7 +248,8 @@ class GroupedQueryAttention(Module):
         self.e, self.h, self.hk, self.hd = embed_dim, n_head, n_kv_head, \
             head_dim
         self.window, self.rope_base = window, rope_base
-        self.qk_norm = qk_norm
+        self.qk_norm, self.qk_norm_per_head = qk_norm, qk_norm_per_head
+        self.fold = kv_cache.lane_fold(n_kv_head, head_dim)
 
     def init(self, rng):
         k1, k2, k3, k4 = jax.random.split(rng, 4)
@@ -255,8 +259,11 @@ class GroupedQueryAttention(Module):
              "wv": xav(k3, (self.e, self.hk * self.hd)),
              "wo": xav(k4, (self.h * self.hd, self.e))}
         if self.qk_norm is not None:
-            p["q_norm"] = jnp.ones((self.h * self.hd,))
-            p["k_norm"] = jnp.ones((self.hk * self.hd,))
+            per_head = self.qk_norm_per_head
+            p["q_norm"] = jnp.ones((self.hd if per_head
+                                    else self.h * self.hd,))
+            p["k_norm"] = jnp.ones((self.hd if per_head
+                                    else self.hk * self.hd,))
         return p
 
     def project_qkv(self, params, x, positions=None):
@@ -268,9 +275,13 @@ class GroupedQueryAttention(Module):
 
         def heads(w, n, norm=None):
             z = x @ params[w]
-            if norm is not None and self.qk_norm is not None:
+            normed = norm is not None and self.qk_norm is not None
+            if normed and not self.qk_norm_per_head:
                 z = rms_norm(z, params[norm], self.qk_norm)
-            return jnp.transpose(z.reshape(b, t, n, self.hd), (0, 2, 1, 3))
+            z = z.reshape(b, t, n, self.hd)
+            if normed and self.qk_norm_per_head:
+                z = rms_norm(z, params[norm], self.qk_norm)
+            return jnp.transpose(z, (0, 2, 1, 3))
         q = heads("wq", self.h, "q_norm")
         k = heads("wk", self.hk, "k_norm")
         v = heads("wv", self.hk)
@@ -287,14 +298,17 @@ class GroupedQueryAttention(Module):
                                else "window attention")
 
     def apply_prefill(self, params, x, lengths=None):
-        """(out [B, T, E], k, v [B, Hkv, T, hd]) of the whole sequence;
-        k and v are what a serving prefill commits. `lengths` is the
-        recurrent layers' argument and is not read: under the causal
-        mask no real position sees a row's padding."""
+        """(out [B, T, E], k, v) of the whole sequence; k and v
+        [B, Hkv, T, hd], folded as the cache holds them
+        (`kv_cache.fold_heads`), are what a serving prefill commits.
+        `lengths` is the recurrent layers' argument and is not read:
+        under the causal mask no real position sees a row's padding."""
         with self._scope():
             q, k, v = self.project_qkv(params, x)
             o = causal_grouped_attention(q, k, v, self.window)
-            return self._finish(params, o), k, v
+            return self._finish(params, o), \
+                kv_cache.fold_heads(k, self.fold), \
+                kv_cache.fold_heads(v, self.fold)
 
     def apply(self, params, input, ctx):
         return self.apply_prefill(params, input)[0]
@@ -313,15 +327,20 @@ class GroupedQueryAttention(Module):
         with self._scope():
             q, k, v = self.project_qkv(params, x,
                                        positions=positions[:, None])
-            k_cache = kv_cache.write(k_cache, k.astype(k_cache.dtype),
-                                     positions, self.window)
-            v_cache = kv_cache.write(v_cache, v.astype(v_cache.dtype),
-                                     positions, self.window)
+            k_cache = kv_cache.write(
+                k_cache, kv_cache.fold_heads(k, self.fold).astype(
+                    k_cache.dtype), positions, self.window)
+            v_cache = kv_cache.write(
+                v_cache, kv_cache.fold_heads(v, self.fold).astype(
+                    v_cache.dtype), positions, self.window)
             block = gqa_decode_kernel.cache_block(k_cache)
             if block is None:
                 mask = kv_cache.step_mask(k_cache.shape[2], positions,
                                           self.window)
-                o = grouped_attention(q, k_cache, v_cache, mask[:, :, None])
+                o = grouped_attention(
+                    q, kv_cache.unfold_heads(k_cache, self.fold),
+                    kv_cache.unfold_heads(v_cache, self.fold),
+                    mask[:, :, None])
             else:
                 o = gqa_decode_kernel.gqa_decode(q, k_cache, v_cache,
                                                  positions, block)

@@ -17,6 +17,8 @@ makes two fusions a layer of it, the gate product and the up product
 fused into the down projection as the producer of its left operand, and
 both stream their weights at 92% of the memory's bandwidth: PERF.md
 PR 35).
+A decode step over more rows than experts (128 slots over 32 experts)
+takes the sorted grouped products like a prefill.
 """
 
 from __future__ import annotations

@@ -1,10 +1,11 @@
 """What a layer keeps per serving slot, and how a decode step reads it.
 The one owner of the per-slot state's format (ROADMAP D3): the models
-build their caches from `init`, `init_recurrent` and `init_latent`,
+build their caches from `init`, `init_recurrent`, `init_latent` and
+`init_tail`,
 prefill lands through `commit`, a decode step writes what it keeps by
 position through `write` and masks through `step_mask`.
 
-Four kinds of layer. Two keep keys and values, told apart by `window`:
+Five kinds of layer. Two keep keys and values, told apart by `window`:
 
 * full (`window` None): `[slots, kv_heads, max_len, head_dim]`, position
   p at index p.
@@ -14,6 +15,14 @@ Four kinds of layer. Two keep keys and values, told apart by `window`:
   position p - ((p - j) % window): the window, whole, once p >= window
   - 1; before that the indices past p hold nothing yet (a negative
   position) and are masked.
+
+A head narrower than the 128 lanes of a TPU tile would be padded to
+them on the device, the buffer twice its size at 64: such heads are
+FOLDED, `lane_fold(kv_heads, head_dim)` of them side by side on the
+minor axis, `[slots, kv_heads / fold, depth, fold * head_dim]`, head
+`i * fold + j` in lanes `j * head_dim ...` of row i (`fold_heads`,
+`unfold_heads`). A fold of 1, every head of 128 or more, is the plain
+format.
 
 A decode step over a full layer reads the cache only as deep as a rung
 of `depth_rungs(max_len)` that covers the deepest slot of the step: the
@@ -45,6 +54,13 @@ step's positions.
   positions minor, so their lanes are not padded to 128: the device
   holds the 576 values a position and layer that the leaves count
   (PERF.md PR 39: 3.62 GB at 32 slots x 16384 x 6 layers).
+
+* tail (`init_tail`; nn/short_conv.py): the last `taps - 1` rows a
+  short causal convolution read, `[slots, taps - 1, channels]` in the
+  cache's type, and nothing else: the recurrent kind's tail without a
+  state. A prefill SETS it whole (`commit`), a decode step REPLACES it
+  (shifted by one row) for every slot it runs over, which every later
+  prefill of the slot overwrites, as the recurrent kind's.
 """
 
 from __future__ import annotations
@@ -69,12 +85,39 @@ def _at_least_one(**sizes):
             raise ValueError(f"{name} must be >= 1, got {n}")
 
 
+def lane_fold(kv_heads: int, head_dim: int) -> int:
+    """K/V heads a row of the cache holds side by side: `128 / head_dim`
+    where that is a whole number over 1 that divides `kv_heads`, else 1."""
+    fold = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+    return fold if kv_heads % fold == 0 else 1
+
+
+def fold_heads(x, fold: int):
+    """[B, H, T, d] -> the cache's [B, H / fold, T, fold * d]."""
+    if fold == 1:
+        return x
+    b, h, t, d = x.shape
+    return jnp.moveaxis(x.reshape(b, h // fold, fold, t, d), 2, 3).reshape(
+        b, h // fold, t, fold * d)
+
+
+def unfold_heads(x, fold: int):
+    """The cache's [B, H / fold, T, fold * d] -> [B, H, T, d]."""
+    if fold == 1:
+        return x
+    b, hc, t, w = x.shape
+    return jnp.moveaxis(x.reshape(b, hc, t, fold, w // fold), 3, 2).reshape(
+        b, hc * fold, t, w // fold)
+
+
 def init(slots: int, kv_heads: int, max_len: int, head_dim: int,
          window: Optional[int] = None, dtype=jnp.float32):
-    """One layer's K (or V) buffer, zeroed."""
+    """One layer's K (or V) buffer, zeroed, its heads folded by
+    `lane_fold`."""
     _at_least_one(slots=slots, max_len=max_len)
-    return jnp.zeros((slots, kv_heads, depth(max_len, window), head_dim),
-                     dtype)
+    fold = lane_fold(kv_heads, head_dim)
+    return jnp.zeros((slots, kv_heads // fold, depth(max_len, window),
+                      fold * head_dim), dtype)
 
 
 def init_recurrent(slots: int, heads: int, key_dim: int, value_dim: int,
@@ -93,6 +136,12 @@ def init_latent(slots: int, max_len: int, rank: int, rope_dim: int,
     _at_least_one(slots=slots, max_len=max_len)
     return (jnp.zeros((slots, max_len, rank), dtype),
             jnp.zeros((slots, max_len, rope_dim), dtype))
+
+
+def init_tail(slots: int, taps: int, channels: int, dtype=jnp.float32):
+    """One short convolution's tail, zeroed."""
+    _at_least_one(slots=slots)
+    return jnp.zeros((slots, taps - 1, channels), dtype)
 
 
 def depth_rungs(max_len: int) -> Tuple[int, ...]:

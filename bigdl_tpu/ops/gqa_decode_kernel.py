@@ -16,6 +16,11 @@ the block loop `mla_decode` runs (ops/ragged_decode.py):
   `[kv_heads, block, head_dim]` each, every K/V head at once (the
   products batched over the heads, the group's query heads as each
   head's rows); an idle slot, at position 0, reads one block;
+- a cache whose heads are folded side by side (nn/kv_cache.py: two
+  heads of 64 in a row of 128 lanes) is read as it lies, a row of
+  `fold` heads at once: each query row is zero outside its own head's
+  lanes, so its scores are its head's alone, and of the values it
+  keeps its own head's lanes;
 - the products are grouped_attention's: scores in float32 times
   `head_dim ** -0.5`, the probabilities in the cache's type against V,
   a float32 accumulator, the result in q's type; they differ only in
@@ -64,7 +69,8 @@ def block_for(depth: int, kv_heads: int, head_dim: int, itemsize: int
 
 
 def cache_block(k_cache) -> Optional[int]:
-    """`block_for` a cache buffer [slots, kv_heads, depth, head_dim]."""
+    """`block_for` a cache buffer [slots, kv_heads, depth, head_dim]
+    (or its folded form: the bytes a position are the same)."""
     _, hk, depth, hd = k_cache.shape
     return block_for(depth, hk, hd, jnp.dtype(k_cache.dtype).itemsize)
 
@@ -96,25 +102,38 @@ def gqa_decode(q, k_cache, v_cache, positions, block: int,
                interpret: Optional[bool] = None):
     """softmax(mask(q . k / sqrt(hd))) @ v for one query row a slot:
     `q` [B, H, 1, hd] over the layer's `k_cache` / `v_cache`
-    [B, Hkv, depth, hd] (query head j reads K/V head j // (H // Hkv)),
-    each slot reading its `min(positions[b] + 1, depth)` live indices in
-    blocks of `block`, one program a slot over all its K/V heads.
-    Returns [B, H, 1, hd] in q's type."""
+    [B, Hkv / fold, depth, fold * hd] (query head j reads K/V head
+    j // (H // Hkv); `fold` K/V heads side by side a row, 1 for the
+    plain format), each slot reading its `min(positions[b] + 1, depth)`
+    live indices in blocks of `block`, one program a slot over all its
+    K/V heads. Returns [B, H, 1, hd] in q's type."""
     from jax.experimental import pallas as pl
 
     b, h, t, hd = q.shape
-    _, hk, depth, _ = k_cache.shape
-    if t != 1 or h % hk or v_cache.shape != k_cache.shape \
-            or depth % block:
+    _, rows, depth, width = k_cache.shape
+    fold = width // hd
+    if t != 1 or width % hd or h % (rows * fold) \
+            or v_cache.shape != k_cache.shape or depth % block:
         raise ValueError(f"q {q.shape} over K/V {k_cache.shape} / "
                          f"{v_cache.shape} against blocks of {block}")
+    group = h // (rows * fold)
     dtype = q.dtype
     q = q.astype(jnp.promote_types(dtype, k_cache.dtype))
+    if fold == 1:
+        queries = q.reshape(b, rows, group, hd)
+    else:  # query row (j, r) of a row of heads: head j's lanes alone
+        lanes = jnp.eye(fold, dtype=q.dtype)[:, None, :, None]
+        queries = (q.reshape(b, rows, fold, group, 1, hd) * lanes).reshape(
+            b, rows, fold * group, width)
     o = ragged_decode.ragged_decode(
-        _score, _value, [q.reshape(b, hk, h // hk, hd)], [k_cache, v_cache],
+        _score, _value, [queries], [k_cache, v_cache],
         [lambda c, i, start: c.at[i, :, pl.ds(start, block), :]] * 2,
-        [(hk, block, hd)] * 2, positions, depth=depth, block=block,
+        [(rows, block, width)] * 2, positions, depth=depth, block=block,
         sm_scale=hd ** -0.5,
-        out_shape=jax.ShapeDtypeStruct((b, hk, h // hk, hd), dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, fold * group, width),
+                                       dtype),
         name="gqa_decode", interpret=interpret)
+    if fold > 1:  # each query row's own head's lanes
+        o = o.reshape(b, rows, fold, group, fold, hd)
+        o = jnp.stack([o[:, :, j, :, j] for j in range(fold)], axis=2)
     return o.reshape(b, h, 1, hd)

@@ -227,3 +227,32 @@ def test_the_engine_serves_the_same_greedy_tokens_on_both_paths(
     assert kern_stats["kv_positions_live"] \
         <= kern_stats["kv_positions_read"] \
         < xla_stats["kv_positions_read"]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("kind,case", CASES,
+                         ids=[f"{k}-{c}" for k, c in CASES])
+def test_heads_folded_in_lanes_read_as_the_unfolded_form(kind, case, group,
+                                                         dtype):
+    """Heads of 64 as the cache folds them, two a row of 128 lanes
+    (`kv_cache.lane_fold`): each query row reads its own head's lanes
+    alone, so the kernel over the folded buffers gives the masked
+    grouped form over the heads apart, to the tolerances above."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    hk, hd = 4, 64
+    q = jax.random.normal(ks[0], (B, hk * group, 1, hd)).astype(dtype)
+    k = jax.random.normal(ks[1], (B, hk, DEPTH, hd)).astype(dtype)
+    v = jax.random.normal(ks[2], (B, hk, DEPTH, hd)).astype(dtype)
+    fold = kv_cache.lane_fold(hk, hd)
+    assert fold == 2
+    pos = jnp.asarray(POSITIONS[case], jnp.int32)
+    got = gdk.gqa_decode(q, kv_cache.fold_heads(k, fold),
+                         kv_cache.fold_heads(v, fold), pos, BLOCK,
+                         interpret=True)
+    want = _xla(q, k, v, pos, kind)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=TOL[dtype], rtol=TOL[dtype])

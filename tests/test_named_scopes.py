@@ -32,6 +32,9 @@ from bigdl_tpu.models.transformer import TransformerLM
 from bigdl_tpu.serving.generation import _next_token
 
 SCOPES = load_py("readers", "scope_device_ms")
+#: the program's scopes that the reader's `VOCABULARY` (the benchmark's
+#: file) does not list yet: the gated short convolution's
+PROGRAM_SCOPES = {"short conv", "conv project", "conv taps"}
 COSTLY = ("dot", "convolution", "custom-call", "dynamic-update-slice",
           "scatter", "gather", "reduce")
 _INSTRUCTION = re.compile(
@@ -83,13 +86,31 @@ def _latent():
         experts=ExpertsKind("silu", "sigmoid", c["scale"]))
 
 
+def _lfm2():
+    """LFM2's block at a tiny size: short convolutions beside attention
+    of 4 query heads over 2 K/V heads of 64 (two a row of the cache's
+    128 lanes) with an RMSNorm a head on q and k, two dense layers then
+    sigmoid-routed experts, the head tied to the embedding."""
+    kinds = ("conv", "conv", "attention", "conv")
+    layers = [LayerSpec(mixer="short_conv" if k == "conv" else "attention",
+                        rope_base=None if k == "conv" else 1e6,
+                        ffn="dense" if i < 2 else "experts",
+                        router_reads="attention" if i < 2 else "ffn")
+              for i, k in enumerate(kinds)]
+    return DecoderLM(128, 64, 4, 2, 64, layers, n_experts=8, expert_dim=32,
+                     top_k=4, eps=1e-5, max_len=256, ffn_dim=96,
+                     qk_norm=True, qk_norm_per_head=True, conv_taps=3,
+                     tie_head=True,
+                     experts=ExpertsKind("silu", "sigmoid", 1.0))
+
+
 def _lm():
     return TransformerLM(64, embed_dim=32, n_layer=2, n_head=2,
                          use_flash=False, max_len=32)
 
 
 SERVED = {"dense": _dense, "sparse": _sparse, "hybrid": _hybrid,
-          "latent": _latent, "transformer": _lm}
+          "latent": _latent, "lfm2": _lfm2, "transformer": _lm}
 
 
 def _serving(kind, program):
@@ -178,7 +199,7 @@ def test_every_costly_operation_lies_under_a_scope(kind, program):
         text, cache_shapes = _serving(kind, program)
     ops = _costly(text)
     assert ops, "the program holds no costly operation"
-    vocabulary = set(SCOPES.VOCABULARY)
+    vocabulary = set(SCOPES.VOCABULARY) | PROGRAM_SCOPES
     unscoped = [(op, shape, comps) for op, shape, comps in ops
                 if comps and not vocabulary.intersection(comps)]
     assert not unscoped, unscoped[:10]
@@ -220,6 +241,7 @@ _KERNEL_CASES = {
         8, 32, 2), ["full attention"] + ["window attention"] * 3),
     "hybrid": (_hybrid, ["full attention"] * 2),
     "latent": (_latent, ["mla attend"] * 6),
+    "lfm2": (_lfm2, ["full attention"]),
 }
 
 
@@ -247,3 +269,22 @@ def test_the_decode_kernels_read_under_their_layers_scope(kind, monkeypatch):
         assert scope in comps and kernel in comps, comps
         if kind == "latent":
             assert "latent attention" in comps
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_short_convolution_reads_under_its_own_scopes(program):
+    """Its projections under `short conv` > `conv project`, its taps and
+    gates (elementwise: fused, and named) under `short conv` > `conv
+    taps`, the prefill's tail gathered under `short conv`; the q/k norms
+    a head (a reduce over each head's 64 values) under `full
+    attention`."""
+    text, _ = _serving("lfm2", program)
+    ops = _costly(text)
+    conv = [comps for _, _, comps in ops if comps and "short conv" in comps]
+    assert any("conv project" in c for c in conv)
+    named = {tuple(SCOPES.components(n))
+             for n in re.findall(r'op_name="([^"]*)"', text)}
+    assert any("short conv" in c and "conv taps" in c for c in named)
+    norms = [comps for op, shape, comps in ops if op == "reduce" and comps
+             and "full attention" in comps]
+    assert norms

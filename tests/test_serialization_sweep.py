@@ -210,6 +210,8 @@ SPECS = {
     "LatentAttention": (
         lambda: nn.LatentAttention(8, 2, 4, 2, 4, 6, rope_base=1e4),
         np.ones((2, 5, 8), np.float32)),
+    "ShortConv": (
+        lambda: nn.ShortConv(8, 3), np.ones((2, 5, 8), np.float32)),
     "ScaledDotProductAttention": (
         lambda: nn.ScaledDotProductAttention(), Table(
             np.ones((2, 2, 5, 4), np.float32), np.ones((2, 2, 5, 4), np.float32),

@@ -691,3 +691,67 @@ class TestLatentDecodeProgramCompiles:
         m = compiled.memory_analysis()
         assert m.alias_size_in_bytes >= 3_600_000_000     # the donated cache
         assert m.temp_size_in_bytes < 100_000_000
+
+
+class TestShortConvDecodeProgramCompiles:
+    """`GenerationEngine`'s `jit__decode_fn` over `DecoderLM` at the
+    shapes of `lfm2-8b-a1b.serve-wide` (128 slots x 4096, hidden 2048,
+    [conv, conv, attention, conv] x 3 with 32 query heads over 8 K/V
+    heads of 64, two dense FFNs of 7168, then 32 experts of 1792 with 4
+    active, vocabulary 65536 tied, bf16 weights, K/V and tails, float32
+    norms and router), compiled by libtpu for a v5e that is not attached
+    under the TPU's routing. Nothing runs, so nothing here is a
+    timing."""
+
+    def test_one_gqa_decode_a_layer_over_folded_heads(self, tpu_routing):
+        import re
+        from bigdl_tpu.models.decoder import DecoderLM, ExpertsKind, LayerSpec
+        on = _v5e_device()
+        slots, max_len, n_layer = 128, 4096, 12
+        kinds = ["conv", "conv", "attention", "conv"] * 3
+        layers = [LayerSpec(mixer="short_conv" if k == "conv" else
+                            "attention", rope_base=None if k == "conv"
+                            else 1e6, ffn="dense" if i < 2 else "experts",
+                            router_reads="attention" if i < 2 else "ffn")
+                  for i, k in enumerate(kinds)]
+        model = DecoderLM(65536, 2048, 32, 8, 64, layers, n_experts=32,
+                          expert_dim=1792, top_k=4, eps=1e-5,
+                          cache_dtype=jnp.bfloat16, ffn_dim=7168,
+                          qk_norm=True, qk_norm_per_head=True, conv_taps=3,
+                          tie_head=True,
+                          experts=ExpertsKind("silu", "sigmoid", 1.0))
+        f32 = {"router", "router_bias", "ln1", "ln2", "norm", "q_norm",
+               "k_norm"}
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, a: struct(
+                a.shape, jnp.float32 if f32
+                & {getattr(k, "key", None) for k in path} else jnp.bfloat16,
+                on),
+            jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+        cache = jax.tree_util.tree_map(
+            lambda a: struct(a.shape, a.dtype, on),
+            jax.eval_shape(lambda: model.init_cache(slots, max_len)))
+        kv = "bf16[128,4,4096,128]"    # two heads of 64 a row of 128 lanes
+        assert {str(c.shape) for c in cache["k"] if c is not None} == \
+            {"(128, 4, 4096, 128)"}
+        compiled = _compile_decode_program(model, params, cache, slots, on)
+        hlo = compiled.as_text()
+        entry = hlo[hlo.index("ENTRY"):]
+        calls = re.findall(r"^\s*%gqa_decode[\w.]* = \S+ custom-call\(",
+                           entry, re.M)
+        assert len(calls) == 3
+        # no K/V buffer is read whole beside the kernel, copied or
+        # relaid out: each is touched by its kernel and its write alone
+        moved = [line for line in entry.splitlines()
+                 if re.search(r" (copy|copy-start|transpose|fusion)\(", line)
+                 and line.split(" = ", 1)[-1].lstrip().startswith(kv)
+                 and "dynamic-update-slice" not in line]
+        assert not moved, moved
+        # more rows than experts: the sorted grouped products, three a
+        # layer of experts, not the every-row x every-expert form
+        assert len(re.findall(r"^\s*%ragged-dot[\w.\-]* = ", entry,
+                              re.M)) >= 30
+        assert "short conv/" in hlo
+        m = compiled.memory_analysis()
+        assert m.alias_size_in_bytes >= 3_200_000_000   # K/V and tails
+        assert m.temp_size_in_bytes < 100_000_000
